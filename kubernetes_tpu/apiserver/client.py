@@ -9,10 +9,9 @@ apiserver/watchcodec.py) and feeds a local Watcher, exactly how Reflector
 consumes watch responses (client-go/tools/cache/reflector.go:210).
 
 Transport: a bounded per-host pool of persistent HTTP/1.1 connections
-(client-go's http.Transport keep-alive role). PERFORMANCE.md round-11
-measured accept+connect dominating the REST bind cost when every request
-opened a fresh TCP connection; `_request`, watch streams, and bind POSTs
-all draw from the same pool now. A pooled socket the server closed while
+(client-go's http.Transport keep-alive role). Accept+connect dominated
+the REST bind cost when every request opened a fresh TCP connection;
+`_request`, watch streams, and bind POSTs all draw from the same pool. A pooled socket the server closed while
 idle is detected at acquire time (pending FIN/EOF) and discarded; the
 narrow race where the close lands mid-request reopens ONCE for
 idempotent GETs only — a reused connection that dies anywhere in a

@@ -168,6 +168,11 @@ class APIServer:
             metrics.set_gauge(GAUGE_DISK_CORRUPT, 1.0)
         return srv
 
+    @property
+    def wal(self):
+        """The write-ahead log behind this store (None: not durable)."""
+        return self._wal
+
     def _log(self, verb: str, kind: str, obj: Any) -> None:
         if self._wal is None and self.replicator is None:
             return

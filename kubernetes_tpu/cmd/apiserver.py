@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import argparse
 import logging
+import os
 import threading
 
 
@@ -46,21 +47,15 @@ def main(argv=None) -> int:
     # shared-memory frame ring fed by this frontend's watch cache
     parser.add_argument("--relay-workers", type=int, default=0)
     parser.add_argument("--relay-port", type=int, default=0)
+    # durability: the store write-ahead-logs every mutation under
+    # <data-dir>/cluster and recovers from it on restart (primary mode)
+    parser.add_argument("--data-dir", default="")
     args = parser.parse_args(argv)
     if bool(args.tls_cert) != bool(args.tls_key):
         parser.error("--tls-cert and --tls-key must be given together")
     logging.basicConfig(
         level=logging.DEBUG if args.verbosity >= 4 else logging.INFO
     )
-    # the apiserver itself is host-only, but control-plane helpers it
-    # hosts (e.g. an in-process scheduler replica in tests, tooling that
-    # imports through this entry) share the process: point JAX at the
-    # persistent compilation cache up front so any kernel they compile
-    # lands in (or comes from) the shared cache. Safe post-generational
-    # snapshot; KTPU_NO_COMPILATION_CACHE=1 opts out.
-    from ..utils.compilation_cache import enable_persistent_compilation_cache
-
-    enable_persistent_compilation_cache()
     log = logging.getLogger("kubernetes_tpu.cmd.apiserver")
     serve_kwargs = dict(
         port=args.port,
@@ -114,7 +109,19 @@ def main(argv=None) -> int:
     else:
         from ..apiserver.rest import serve
 
-        srv, port, store = serve(**serve_kwargs)
+        store = None
+        if args.data_dir:
+            from ..client.apiserver import APIServer
+
+            os.makedirs(args.data_dir, exist_ok=True)
+            wal_path = os.path.join(args.data_dir, "cluster")
+            # recover() is APIServer(wal=WriteAheadLog(path)) plus the
+            # replay of whatever an earlier run of this entry left there
+            store = APIServer.recover(wal_path)
+            log.info(
+                "WAL at %s (native sink: %s)", wal_path, store.wal.native
+            )
+        srv, port, store = serve(store=store, **serve_kwargs)
         if args.repl_port or args.cluster_size:
             from ..runtime.replication import ReplicationListener
 

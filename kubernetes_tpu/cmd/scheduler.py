@@ -14,6 +14,7 @@ informer streams, and leadership-fenced binds all cross the wire — the
 from __future__ import annotations
 
 import argparse
+import importlib.metadata
 import json
 import logging
 import signal
@@ -276,6 +277,13 @@ def run(
     return sched
 
 
+def _dist_version(name: str) -> str:
+    try:
+        return importlib.metadata.version(name)
+    except importlib.metadata.PackageNotFoundError:
+        return "absent"
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(prog="kube-scheduler-tpu")
     parser.add_argument("--config", help="ComponentConfig or Policy file")
@@ -316,8 +324,9 @@ def main(argv=None) -> int:
     parser.add_argument(
         "--platform",
         default="",
-        help="force a JAX platform (e.g. 'cpu' to run without the TPU — "
-        "the device-failure fallback path)",
+        help="the JAX platform this replica must run on ('tpu', 'cpu'): "
+        "start-up fails if it does not initialise. Default: JAX's own "
+        "choice (JAX_PLATFORMS, else the best backend present)",
     )
     parser.add_argument(
         "--autoscale-shapes",
@@ -358,18 +367,26 @@ def main(argv=None) -> int:
     logging.basicConfig(
         level=logging.DEBUG if args.verbosity >= 4 else logging.INFO
     )
-    if args.platform:
-        import jax
+    import jax
 
+    if args.platform:
         jax.config.update("jax_platforms", args.platform)
-    # persistent compilation cache, on by default: the generational
-    # snapshot made donation safe against deserialized executables (see
-    # utils/compilation_cache.py), so a replica restart or a standby
-    # promotion deserializes its kernels instead of paying the cold-start
-    # compile storm. KTPU_NO_COMPILATION_CACHE=1 opts out.
+    # one persistent compilation cache for every replica of this checkout
+    # (utils/compilation_cache.py says where, and counts its hits): a
+    # restart or a standby promotion deserializes its kernels instead of
+    # paying the cold-start compile storm
     from ..utils.compilation_cache import enable_persistent_compilation_cache
 
-    enable_persistent_compilation_cache()
+    cache_dir = enable_persistent_compilation_cache()
+    # initialise the backend HERE: a platform that cannot come up (no chip,
+    # or a chip another process holds) fails the start, not the first batch
+    devices = jax.devices()
+    logger.info(
+        "runtime: jax=%s jaxlib=%s libtpu=%s platform=%s devices=%d "
+        "compilation_cache=%s",
+        jax.__version__, _dist_version("jaxlib"), _dist_version("libtpu"),
+        devices[0].platform, len(devices), cache_dir,
+    )
     cfg = (
         load_config_file(args.config)
         if args.config

@@ -33,18 +33,23 @@ Two entry points:
     mask  [TPL, N] bool  all-resources fit (req==0 columns always fit)
     score [TPL, N] f32   mean over requested resources of (free-req)/alloc
 
-On CPU backends the kernel runs in interpreter mode (bit-accurate, slow) —
-tests pin it against the jnp reference; `use_pallas` wiring in the wave
-kernel is config-gated so enabling it on hardware is a one-flag change.
+`interpret=True` runs a kernel in the Pallas interpreter (bit-accurate,
+slow, any backend) instead of compiling it with Mosaic; tests on the CPU
+ask for it to pin the kernels against the jnp reference. It is always the
+caller's statement, never inferred from the platform here.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+logger = logging.getLogger("kubernetes_tpu.ops.pallas_ops")
 
 BLOCK_N = 512  # nodes per tile (lane axis: multiple of 128)
 R_PAD = 8  # resource sublanes
@@ -117,19 +122,31 @@ def _mask_kernel(req_ref, free_ref, mask_ref):
     mask_ref[:] = jnp.all(fits, axis=1)
 
 
+def fit_mask_block(r: int, n: int) -> Optional[int]:
+    """Node-tile width `fit_mask` uses for an [n, r] free matrix, or None
+    when the shapes don't tile (R > 8 after extended-resource growth, or
+    N not 128-divisible) and it takes the jnp broadcast instead."""
+    if r > R_PAD:
+        return None
+    return next((b for b in (512, 256, 128) if n % b == 0), None)
+
+
 def fit_mask(req, free, interpret: bool = False):
     """[TPL, N] resource-fit mask, fused over node tiles (the wave
     kernel's `fits0`/`fits_w` without the [TPL, N, R] HBM intermediate).
     req [TPL, R] i32, free [N, R] i32 (natural layout; transposed and
-    padded here at trace time, static shapes). Falls back to the jnp
-    broadcast when the shapes don't tile (R > 8 after extended-resource
-    growth, or N not 128-divisible)."""
+    padded here at trace time, static shapes). Shapes that don't tile
+    (`fit_mask_block`) take the jnp broadcast, and say so in the log."""
     from jax.experimental import pallas as pl
 
     tpl, r = req.shape
     n = free.shape[0]
-    block = next((b for b in (512, 256, 128) if n % b == 0), None)
-    if r > R_PAD or block is None:
+    block = fit_mask_block(r, n)
+    if block is None:
+        logger.warning(
+            "pallas fit_mask: free [%d, %d] does not tile; this trace "
+            "uses the jnp broadcast", n, r,
+        )
         reqb = req[:, :, None]
         return jnp.all((reqb == 0) | (reqb <= free.T[None]), axis=1)
     tpl_pad = max(8, tpl)

@@ -62,7 +62,7 @@ logger = logging.getLogger("kubernetes_tpu.runtime.consensus")
 HEALTHY = 1.0
 DEGRADED = 0.0
 
-# metrics series names (PERFORMANCE.md "Durability" section): the SIGUSR2
+# metrics series names: the SIGUSR2
 # debugger dump (scheduler/cache/debugger.py) renders every gauge under
 # this prefix, so a wedged cluster is diagnosable without logs.
 GAUGE_COMMIT_INDEX = "apiserver_commit_index"

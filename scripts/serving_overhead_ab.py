@@ -1,4 +1,4 @@
-"""Same-process serving-tier A/Bs (PERFORMANCE.md round-15).
+"""Same-process serving-tier A/Bs.
 
 Two experiments, each against one live in-process REST apiserver:
 

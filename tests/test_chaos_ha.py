@@ -76,7 +76,7 @@ def lock_order_watchdog():
 
 # The acceptance budget for "the standby starts binding fast": ONE
 # autoscaler period. The PR-5 autoscaler's what-if simulation alone costs
-# 2.2-6.6 s on the CPU backend (PERFORMANCE.md round-9), so a CPU
+# 2.2-6.6 s on the CPU backend, so a CPU
 # deployment runs multi-second scan periods; 5 s is the tight end of
 # that range and comfortably covers lease expiry + takeover + adoption +
 # the first warm wave — but NOT a snapshot rebuild + compile storm.

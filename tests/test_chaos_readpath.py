@@ -8,8 +8,8 @@ nodes) plus heartbeat/bind load against ONE apiserver, with the gates:
   * zero informer full-relists after a forced watch flap (bookmark/RV
     resume through the event window)
   * zero bind-path starvation while the read storm saturates watch-init
-  * p99 watch-delivery latency measured (PERFORMANCE.md round-10 runs
-    the 10k-client version through perf/harness.run_readpath_benchmark)
+  * p99 watch-delivery latency measured (the 10k-client version runs
+    through perf/harness.run_readpath_benchmark)
 
 Bind-invariant accounting rides the ChaosStore ledger from
 test_chaos_pipeline: every bind acked under the storm stays bound.
@@ -244,7 +244,7 @@ def test_readpath_storm_500_one_store_watch_zero_relists():
 def test_readpath_storm_10k_acceptance():
     """The acceptance-scale storm: 10 000 hollow informers. Gates are
     structural (one store watch, zero relists, zero starvation); the
-    measured p99 lands in PERFORMANCE.md round-10 via bench.py."""
+    measured p99 is reported by bench.py's readpath line."""
     p99 = _storm_scenario(n_informers=10000, n_events=150, sampled=64)
     print(f"10k-informer watch-delivery p99: {p99:.2f} ms")
 

@@ -25,8 +25,7 @@ import pytest
         (100, 3000, 240),
         # the 1000-node cluster of the 30k-pod gate (scheduler_test.go:
         # 93-103) at a CPU-scale pod count; the full 30k-pod config is
-        # SchedulingDensity/1000 in the TPU bench queue
-        # (scripts/tpu_experiments.py density)
+        # SchedulingDensity/1000 in perf/workloads.py
         (1000, 3000, 300),
     ],
     ids=["100n-3k", "1000n-3k"],

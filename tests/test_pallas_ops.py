@@ -107,7 +107,8 @@ def test_wave_kernel_pallas_fit_parity():
         ptab, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
         snap = enc.flush()
         kern = make_wave_kernel_jit(
-            enc.cfg.v_cap, 64, 4, use_pallas_fit=use_pallas
+            enc.cfg.v_cap, 64, 4, use_pallas_fit=use_pallas,
+            pallas_interpret=_interpret(),
         )
         _, res = kern(
             snap, eb.batch, ptab, jnp.asarray(DEFAULT_WEIGHTS),
@@ -125,31 +126,72 @@ def test_wave_kernel_pallas_fit_parity():
     np.testing.assert_array_equal(chosen_a, chosen_b)
 
 
-def test_sharded_wave_kernel_with_pallas_fit():
-    """use_pallas_fit composes with the sharded mesh path: GSPMD
-    partitions around the (interpret-mode on CPU) pallas call and
-    placements match the unsharded kernel's count."""
+def test_sharded_wave_kernel_with_pallas_fit(caplog):
+    """use_pallas_fit composes with the sharded mesh path. Mosaic kernels
+    are not partitioned automatically (on a TPU the lowering refuses a
+    sharded pallas_call outright), so under a mesh the fit mask runs per
+    node shard through shard_map — and must place exactly what the
+    single-device kernel places. n_cap is sized so every shard still
+    tiles: the Pallas branch itself runs per shard, not its jnp stand-in."""
+    import logging
+
     import jax.numpy as jnp
 
-    from kubernetes_tpu.ops.encoding import SnapshotEncoder
+    from kubernetes_tpu.ops.encoding import EncodingConfig, SnapshotEncoder
     from kubernetes_tpu.ops.lattice import DEFAULT_WEIGHTS
+    from kubernetes_tpu.ops.pallas_ops import fit_mask_block
     from kubernetes_tpu.ops.templates import TemplateCache, build_pair_table
-    from kubernetes_tpu.parallel.mesh import make_mesh
+    from kubernetes_tpu.ops.wavelattice import make_wave_kernel_jit
+    from kubernetes_tpu.parallel.mesh import (
+        make_mesh,
+        replicated,
+        snapshot_shardings,
+    )
     from kubernetes_tpu.parallel.sharded import make_sharded_wave_kernel
     from test_lattice_smoke import make_node, make_pod
 
-    enc = SnapshotEncoder()
-    for i in range(16):
-        enc.add_node(make_node(f"n{i}", cpu="8"))
-    cache = TemplateCache(enc)
-    pods = [make_pod(f"p{i}", cpu="500m") for i in range(12)]
-    eb = cache.encode(pods, pad_to=16)
-    pt, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
-    snap = enc.flush()
     mesh = make_mesh()
-    kern = make_sharded_wave_kernel(enc.cfg.v_cap, 64, 4, 1.0, mesh, True)
-    _, res = kern(
-        snap, eb.batch, pt, jnp.asarray(DEFAULT_WEIGHTS), jax.random.PRNGKey(0)
-    )
-    enc.invalidate_device()
-    assert int(np.asarray(jax.device_get(res.placed)).sum()) == 12
+    n_cap = 128 * mesh.size
+
+    def run(sharded):
+        enc = SnapshotEncoder(EncodingConfig(n_cap=n_cap))
+        assert fit_mask_block(enc.cfg.r_cap, n_cap // mesh.size) == 128
+        for i in range(40):
+            enc.add_node(make_node(f"n{i}", cpu="8" if i % 3 else "2"))
+        cache = TemplateCache(enc)
+        pods = [
+            make_pod(f"p{i}", cpu="1500m" if i % 2 else "500m")
+            for i in range(24)
+        ]
+        eb = cache.encode(pods, pad_to=32)
+        pt, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+        if sharded:
+            enc.set_sharding(snapshot_shardings(mesh), replicated(mesh))
+            kern = make_sharded_wave_kernel(
+                enc.cfg.v_cap, 64, 4, 1.0, mesh, True, pallas_interpret=True
+            )
+        else:
+            kern = make_wave_kernel_jit(
+                enc.cfg.v_cap, 64, 4, use_pallas_fit=True,
+                pallas_interpret=True,
+            )
+        snap = enc.flush()
+        new_snap, res = kern(
+            snap, eb.batch, pt, jnp.asarray(DEFAULT_WEIGHTS),
+            jax.random.PRNGKey(0),
+        )
+        out = jax.device_get(
+            (res.placed, res.chosen, res.feasible_tpl, new_snap.requested)
+        )
+        if sharded:
+            assert len(new_snap.requested.sharding.device_set) == mesh.size
+        enc.invalidate_device()
+        return [np.asarray(x) for x in out]
+
+    with caplog.at_level(logging.WARNING, "kubernetes_tpu.ops.pallas_ops"):
+        single = run(False)
+        sharded = run(True)
+    assert not caplog.records, [r.getMessage() for r in caplog.records]
+    assert single[0].sum() == 24
+    for a, b in zip(single, sharded):
+        np.testing.assert_array_equal(a, b)
