@@ -30,11 +30,14 @@ path; no guard trip, device loss, retry, mesh shrink, snapshot drift or
 rebuild; a clean anti-entropy pass after the last bind; no compile in
 stage B after a shape's first chunk; no failure line in the log.
 
-The last stdout line is one JSON object: {"ok": true, "device":
-{"platform", "kind", "count"}, ...smoke observations}. These are smoke
-observations, not benchmark metrics. Without an accelerator the script
-fails and prints no result; a CPU rehearsal at a small size is an
-explicit argument (--rehearse-cpu --nodes 64), never a default.
+The last stdout line is one JSON object with exactly these keys:
+{"ok": true, "device": {"platform", "kind", "count"}}, the device as the
+scheduler process reported it. The line before it is "observations: "
+plus one JSON object of what the smoke saw (per stage pods bound, wall
+seconds, batches; cache hits; counters). Those are smoke observations,
+not benchmark metrics. Without an accelerator the script fails and prints
+no result; a CPU rehearsal at a small size is an explicit argument
+(--rehearse-cpu --nodes 64), never a default.
 """
 
 from __future__ import annotations
@@ -721,7 +724,9 @@ def main() -> int:
         print(f"chip_smoke FAILED: {e}", file=sys.stderr, flush=True)
         return 1
     device = result.pop("device")
-    print(json.dumps({"ok": True, "device": device, **result}), flush=True)
+    print(f"observations: {json.dumps(result)}", flush=True)
+    # the contract's line: these keys and no others
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

@@ -71,9 +71,14 @@ def test_chip_smoke_cpu_rehearsal_and_refusal_without_a_chip(tmp_path):
         timeout=400,
     )
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-3000:])
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["ok"] is True and out["rehearsal"] is True
-    assert out["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    *_, observed, last = r.stdout.strip().splitlines()
+    # the last line holds the contract's keys and no others
+    assert json.loads(last) == {
+        "ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 1},
+    }
+    assert observed.startswith("observations: ")
+    out = json.loads(observed[len("observations: "):])
+    assert out["rehearsal"] is True
     assert out["compile_cache"]["dir"] == str(cache)
     assert out["compile_cache"]["start"] == "cold"
     assert out["compile_cache"]["wave_kernel"].get("miss", 0) >= 1
