@@ -53,8 +53,10 @@ from ..runtime.consensus import (
     QuorumLost,
 )
 from ..runtime.watch import BOOKMARK, Event, Watcher
-from ..utils.metrics import metrics
+from ..utils.metrics import metrics, rest_resource_label
 from ..utils.tracing import TRACE_HEADER, trace_for_binding
+
+_client_sets: dict = {}  # (verb, resource) -> HistogramSet of one series
 
 # connection-pool observability (SIGUSR2 "serving / REST client" section;
 # the serving A/B reads opened vs reused to prove the pool is actually on
@@ -429,7 +431,17 @@ class RESTClient:
         subresources with an unmapped error."""
         data = json.dumps(body).encode() if body is not None else None
         attempt = 0
+        key = (method, rest_resource_label(
+            url[len(self.base):] if url.startswith(self.base)
+            else urlsplit(url).path))
+        hs = _client_sets.get(key)
+        if hs is None:
+            hs = _client_sets[key] = metrics.histogram_set(
+                "rest_client_request_duration_seconds",
+                {"verb": key[0], "resource": key[1]},
+            )
         while True:
+            t0 = time.monotonic()
             status, reason, hdrs, raw = self._http(
                 method,
                 url,
@@ -440,6 +452,11 @@ class RESTClient:
                     **(headers or {}),
                 },
             )
+            # one exchange's round trip as the CLIENT sees it (a degraded
+            # retry's sleep is not in it): set beside the server's
+            # apiserver_request_duration_seconds for the same requests,
+            # the difference is connection, accept and interpreter wait
+            hs.observe((time.monotonic() - t0,))
             if 200 <= status < 300:
                 return raw
             payload = {}

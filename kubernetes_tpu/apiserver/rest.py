@@ -24,8 +24,10 @@ shape client-go's Reflector consumes.
 
 from __future__ import annotations
 
+import bisect
 import json
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional, Tuple
 from urllib.parse import parse_qs, urlparse
@@ -40,6 +42,7 @@ from ..client.apiserver import (
     LeaderFenced,
     NotFound,
     NotPrimary,
+    last_commit_instants,
 )
 from ..runtime.consensus import (
     DegradedWrites,
@@ -48,7 +51,37 @@ from ..runtime.consensus import (
     QuorumLost,
 )
 from ..api.validation import ValidationError
+from ..utils.metrics import DEFAULT_BUCKETS, metrics, rest_resource_label
 from .auth import AdmissionDenied
+
+# a write's stages around the store's own series (store_lock_wait_seconds
+# + store_commit_stage_seconds, which `store` holds exactly)
+REQUEST_STAGES = ("authz", "read", "admit", "store", "observe", "respond")
+_request_sets: dict = {}  # (verb, resource) -> HistogramSet
+# requests in flight: a count under a leaf lock of its own (not the
+# registry's, which every observing thread contends for), published as
+# apiserver_requests_inflight at the scrape
+_inflight_lock = threading.Lock()
+_inflight = [0]
+
+
+def _request_set(verb: str, resource: str):
+    return metrics.histogram_set(
+        "apiserver_request_duration_seconds",
+        {"verb": verb, "resource": resource},
+    ) + metrics.histogram_set(
+        "apiserver_request_stage_seconds",
+        {"resource": resource, "stage": REQUEST_STAGES},
+    )
+
+
+def _publish_inflight() -> None:
+    with _inflight_lock:
+        n = _inflight[0]
+    metrics.set_gauge("apiserver_requests_inflight", float(n))
+
+
+metrics.add_collector(_publish_inflight)
 
 _WATCH_POLL_S = 0.5
 
@@ -644,17 +677,73 @@ class _Handler(BaseHTTPRequestHandler):
         finally:
             sem.release()
 
+    def _served(self, handler):
+        """The one choke point every request passes: audit → limiter →
+        handler, timed as apiserver_request_duration_seconds{verb,
+        resource} with apiserver_requests_inflight beside it. A watch is
+        a stream, not a request: excluded from both. A write handler
+        leaves the instants its stages ended in `_t_authz` / `_t_read` /
+        `_t_store`; together with the two the store took they become
+        apiserver_request_stage_seconds{resource,stage}. All of a
+        request's series are one HistogramSet.observe: this process is
+        the wall of the served path, its accounting costs microseconds."""
+        def chain():
+            return self._audited(lambda: self._limited(handler))
+
+        # (the cheap test first: only a GET with watch= in its query can
+        # be a watch, and _limited parses the query again for those)
+        if (self.command == "GET" and "watch=" in self.path
+                and self._is_long_running()):
+            return chain()
+        self._t_authz = None  # keep-alive: never a previous request's
+        with _inflight_lock:
+            _inflight[0] += 1
+        t0 = time.monotonic()
+        try:
+            return chain()
+        finally:
+            t1 = time.monotonic()
+            with _inflight_lock:
+                _inflight[0] -= 1
+            key = (self.command, rest_resource_label(self.path))
+            hs = _request_sets.get(key)
+            if hs is None:
+                hs = _request_sets[key] = _request_set(*key)
+            t_a = self._t_authz
+            if t_a is None:
+                hs.observe((t1 - t0,))
+            else:
+                t_r, t_s = self._t_read, self._t_store
+                t_w, t_e = last_commit_instants()
+                if not (t_w is not None and t_e is not None
+                        and t_r <= t_w <= t_e <= t_s):
+                    # a store that took no instants: all of it is `store`
+                    t_w = t_e = None
+                hs.observe((
+                    t1 - t0,
+                    t_a - t0,
+                    t_r - t_a,
+                    t_w - t_r if t_w is not None else None,
+                    (t_e - t_w) if t_w is not None else (t_s - t_r),
+                    t_s - t_e if t_e is not None else None,
+                    t1 - t_s,
+                ))
+
+    # instants at which a write handler's stages ended (time.monotonic),
+    # per request; _t_authz None = not a staged request
+    _t_authz = _t_read = _t_store = None
+
     def do_GET(self):
-        return self._audited(lambda: self._limited(self._handle_GET))
+        return self._served(self._handle_GET)
 
     def do_POST(self):
-        return self._audited(lambda: self._limited(self._handle_POST))
+        return self._served(self._handle_POST)
 
     def do_PUT(self):
-        return self._audited(lambda: self._limited(self._handle_PUT))
+        return self._served(self._handle_PUT)
 
     def do_DELETE(self):
-        return self._audited(lambda: self._limited(self._handle_DELETE))
+        return self._served(self._handle_DELETE)
 
     def _handle_GET(self):
         u = urlparse(self.path)
@@ -905,7 +994,7 @@ class _Handler(BaseHTTPRequestHandler):
             self.wfile.flush()
             last_write = _time.monotonic()
 
-        def write_event(ev) -> None:
+        def write_event(ev, live: bool) -> None:
             if binary:
                 write_chunk(watchcodec.event_frame(ev))
             else:
@@ -915,6 +1004,30 @@ class _Handler(BaseHTTPRequestHandler):
                     ).encode()
                     + b"\n"
                 )
+            if live and ev.ts:
+                # Event.ts (the watch cache's fan-out enqueue) -> this
+                # event's bytes handed to the socket: queue wait, encode
+                # and write. Replayed events are as old as the replay
+                # reaches back and would read as lag: left out. Folded
+                # into this stream's own counts (no lock per event) and
+                # merged when the stream idles or every 64 events.
+                lag = last_write - ev.ts
+                delivery[0] += 1
+                delivery[1] += lag
+                delivery[2][bisect.bisect_left(DEFAULT_BUCKETS, lag)] += 1
+                if delivery[0] >= 64:
+                    flush_delivery()
+
+        delivery = [0, 0.0, [0] * (len(DEFAULT_BUCKETS) + 1)]
+
+        def flush_delivery() -> None:
+            if delivery[0]:
+                metrics.merge_histogram(
+                    "apiserver_watch_delivery_seconds", {"kind": resource},
+                    delivery[2], delivery[1], delivery[0],
+                )
+                delivery[0], delivery[1] = 0, 0.0
+                delivery[2] = [0] * (len(DEFAULT_BUCKETS) + 1)
 
         def write_bookmark(rv: int) -> None:
             if binary:
@@ -978,6 +1091,7 @@ class _Handler(BaseHTTPRequestHandler):
             while not self.server.stopping.is_set():
                 ev = watcher.get(timeout=_WATCH_POLL_S)
                 if ev is None:
+                    flush_delivery()
                     if watcher.stopped:
                         break
                     self._release_watch_seat()  # queue drained: init over
@@ -993,6 +1107,7 @@ class _Handler(BaseHTTPRequestHandler):
                     ):
                         write_bookmark(last_rv_sent)
                     continue
+                live = replay_left == 0
                 if replay_left > 0:
                     replay_left -= 1
                     if replay_left == 0:
@@ -1010,7 +1125,7 @@ class _Handler(BaseHTTPRequestHandler):
                     continue
                 if pred is not None and not pred(obj):
                     continue
-                write_event(ev)
+                write_event(ev, live)
                 last_rv_sent = max(last_rv_sent, ev.resource_version)
         except (BrokenPipeError, ConnectionResetError, OSError):
             # decrement on the write-failure path itself: the stream is
@@ -1028,6 +1143,7 @@ class _Handler(BaseHTTPRequestHandler):
                 pass
             watcher.stop()
             gauge_close()
+            flush_delivery()
 
     def _handle_POST(self):
         if self._maybe_proxy():
@@ -1052,6 +1168,11 @@ class _Handler(BaseHTTPRequestHandler):
                 authz_resource = "bindings"
             if not self._authorize("create", authz_resource, ns):
                 return
+        # stages of a write around the store: `authz` (limiter, authn,
+        # routing, authz) ends here; `read` (body read + decode) where the
+        # store call begins; `admit` / `store` / `observe` are split from
+        # the instants the store took; `respond` is what follows its return
+        self._t_authz = self._t_read = self._t_store = time.monotonic()
         try:
             body = self._read_body()
             if resource == "pods" and name and name.endswith("/exec"):
@@ -1116,8 +1237,10 @@ class _Handler(BaseHTTPRequestHandler):
 
                 trace_hdr = self.headers.get(TRACE_HEADER) or ""
                 bind_key = f"{b.pod_namespace}/{b.pod_name}"
+                self._t_read = time.monotonic()
                 with bind_context({bind_key: trace_hdr} if trace_hdr else {}):
                     errs = self.store.bind_pods([b], fence=fence)
+                self._t_store = time.monotonic()
                 if errs and errs[0] is not None:
                     # preserve the store's error taxonomy across the wire
                     # (bind_pods returns the typed exception): a vanished
@@ -1187,7 +1310,10 @@ class _Handler(BaseHTTPRequestHandler):
             obj = codec.decode(resource, body)
             if ns is not None:
                 obj.metadata.namespace = ns
+            self._t_read = time.monotonic()
+            # admission + validation, then the store's own series
             created = self.store.create(resource, obj)
+            self._t_store = time.monotonic()
             return self._json(201, codec.encode(created))
         except AlreadyExists as e:
             return self._status_error(409, "AlreadyExists", str(e))

@@ -28,6 +28,7 @@ from ..api.objects import event_copy
 from ..runtime.watch import ADDED, DELETED, MODIFIED, Event, Watcher
 from ..testing.lockgraph import named_lock, track_attrs
 from ..utils.metrics import metrics
+from ..utils.tracing import note_pass
 
 logger = logging.getLogger("kubernetes_tpu.apiserver")
 
@@ -93,6 +94,49 @@ class LeaderFenced(Conflict):
     fence: a paused leader resuming after a standby promotion gets THIS,
     never a silently applied late bind. Non-retryable by design (the
     caller is not the leader anymore)."""
+
+
+# the instants at which the calling thread's last create/bind began to
+# wait for the store lock and finished its hold: the REST handler reads
+# them so that its `store` stage is exactly what the store's own series
+# (lock wait + commit stages) cover, with `admit` (admission + validation)
+# before it and `observe` (those series being observed, the return) after
+_commit_tls = threading.local()
+
+
+def last_commit_instants() -> Tuple[Optional[float], Optional[float]]:
+    """(began to wait for the store lock, finished its hold) of this
+    thread's most recent create or bind_pods, on time.monotonic()
+    (None: none yet)."""
+    return getattr(_commit_tls, "t_w", None), getattr(_commit_tls, "t_e", None)
+
+
+_COMMIT_STAGES = ("apply", "wal_append", "fsync", "notify")
+_commit_sets: Dict[Tuple[str, str], Any] = {}
+
+
+def _observe_commit(
+    op: str, kind: str, lock_wait: float, apply: float, wal_append: float,
+    fsync: float, notify: float,
+) -> None:
+    """One committed write's time at the store: the wait for the `store`
+    lock, then the stages of its hold — `apply` (checks under the lock,
+    rv bump, copies), `wal_append` (serialise + enqueue or write, and
+    whatever else the log step does besides waiting), `fsync` (the wait),
+    `notify` (watch fan-out). By `kind` as well as `op`: a scheduler over
+    REST creates an Event for every pod it binds, and a pod's create is
+    not an event's. Called AFTER the lock is released; one registry-lock
+    hop for the five series (HistogramSet: a write costs ~3 ms and the
+    apiserver is the wall, so its own accounting has to cost microseconds)."""
+    hs = _commit_sets.get((op, kind))
+    if hs is None:
+        hs = _commit_sets[(op, kind)] = metrics.histogram_set(
+            "store_lock_wait_seconds", {"op": op, "kind": kind}
+        ) + metrics.histogram_set(
+            "store_commit_stage_seconds",
+            {"op": op, "kind": kind, "stage": _COMMIT_STAGES},
+        )
+    hs.observe((lock_wait, apply, wal_append, fsync, notify))
 
 
 class APIServer:
@@ -173,21 +217,24 @@ class APIServer:
         """The write-ahead log behind this store (None: not durable)."""
         return self._wal
 
-    def _log(self, verb: str, kind: str, obj: Any) -> None:
+    def _log(self, verb: str, kind: str, obj: Any) -> float:
         if self._wal is None and self.replicator is None:
-            return
-        self._log_batch([(self._rv, verb, kind, obj)])
+            return 0.0
+        return self._log_batch([(self._rv, verb, kind, obj)])
 
-    def _log_batch(self, records) -> None:
+    def _log_batch(self, records) -> float:
         """records: [(rv, verb, kind, obj)] — one group-committed append,
         then synchronous replication to any attached followers (ack'd
         before the mutation is acknowledged to the client: kill the
-        primary at any point and no acknowledged write is lost)."""
+        primary at any point and no acknowledged write is lost). Returns
+        the seconds of the append spent waiting for the fsync (the
+        `fsync` commit stage; 0.0 without a WAL)."""
         if not records:
-            return
+            return 0.0
+        fsync_s = 0.0
         if self._wal is not None:
             try:
-                self._wal.append_batch(records)
+                fsync_s = self._wal.append_batch(records) or 0.0
             except OSError as e:
                 # the record is NOT durable, so the client must not see an
                 # ack — but the in-memory mutation already applied and is
@@ -220,6 +267,7 @@ class APIServer:
                     )
                     self._notify(kind, Event(ev_type, copy.deepcopy(obj), rv))
                 raise
+        return fsync_s
 
     def _on_wal_disk_failed(self, why: str) -> None:
         """WAL fail-stop callback (fired under the wal lock: flag flips
@@ -305,12 +353,21 @@ class APIServer:
 
     def _compact_async(self) -> None:
         try:
+            t0 = time.monotonic()
             with self._lock:  # cheap structural copies only under the lock
                 rv = self._rv
                 objects = {
                     kind: [copy.deepcopy(o) for o in store.values()]
                     for kind, store in self._objects.items()
                 }
+            # the locked part stalls every API call for its length:
+            # observed after release, and kept for /debug/traces?stalls=1
+            dt = time.monotonic() - t0
+            metrics.observe(
+                "store_background_pass_seconds", dt,
+                {"task": "wal_compact_copy"},
+            )
+            note_pass("wal_compact_copy", t0, dt)
             self._wal.write_snapshot(rv, objects)
             self._compact_failures = 0
         except OSError:
@@ -439,7 +496,11 @@ class APIServer:
         # validated, not raw input) — malformed objects 400 here instead
         # of surfacing later as encode-time scheduler exceptions
         validation.validate_object("create", kind, obj)
+        # the instants are taken under the lock and observed after its
+        # release (_observe_commit): they describe the lock's hold
+        t_w = _commit_tls.t_w = time.monotonic()
         with self._lock:
+            t_l = time.monotonic()
             store = self._objects.setdefault(kind, {})
             key = self._key(obj)
             if key in store:
@@ -453,12 +514,22 @@ class APIServer:
             self._bump(obj)
             stored = copy.deepcopy(obj)
             store[key] = stored
-            self._log("create", kind, stored)
+            t_a = time.monotonic()
+            fsync_s = self._log("create", kind, stored)
+            t_g = time.monotonic()
             self._notify(
                 kind,
                 Event(ADDED, copy.deepcopy(stored), stored.metadata.resource_version),
             )
-            return copy.deepcopy(stored)
+            t_n = time.monotonic()
+            out = copy.deepcopy(stored)
+            t_e = _commit_tls.t_e = time.monotonic()
+        # the reply's copy is part of `apply` (the three deepcopies)
+        _observe_commit(
+            "create", kind, t_l - t_w, (t_a - t_l) + (t_e - t_n),
+            t_g - t_a - fsync_s, fsync_s, t_n - t_g,
+        )
+        return out
 
     def get(self, kind: str, namespace: str, name: str) -> Any:
         namespace = self._normalize_ns(kind, namespace)
@@ -727,12 +798,14 @@ class APIServer:
         still matches the live lease — checked under the same lock the
         binds apply under, so a promotion can never interleave mid-batch.
         """
-        from ..utils.tracing import stamp_bind
+        from ..utils.tracing import stamp_bind, trace_for_binding
 
         self._check_writable()
         errors = []
+        t_w = _commit_tls.t_w = time.monotonic()
         try:
             with self._lock:
+                t_l = time.monotonic()
                 if fence is not None:
                     self._check_fence(fence)
                 records = []  # WAL batch: group-committed in ONE fsync
@@ -765,9 +838,12 @@ class APIServer:
                         errors.append(e)
                 # durable BEFORE any watcher learns of the binds (etcd fires
                 # watch events post-commit); the batch shares one fsync
-                self._log_batch(records)
+                t_a = time.monotonic()
+                fsync_s = self._log_batch(records)
+                t_g = time.monotonic()
                 for ev in events:
                     self._notify("pods", ev)
+                t_n = _commit_tls.t_e = time.monotonic()
         except LeaderFenced as fe:
             # the fenced rejection is a trace event too: a zombie's late
             # bind shows up under the SAME id the deposed scheduler
@@ -779,10 +855,33 @@ class APIServer:
                     detail=str(fe)[:160],
                 )
             raise
+        # one bind call's time inside the store, stage by stage (observed
+        # outside the lock it describes)
+        lock_wait, apply, wal_append, notify = (
+            t_l - t_w, t_a - t_l, t_g - t_a - fsync_s, t_n - t_g
+        )
+        _observe_commit(
+            "bind", "pods", lock_wait, apply, wal_append, fsync_s, notify
+        )
         # store-side stamp: the ack the scheduler's trace resolves to
-        # (outside the store lock — the trace ledger is a leaf concern)
+        # (outside the store lock — the trace ledger is a leaf concern);
+        # it carries the call's stages, so /debug/traces?id= shows where
+        # the bind's time inside the store went (built only for a bind
+        # somebody is tracing)
+        stages = None
         for b, err in zip(bindings, errors):
-            stamp_bind(b, "applied" if err is None else type(err).__name__)
+            event = "applied" if err is None else type(err).__name__
+            if not trace_for_binding(b):
+                continue
+            if stages is None:
+                stages = {
+                    "lock_wait_ms": round(lock_wait * 1e3, 3),
+                    "apply_ms": round(apply * 1e3, 3),
+                    "wal_append_ms": round(wal_append * 1e3, 3),
+                    "fsync_ms": round(fsync_s * 1e3, 3),
+                    "notify_ms": round(notify * 1e3, 3),
+                }
+            stamp_bind(b, event, **stages)
         return errors
 
     def write_events_bulk(self, events_in) -> None:

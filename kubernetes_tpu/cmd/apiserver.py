@@ -57,6 +57,10 @@ def main(argv=None) -> int:
         level=logging.DEBUG if args.verbosity >= 4 else logging.INFO
     )
     log = logging.getLogger("kubernetes_tpu.cmd.apiserver")
+    # GC pauses and the process clock on /metrics (utils/tracing.py)
+    from ..utils.tracing import install_stall_probes
+
+    install_stall_probes()
     serve_kwargs = dict(
         port=args.port,
         watch_cache=bool(args.watch_cache),

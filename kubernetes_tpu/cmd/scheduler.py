@@ -70,6 +70,20 @@ class _HealthHandler(BaseHTTPRequestHandler):
                 self._respond(200, body, "application/json")
             else:
                 self._respond(200, *metrics_payload())
+        elif self.path.split("?", 1)[0] == "/debug/traces":
+            # the same view --debug-port serves (slowest-N, ?id=,
+            # ?stalls=1), on the port /metrics is already scraped from
+            from urllib.parse import parse_qs, urlparse
+
+            from ..utils.debugserver import traces_payload
+
+            q = {k: v[-1]
+                 for k, v in parse_qs(urlparse(self.path).query).items()}
+            code, payload = traces_payload(q)
+            self._respond(
+                code, json.dumps(payload, indent=1).encode(),
+                "application/json",
+            )
         else:
             self._respond(404, b"not found")
 
@@ -378,6 +392,10 @@ def main(argv=None) -> int:
     from ..utils.compilation_cache import enable_persistent_compilation_cache
 
     cache_dir = enable_persistent_compilation_cache()
+    # GC pauses and the process clock on /metrics (utils/tracing.py)
+    from ..utils.tracing import install_stall_probes
+
+    install_stall_probes()
     # initialise the backend HERE: a platform that cannot come up (no chip,
     # or a chip another process holds) fails the start, not the first batch
     devices = jax.devices()

@@ -46,6 +46,7 @@ import logging
 import os
 import threading
 import time
+import weakref
 import zlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -72,6 +73,12 @@ GAUGE_SINK_FAILED = "wal_sink_failed"
 GAUGE_CORRUPT = "wal_recovered_corrupt"
 # slow-disk watchdog: a dying disk's fsyncs stretch long before they fail
 HIST_FSYNC = "wal_fsync_duration_seconds"
+# records per physical fsync is the ratio of these two: records acked,
+# and fsyncs the sink really made (the native committer's own count, or
+# one per _sink_fsync) — HIST_FSYNC is observed once per APPEND, so it
+# cannot show whether the group commit ever groups
+COUNTER_RECORDS = "wal_records_appended_total"
+COUNTER_FSYNCS = "wal_fsyncs_total"
 COUNTER_FSYNC_STALLS = "wal_fsync_stalls_total"
 GAUGE_FSYNC_STALLED = "wal_fsync_stalled"
 # disk-space probe (store-level family: the gate acts on it)
@@ -248,6 +255,24 @@ class WriteAheadLog:
         self._closed = False
         self._failed: Optional[str] = None
         self._good_offset = 0
+        # records acked and physical fsyncs (those of native sinks
+        # already closed + the live committer's own count), kept under
+        # the wal lock and published by a collector at each scrape
+        self._records_total = 0
+        self._fsync_base = 0
+        self._published = (0, 0)
+        # weakly: a WAL nobody closes must still be collectable
+        ref = weakref.ref(self)
+
+        def publish() -> None:
+            wal = ref()
+            if wal is None:
+                metrics.remove_collector(publish)
+            else:
+                wal._publish_counts()
+
+        self._collector = publish
+        metrics.add_collector(publish)
         # fired (once) when the sink poisons, with the reason — the store
         # flips its write gate to disk-failed read-only here. Called with
         # the wal lock held: callbacks must be cheap flag flips and must
@@ -278,6 +303,8 @@ class WriteAheadLog:
     def _close_sink(self) -> None:
         if self._native is not None:
             lib, h = self._native
+            # the committer's count dies with its handle: carry it over
+            self._fsync_base += int(lib.wal_fsync_count(h))
             lib.wal_close(h)
             self._native = None
         if self._f is not None:
@@ -423,11 +450,15 @@ class WriteAheadLog:
     def append(self, rv: int, verb: str, kind: str, obj: Any) -> None:
         self.append_batch([(rv, verb, kind, obj)])
 
-    def append_batch(self, records: List[Tuple[int, str, str, Any]]) -> None:
+    def append_batch(
+        self, records: List[Tuple[int, str, str, Any]]
+    ) -> float:
         """Durably append records IN ORDER; acknowledged once ALL are on
         disk. With the native sink the whole batch (plus any concurrent
-        appenders') shares one fsync."""
-        self._append_lines([self._record(*r) for r in records])
+        appenders') shares one fsync. Returns the seconds of that call
+        spent waiting for the fsync (the store's `fsync` commit stage;
+        the rest — serialising, enqueue or write — is its `wal_append`)."""
+        return self._append_lines([self._record(*r) for r in records])
 
     def append_commit(self, rv: int, commit: int, term: int, event: str) -> None:
         """Durably log a commit-index epoch transition (consensus mode:
@@ -453,9 +484,11 @@ class WriteAheadLog:
         """Python-sink fsync seam (patched by testing/diskfaults.py)."""
         os.fsync(self._f.fileno())
 
-    def _append_lines(self, lines: List[str]) -> None:
+    def _append_lines(self, lines: List[str]) -> float:
+        """Returns the seconds spent in the fsync wait (0.0 with fsync
+        off). Every series is observed after the wal lock is released."""
         if not lines:
-            return
+            return 0.0
         with self._lock:
             if self._failed is not None:
                 raise SinkFailed(f"WAL sink poisoned (fail-stop): {self._failed}")
@@ -466,13 +499,14 @@ class WriteAheadLog:
                 for line in lines:
                     data = line.encode()
                     ticket = lib.wal_enqueue(h, data, len(data))
+                t_w = time.monotonic()
                 if lib.wal_wait(h, ticket) != 0:
                     # the record is NOT durable, the mutation must not be
                     # acknowledged — and the sink can't say whether the
                     # failure was the write or the fsync, so fail-stop
                     self._poison_locked("native sink write/fsync failed")
                     raise SinkFailed("WAL sink write/fsync failed")
-                self._observe_fsync_locked(time.monotonic() - t0)
+                t1 = time.monotonic()
             else:
                 try:
                     self._sink_write("".join(lines))
@@ -481,6 +515,7 @@ class WriteAheadLog:
                         self._repair_enospc_locked(e)  # raises
                     self._poison_locked(f"write failed: {e}")
                     raise SinkFailed(f"WAL write failed: {e}") from e
+                t_w = time.monotonic()
                 if self.fsync:
                     try:
                         self._sink_fsync()
@@ -490,12 +525,33 @@ class WriteAheadLog:
                         # poisons, because retrying can't prove durability
                         self._poison_locked(f"fsync failed: {e}")
                         raise SinkFailed(f"WAL fsync failed: {e}") from e
-                self._observe_fsync_locked(time.monotonic() - t0)
+                    self._fsync_base += 1
+                t1 = time.monotonic()
                 self._good_offset = self._f.tell()
+            self._records_total += len(lines)
             self._since_compact += len(lines)
             if _DEBUG:
                 rvs = [(parse_wal_line(line.rstrip("\n")) or {}).get("rv") for line in lines]
                 _trace(self.path, f"append acked rvs={rvs} native={self._native is not None}")
+        # outside the wal lock: the series describes its hold
+        self._observe_fsync(t1 - t0)
+        return t1 - t_w if self.fsync else 0.0
+
+    def _publish_counts(self) -> None:
+        """Collector: COUNTER_RECORDS / COUNTER_FSYNCS brought up to now."""
+        with self._lock:
+            fsyncs = self._fsync_base
+            if self._native is not None:
+                lib, h = self._native
+                fsyncs += int(lib.wal_fsync_count(h))
+            now = (self._records_total, fsyncs)
+            d_rec = now[0] - self._published[0]
+            d_fsync = now[1] - self._published[1]
+            self._published = now
+        if d_rec > 0:
+            metrics.inc(COUNTER_RECORDS, by=float(d_rec))
+        if d_fsync > 0:
+            metrics.inc(COUNTER_FSYNCS, by=float(d_fsync))
 
     def _repair_enospc_locked(self, cause: OSError) -> None:
         """ENOSPC before fsync is the one recoverable sink error: nothing
@@ -524,7 +580,7 @@ class WriteAheadLog:
             "(log repaired to last acked record; retry after space frees)",
         ) from cause
 
-    def _observe_fsync_locked(self, dt: float) -> None:
+    def _observe_fsync(self, dt: float) -> None:
         if not self.fsync:
             return
         metrics.observe(HIST_FSYNC, dt)
@@ -625,6 +681,8 @@ class WriteAheadLog:
         with self._lock:
             self._closed = True
             self._close_sink()
+        self._publish_counts()
+        metrics.remove_collector(self._collector)
 
     # -- recovery ------------------------------------------------------------
 

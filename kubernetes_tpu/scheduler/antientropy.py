@@ -55,11 +55,13 @@ from __future__ import annotations
 import contextlib
 import logging
 import threading
+import time
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 from ..utils.metrics import metrics
+from ..utils.tracing import note_pass
 
 logger = logging.getLogger("kubernetes_tpu.scheduler.antientropy")
 
@@ -104,12 +106,22 @@ class SnapshotAntiEntropy:
             return
         def loop():
             while not self._stop.wait(self.period_s):
+                t0 = time.monotonic()
                 try:
                     self.audit_once()
                 except Exception:
                     # an audit failure must never take the process down —
                     # it is a diagnostic/repair loop, not a dependency
                     logger.exception("anti-entropy audit pass failed")
+                # the pass takes the cache lock and fetches from the
+                # device under it: its start and length, observed after
+                # the lock is released, are what a stall is matched to
+                dt = time.monotonic() - t0
+                metrics.observe(
+                    "scheduler_background_pass_seconds", dt,
+                    {"task": "antientropy"},
+                )
+                note_pass("antientropy", t0, dt)
         self._thread = threading.Thread(
             target=loop, daemon=True, name="snapshot-antientropy"
         )

@@ -22,6 +22,8 @@ from typing import Dict, List, Optional
 from ...api import objects as v1
 from ...ops.encoding import EncodingConfig, SnapshotEncoder
 from ...testing.lockgraph import named_lock, track_attrs
+from ...utils.metrics import metrics
+from ...utils.tracing import note_pass
 from .nodeinfo import NodeInfo, Snapshot, _has_affinity
 
 logger = logging.getLogger("kubernetes_tpu.scheduler.cache")
@@ -398,7 +400,14 @@ class SchedulerCache:
             return
         def loop():
             while not self._stop.wait(period):
+                t0 = time.monotonic()
                 self.cleanup_expired()
+                dt = time.monotonic() - t0
+                metrics.observe(
+                    "scheduler_background_pass_seconds", dt,
+                    {"task": "assume_ttl"},
+                )
+                note_pass("assume_ttl", t0, dt)
         self._janitor = threading.Thread(target=loop, daemon=True, name="cache-janitor")
         self._janitor.start()
 

@@ -171,6 +171,8 @@ class CacheDebugger:
         if trc:
             lines.append("Dump of tracing pipeline state:")
             lines.extend(trc)
+        lines.append("Dump of stalls (GC pauses, background passes; longest first):")
+        lines.extend(tracing_mod.stall_lines(8))
         return "\n".join(lines)
 
     # -- signal hookup (signal.go:25) ---------------------------------------

@@ -21,7 +21,8 @@ from typing import Callable, Dict, List, Optional
 
 from ...api import objects as v1
 from ...testing.lockgraph import named_lock, track_attrs
-from ...utils.tracing import tracer
+from ...utils.metrics import metrics
+from ...utils.tracing import note_pass, tracer
 from .heap import Heap
 
 
@@ -83,16 +84,28 @@ class PriorityQueue:
     def run(self) -> None:
         """Start flush loops (scheduling_queue.go:234: backoff every 1s,
         unschedulable leftover every 30s)."""
-        for period, fn in ((1.0, self.flush_backoff_completed), (30.0, self._flush_unschedulable_leftover)):
+        for period, fn, task in (
+            (1.0, self.flush_backoff_completed, "queue_flush_backoff"),
+            (30.0, self._flush_unschedulable_leftover,
+             "queue_flush_unschedulable"),
+        ):
             t = threading.Thread(
-                target=self._loop, args=(period, fn), daemon=True
+                target=self._loop, args=(period, fn, task), daemon=True
             )
             t.start()
             self._threads.append(t)
 
-    def _loop(self, period: float, fn) -> None:
+    def _loop(self, period: float, fn, task: str) -> None:
         while not self._stop.wait(period):
+            t0 = time.monotonic()
             fn()
+            # a flush holds the queue lock the scheduling loop pops
+            # under: its start and length, observed outside that lock
+            dt = time.monotonic() - t0
+            metrics.observe(
+                "scheduler_background_pass_seconds", dt, {"task": task}
+            )
+            note_pass(task, t0, dt)
 
     def close(self) -> None:
         self._stop.set()

@@ -73,8 +73,7 @@ from ..parallel.sharded import (
     is_device_loss_error,
 )
 from ..utils.metrics import metrics
-from ..utils.trace import Trace
-from ..utils.tracing import tracer
+from ..utils.tracing import PhaseTracker, tracer
 from .cache.cache import SchedulerCache
 from .config import KubeSchedulerConfiguration
 from .core import FitError, GenericScheduler
@@ -124,6 +123,16 @@ COUNTER_WAVE_TRAILING_READBACKS = "scheduler_wave_trailing_readbacks_total"
 COUNTER_WAVE_TRAILING_UNWOUND = "scheduler_wave_trailing_unwound_assumes_total"
 COUNTER_WAVE_HOSTCB = "scheduler_wave_hostcb_deliveries_total"
 GAUGE_WAVE_TRAILING_BACKLOG = "scheduler_wave_trailing_backlog"
+# pods a wave deferred (feasible nodes existed, in-batch contention ran
+# out of waves) and the most deferrals any pod still waiting has had: a
+# deferral livelock otherwise shows only as pods that never bind
+COUNTER_WAVE_DEFERRED = "scheduler_wave_deferred_pods_total"
+GAUGE_WAVE_DEFERRED_MAX = "scheduler_wave_deferred_max_attempts"
+# a deferred pod re-enters a wave within seconds (readd, or 1-10 s of
+# backoff): an entry untouched this long belongs to a pod that is gone
+_DEFERRED_FORGET_S = 120.0
+# the slow-batch report (rendered from the wave trace) fires at this wall
+_SLOW_BATCH_S = 0.1
 
 
 def _device_ready(arr) -> bool:
@@ -139,6 +148,23 @@ def _device_ready(arr) -> bool:
         return True
 
 
+def _observe_stage(
+    stage: str, t0: float, t1: float,
+    c0: Optional[float] = None, c1: Optional[float] = None,
+) -> None:
+    """One stage of one batch, from instants the caller already took (the
+    same reads close the wave trace's span and switch the loop's phase):
+    wall always, this-thread CPU where the caller read it. Called
+    OUTSIDE the lock the stage ran under."""
+    metrics.observe(
+        "scheduling_stage_duration_seconds", t1 - t0, {"stage": stage}
+    )
+    if c0 is not None and c1 is not None:
+        metrics.observe(
+            "scheduling_stage_cpu_seconds", c1 - c0, {"stage": stage}
+        )
+
+
 @contextmanager
 def _stage_timer(stage: str):
     """Feed the bench's stage_breakdown_s (encode vs kernel time per batch).
@@ -147,35 +173,30 @@ def _stage_timer(stage: str):
     wall inflates with GIL/scheduler starvation from unrelated threads,
     which is unattributable from wall alone (the r5 soak recorded a 30 s
     'finish' wall whose actual work was ~0.7 s). The CPU series is the
-    work; the wall minus CPU is time spent descheduled or blocked."""
+    work; the wall minus CPU is time spent descheduled or blocked.
+
+    For stages that are no boundary of the loop's phases (`finish.*`);
+    a stage that is one takes its instants from the phase switch and
+    reports through _observe_stage."""
     t0 = time.monotonic()
     c0 = time.thread_time()
     try:
         yield
     finally:
-        metrics.observe(
-            "scheduling_stage_duration_seconds",
-            time.monotonic() - t0,
-            {"stage": stage},
-        )
-        metrics.observe(
-            "scheduling_stage_cpu_seconds",
-            time.thread_time() - c0,
-            {"stage": stage},
-        )
+        _observe_stage(stage, t0, time.monotonic(), c0, time.thread_time())
 
 class _InFlightBatch:
     """A wave batch whose kernel is dispatched but whose results haven't
     been read back yet (pipeline depth 1)."""
 
     __slots__ = (
-        "pis", "eb", "row_names", "res", "moves0", "trace", "t_start",
+        "pis", "eb", "row_names", "res", "moves0", "t_start",
         "snapshot", "launch_gen", "wave_tid", "t_launched", "weights",
         "rng_key", "ticket", "trailing",
     )
 
     def __init__(
-        self, pis, eb, row_names, res, moves0, trace, t_start, snapshot=None,
+        self, pis, eb, row_names, res, moves0, t_start, snapshot=None,
         launch_gen=0, wave_tid="", t_launched=0.0, weights=None, rng_key=None,
         ticket=None,
     ):
@@ -184,7 +205,6 @@ class _InFlightBatch:
         self.row_names = row_names
         self.res = res
         self.moves0 = moves0
-        self.trace = trace
         self.t_start = t_start
         # per-wave trace (utils/tracing.py): the fan-in id the N pod
         # traces of this batch reference, plus the launch-complete stamp
@@ -229,6 +249,7 @@ class _TrailingReadback:
     __slots__ = (
         "score", "placed", "to_bind", "launch_gen", "wave_tid", "pin",
         "binds_issued", "quarantined", "gated", "t_registered", "path",
+        "finish_outcome",
     )
 
     def __init__(
@@ -252,6 +273,10 @@ class _TrailingReadback:
         self.gated = False
         self.t_registered = time.monotonic()
         self.path = path
+        # set instead of finishing the wave trace when this entry is
+        # consumed by its OWN batch's pre-bind gate: the commit is still
+        # under way, and its bind span must land before the trace closes
+        self.finish_outcome = ""
 
     def ready(self) -> bool:
         return _device_ready(self.score)
@@ -412,6 +437,14 @@ class Scheduler:
         # of the reference's async binding goroutine overlapping the next
         # scheduleOne, scheduler.go:666, taken to its batch conclusion).
         self._pending: List[_InFlightBatch] = []
+        # the scheduling loop's wall, phase by phase (utils/tracing.py):
+        # switched by the loop thread at every stage boundary below, read
+        # by the /metrics scrape; each phase is also a ktpu.loop.<phase>
+        # annotation on the profiler's host plane while a session runs
+        self._phase = PhaseTracker(annotate=jax.profiler.TraceAnnotation)
+        # pod key -> [deferrals, last deferred at]: scheduling-loop
+        # thread only (scheduler_wave_deferred_max_attempts)
+        self._deferred_counts: Dict[str, list] = {}
         self._wave_inflight_peak = 0  # high-water mark of len(_pending)
         self._wave_batch_pods_peak = 0  # most pods in one wave launch
         # split-phase readback (round 17): resolve on the fast index
@@ -771,6 +804,8 @@ class Scheduler:
             self._auditor.start()
         self.queue.run()
         self.cache.start_janitor()
+        # scraped from the moment the loop exists (dropped by stop())
+        metrics.add_collector(self._phase.publish)
         self._sched_thread = threading.Thread(
             target=self._scheduling_loop, daemon=True, name="scheduler"
         )
@@ -1004,6 +1039,9 @@ class Scheduler:
         # them (the loop is dead, so nobody else will release them)
         if self._trailing:
             self._drain_trailing(block=True)
+        # last publish of the loop's phases, then stop being scraped
+        self._phase.publish()
+        metrics.remove_collector(self._phase.publish)
         if self._owned_read_cache is not None:
             self._owned_read_cache.stop()
         # release parked permit-waiters or the drain below would block on
@@ -1063,13 +1101,22 @@ class Scheduler:
         self._busy = True
 
     def _scheduling_loop(self) -> None:
+        try:
+            self._scheduling_loop_body()
+        finally:
+            self._phase.close()
+
+    def _scheduling_loop_body(self) -> None:
+        ph = self._phase
         while not self._stop.is_set():
             # Circuit breaker: the store refused binds with a retryable
             # 503. Pause batch dispatch (informers, queue, and the HBM
             # snapshot stay warm) and probe for recovery; the queue keeps
             # accumulating instead of failing waves into unschedulableQ.
             if self._ridethrough.open:
+                ph.switch("ridethrough")
                 self._ride_through_degraded()
+                ph.switch("other")
                 continue
             # Batch-fill policy: the wave kernel's cycle cost is nearly
             # batch-size-independent (per-wave [TPL, N] work dominates), so
@@ -1113,12 +1160,14 @@ class Scheduler:
             # first pod leaves the queue, so wait_for_idle can never
             # observe "queue empty, nothing in flight" while a popped
             # batch is still on its way into the pipeline
+            ph.switch("pop")
             pis = self.queue.pop_batch(
                 self._batch_size,
                 timeout=0.0 if inflight else 0.2,
                 window=0.0 if inflight else self.cfg.device_batch_window,
                 on_first=self._mark_busy,
             )
+            ph.switch("other")
             if not pis:
                 if self._pending:
                     # stay busy across the drain: _resolve_oldest detaches
@@ -1167,6 +1216,7 @@ class Scheduler:
                 logger.exception("degraded-mode pipeline flush failed")
             finally:
                 self._busy = False
+            self._phase.switch("ridethrough")
         if self._stop.wait(self._ridethrough.next_probe_delay()):
             return
         # cheap introspection first: an in-process store exposes its write
@@ -1448,9 +1498,22 @@ class Scheduler:
             f"Successfully assigned {pi.pod.metadata.key} to {node_name}",
         )
 
+    def _warn_if_slow(self, t_start: float, n_pods: int, path: str) -> None:
+        """The slow-batch line of a batch that rode no wave (host lanes,
+        the serial device path): its wall only — a wave's report is
+        rendered from its trace, span by span (_finish_batch)."""
+        total = time.monotonic() - t_start
+        if total >= _SLOW_BATCH_S:
+            logger.warning(
+                '"schedule_batch" %s (%.1fms): %s',
+                {"pods": n_pods}, total * 1e3, path,
+            )
+
     def schedule_pod_batch(self, pis: List[QueuedPodInfo]) -> None:
-        trace = Trace("schedule_batch", pods=len(pis))
-        t_start = time.monotonic()
+        # one read: the cycle's start, the end of every pod's queue wait,
+        # and the loop's switch into `prepare` (queue spans, profiles and
+        # extenders per pod, until the wait for the cache lock)
+        t_start = self._phase.switch("prepare")
         # close every pod's queue-wait span (last queue ENTRY -> cycle
         # start) in ONE ring acquisition; requeued pods accumulate one
         # `queue` span per attempt, which is the honest attribution
@@ -1478,10 +1541,13 @@ class Scheduler:
         if extender_pis:
             # host path reads the host cache: in-flight replays must land
             self._resolve_pending()
-        for pi in extender_pis:
-            # _schedule_one_host re-snapshots per pod
-            self._schedule_one_host(pi, moves0, "extender")
+            self._phase.switch("host")
+            for pi in extender_pis:
+                # _schedule_one_host re-snapshots per pod
+                self._schedule_one_host(pi, moves0, "extender")
+            self._phase.switch("prepare")
         if not known:
+            self._phase.switch("other")
             return
         # the device-down latch (unrecoverable device loss) degrades every
         # batch to the host path — correctness over throughput
@@ -1501,23 +1567,27 @@ class Scheduler:
             # consistent: the host path resolves in-flight batches and its
             # binds dirty the encoder rows like any informer write.
             self._resolve_pending()
+            self._phase.switch("host")
             for pi in known:
                 self._schedule_one_host(pi, moves0, "small_batch")
-            trace.log_if_long(0.1)
+            self._phase.switch("other")
+            self._warn_if_slow(t_start, len(pis), "small_batch host lane")
             return
         if use_device and self.cfg.use_wave:
-            self._schedule_batch_wave(known, moves0, trace, t_start)
+            self._schedule_batch_wave(known, moves0, t_start)
         elif use_device:
             self._resolve_pending()
-            self._schedule_batch_device(known, moves0, trace, t_start)
-            trace.log_if_long(0.1)
+            self._schedule_batch_device(known, moves0, t_start)
+            self._warn_if_slow(t_start, len(pis), "serial device path")
         else:
             self._resolve_pending()
+            self._phase.switch("host")
             self._snapshot = self.cache.update_snapshot()
             lane = "degraded" if self._device_down else "host_only"
             for pi in known:
                 self._schedule_one_host(pi, moves0, lane)
-            trace.log_if_long(0.1)
+            self._phase.switch("other")
+            self._warn_if_slow(t_start, len(pis), f"{lane} host lane")
 
     # -- device path ---------------------------------------------------------
 
@@ -1529,7 +1599,7 @@ class Scheduler:
         return p
 
     def _schedule_batch_device(
-        self, pis: List[QueuedPodInfo], moves0: int, trace: Trace, t_start: float
+        self, pis: List[QueuedPodInfo], moves0: int, t_start: float
     ) -> None:
         # device-loss ride-through, serial-path edition (launch+readback
         # are one synchronous call here): bounded jittered retries, then
@@ -1542,8 +1612,12 @@ class Scheduler:
         # choices against the wrong nodes (same reason the wave wrapper
         # re-encodes per retry).
         attempts = 0
+        ph = self._phase
         while True:
-            with self.cache.lock, _stage_timer("encode"):
+            t_lw = ph.switch("lock_wait")
+            with self.cache.lock:
+                t_e0 = ph.switch("encode")
+                c_e0 = time.thread_time()
                 eb = encode_pod_batch(
                     self.cache.encoder,
                     [pi.pod for pi in pis],
@@ -1553,16 +1627,28 @@ class Scheduler:
                 enc_cfg = self.cache.encoder.cfg
                 row_names = list(self.cache.encoder.row_names)
                 launch_gen = self.cache._ext_generation
-            trace.step("encoded+flushed")
+                c_e1 = time.thread_time()
+                t_e1 = ph.switch("other")
+            # observed after release: the series describe the lock's hold
+            metrics.observe("scheduler_cache_lock_wait_seconds", t_e0 - t_lw)
+            _observe_stage("encode", t_e0, t_e1, c_e0, c_e1)
             kern = make_schedule_batch(
                 enc_cfg.v_cap, self.cfg.hard_pod_affinity_weight
             )
             self._rng_key, sub = jax.random.split(self._rng_key)
             w_launch = np.asarray(self._weights)
+            # launch + readback are one synchronous call on this path
+            t_k0 = ph.switch("readback", inflight=True)
+            c_k0 = time.thread_time()
             try:
-                with _stage_timer("kernel"):
+                try:
                     res, chosen, score = self._run_serial_kernel(
                         kern, snap, eb.batch, sub, w_launch
+                    )
+                finally:
+                    _observe_stage(
+                        "kernel", t_k0, ph.switch("other", inflight=False),
+                        c_k0, time.thread_time(),
                     )
                 self._consecutive_device_loss = 0
                 break
@@ -1602,7 +1688,6 @@ class Scheduler:
                 for pi in pis:
                     self._schedule_one_host(pi, moves0, "degraded")
                 return
-        trace.step("kernel")
         algo_dur = time.monotonic() - t_start
         if self.cfg.kernel_output_guards:
             # mask with `!= -1`, not `>= 0`: -1 is the kernel's ONLY
@@ -1833,7 +1918,7 @@ class Scheduler:
         )
 
     def _schedule_batch_wave(
-        self, pis: List[QueuedPodInfo], moves0: int, trace: Trace, t_start: float
+        self, pis: List[QueuedPodInfo], moves0: int, t_start: float
     ) -> None:
         """Device-loss ride-through wrapper around the wave launch:
         a launch that dies with a device-loss error gets bounded jittered
@@ -1846,7 +1931,7 @@ class Scheduler:
         attempts = 0
         while True:
             try:
-                self._schedule_batch_wave_once(pis, moves0, trace, t_start)
+                self._schedule_batch_wave_once(pis, moves0, t_start)
                 self._consecutive_device_loss = 0
                 return
             except Exception as e:  # noqa: BLE001 — classifier filters
@@ -2066,9 +2151,15 @@ class Scheduler:
             self._release_trailing_pin(entry)
             tracer.finish(entry.wave_tid, outcome="trailing_sibling")
             return
-        t0 = time.monotonic()
+        # trailing work interrupts whatever phase the loop was in (the
+        # pre-launch drain, the pre-bind gate, the idle beat) and hands
+        # it back: t0 / t_f1 / t1 are the only reads
+        ph = self._phase
+        resume = ph.phase
+        t0 = ph.switch("trailing")
+        c0 = time.thread_time()
         try:
-            with _stage_timer("trailing"):
+            try:
                 score = call_with_device_retry(
                     lambda: self._fetch_wave_bulk([entry]),
                     attempts=self.cfg.device_retry_attempts,
@@ -2077,6 +2168,10 @@ class Scheduler:
                         {"stage": "trailing"},
                     ),
                 )[0]
+            finally:
+                _observe_stage(
+                    "trailing", t0, time.monotonic(), c0, time.thread_time()
+                )
             metrics.inc(COUNTER_WAVE_TRAILING_READBACKS)
         except Exception as e:
             logger.exception("trailing bulk readback failed")
@@ -2085,6 +2180,7 @@ class Scheduler:
                     "scheduler_device_loss_total", {"stage": "trailing"}
                 )
             self._unwind_trailing(entry, GUARD_TRAILING_LOSS, str(e))
+            ph.switch(resume)
             return
         finally:
             self._release_trailing_pin(entry)
@@ -2093,11 +2189,15 @@ class Scheduler:
             reason = validate_trailing_score(score, entry.placed)
         if reason is not None:
             self._unwind_trailing(entry, reason)
+            ph.switch(resume)
             return
         self._consecutive_guard_trips = 0
-        t1 = time.monotonic()
+        t1 = ph.switch(resume)
         tracer.add_span(entry.wave_tid, "trailing", t0, t1)
-        tracer.finish(entry.wave_tid, outcome="committed")
+        if entry.gated:
+            entry.finish_outcome = "committed"  # _commit_batch finishes it
+        else:
+            tracer.finish(entry.wave_tid, outcome="committed")
 
     def _release_trailing_pin(self, entry: "_TrailingReadback") -> None:
         pin, entry.pin = entry.pin, None
@@ -2165,7 +2265,7 @@ class Scheduler:
             self._set_device_down()
 
     def _schedule_batch_wave_once(
-        self, pis: List[QueuedPodInfo], moves0: int, trace: Trace, t_start: float
+        self, pis: List[QueuedPodInfo], moves0: int, t_start: float
     ) -> None:
         """Launch the wave kernel for this batch; resolve the PREVIOUS
         in-flight batch while this one computes (depth-1 pipeline)."""
@@ -2201,10 +2301,17 @@ class Scheduler:
         # can intern predicates and dirty rows)
         if self._pending and self.cache.encoder.has_pending_updates:
             self._resolve_pending()
+        ph = self._phase
         while True:
-            with self.cache.lock, _stage_timer("encode"):
+            # every instant below is ONE read shared by the loop's phase,
+            # the stage histograms and the wave trace; the histograms are
+            # observed after the lock is released
+            t_lw = ph.switch("lock_wait")
+            with self.cache.lock:
+                t_e0 = ph.switch("encode")
+                c_e0 = time.thread_time()
+                t_f0 = None
                 eb = self._tpl_cache.encode([pi.pod for pi in pis], pad_to=pad)
-                trace.step("tpl-encode")
                 ptab = self._pair_table(eb)
                 n_waves, batch_has_hard = self._batch_waves(eb)
                 if small_bucket and not batch_has_hard:
@@ -2213,11 +2320,11 @@ class Scheduler:
                     # loser just requeues — 2 waves suffice and halve the
                     # small-cycle cost
                     n_waves = min(n_waves, 2)
-                trace.step("pair-table")
                 if (
                     not self._pending
                     or not self.cache.encoder.has_pending_updates
                 ):
+                    t_f0 = time.monotonic()
                     snap = self.cache.encoder.flush()
                     enc_cfg = self.cache.encoder.cfg
                     row_names = list(self.cache.encoder.row_names)
@@ -2231,9 +2338,19 @@ class Scheduler:
                         else None
                     )
                     launch_gen = self.cache._ext_generation
-                    break
+                c_e1 = time.thread_time()
+                # flushed: `launch` begins where the lock's hold ends —
+                # picking the kernel variant, the PRNG split, the dispatch
+                t_e1 = ph.switch("launch" if t_f0 is not None else "other")
+            metrics.observe("scheduler_cache_lock_wait_seconds", t_e0 - t_lw)
+            # `encode` keeps its meaning: the lock's hold (template
+            # encode, pair table, flush), without the wait for the lock
+            _observe_stage("encode", t_e0, t_e1, c_e0, c_e1)
+            if t_f0 is not None:
+                _observe_stage("flush", t_f0, t_e1)
+                break
             self._resolve_pending()
-        trace.step("flush")
+        t_launch0 = t_e1
         # static pinnedness: compiling the pinned-row plan only into
         # batches that carry pinned pods keeps the common path lean (two
         # variants max per config; pod_name_row is host-resident numpy)
@@ -2258,19 +2375,20 @@ class Scheduler:
 
         self._rng_key, sub = jax.random.split(self._rng_key)
         w_launch = np.asarray(self._weights)
-        t_launch0 = time.monotonic()
         try:
             new_snap, res = self._launch_wave_kernel(
                 kern, snap, eb.batch, ptab, w_launch, sub
             )
         except Exception:
+            ph.switch("other")
             if ticket is not None:
                 hostcallback.discard(ticket)
             with self.cache.lock:
                 self.cache.encoder.invalidate_device()
             raise
-        trace.step("launch")
-        t_launched = time.monotonic()
+        # from here the chip holds this batch: inflight until the index
+        # payload of the LAST pending batch has been read back
+        t_launched = ph.switch("record", inflight=True)
         # wave-level trace: ONE record for the kernel launch the whole
         # batch shares — each pod's span chain carries `wave=<id>` so a
         # slow wave explains its N slow pods in one lookup
@@ -2287,7 +2405,7 @@ class Scheduler:
         # new_snap as the live generation — nothing to publish here
         self._pending.append(
             _InFlightBatch(
-                pis, eb, row_names, res, moves0, trace, t_start, verify_snap,
+                pis, eb, row_names, res, moves0, t_start, verify_snap,
                 launch_gen, wave_tid, t_launched, w_launch, sub, ticket,
             )
         )
@@ -2322,6 +2440,9 @@ class Scheduler:
                 n_ready += 1
             if n_ready:
                 self._resolve_oldest(n_ready)
+        # the wave trace, its pods' spans and the launch's gauges were
+        # `record` (unless a resolve above has moved on already)
+        ph.switch("other")
 
     def _fast_payload_ready(self, b: "_InFlightBatch") -> bool:
         if b.ticket is not None and hostcallback.ready(b.ticket):
@@ -2344,22 +2465,32 @@ class Scheduler:
         same capacity twice."""
         if k <= 0:
             return
+        try:
+            self._resolve_batches(k)
+        finally:
+            # after the frame below is gone: releasing the batches (their
+            # device arrays, encodings, row tables) is part of `finish`
+            self._phase.switch("other")
+
+    def _resolve_batches(self, k: int) -> None:
         batches, self._pending = self._pending[:k], self._pending[k:]
         metrics.set_gauge(GAUGE_WAVE_INFLIGHT, float(len(self._pending)))
         split = self._split_phase
-        t_rb0 = time.monotonic()
-        with _stage_timer("kernel"):
+        ph = self._phase
+        t_rb0 = ph.switch("readback")
+        c_rb0 = time.thread_time()
+        try:
+            # transient device blips get bounded jittered
+            # retries (the fetched refs are re-gettable — no donation
+            # on the read side) before the loss path takes over.
+            # Split mode fetches ONLY the index payload here; the bulk
+            # score trails through _fetch_wave_bulk off this path.
+            fetch = (
+                self._fetch_wave_index
+                if split
+                else self._fetch_wave_results
+            )
             try:
-                # transient device blips get bounded jittered
-                # retries (the fetched refs are re-gettable — no donation
-                # on the read side) before the loss path takes over.
-                # Split mode fetches ONLY the index payload here; the bulk
-                # score trails through _fetch_wave_bulk off this path.
-                fetch = (
-                    self._fetch_wave_index
-                    if split
-                    else self._fetch_wave_results
-                )
                 fetched = call_with_device_retry(
                     lambda: fetch(batches),
                     attempts=self.cfg.device_retry_attempts,
@@ -2368,42 +2499,50 @@ class Scheduler:
                         {"stage": "readback"},
                     ),
                 )
-                self._consecutive_device_loss = 0
-            except Exception as e:
-                for b in batches:
-                    if b.ticket is not None:
-                        hostcallback.discard(b.ticket)
-                    tracer.finish(b.wave_tid, outcome="readback_failed")
-                    for pi in b.pis:
-                        tracer.event(pi.trace_id, "readback.failed")
-                # device error: the kernels' on-device commits are
-                # unknowable — rebuild HBM from the host masters and retry
-                with self.cache.lock:
-                    self.cache.encoder.invalidate_device()
-                logger.exception(
-                    "wave pipeline readback failed (%d batches)", len(batches)
+            finally:
+                # the host's wait for the index payload: stage="kernel"
+                # (its old name), the `readback` span and phase — one
+                # pair of reads. The chip is idle again unless a younger
+                # batch is still pending.
+                t_rb1 = ph.switch("guard", inflight=bool(self._pending))
+                _observe_stage(
+                    "kernel", t_rb0, t_rb1, c_rb0, time.thread_time()
                 )
-                lost = is_device_loss_error(e)
-                if lost:
-                    metrics.inc(
-                        "scheduler_device_loss_total", {"stage": "readback"}
-                    )
-                    self._handle_device_loss(e)
-                moves = self.queue.moves_snapshot()
-                for b in batches:
-                    for pi in b.pis:
-                        if self.cache.has_pod(pi.pod.metadata.key):
-                            continue
-                        if lost:
-                            # infrastructure failure, not pod
-                            # unschedulability: backoff retries in 1-10 s
-                            # instead of sitting out unschedulableQ's
-                            # 30-60 s leftover flush
-                            self.queue.requeue_backoff(pi)
-                        else:
-                            self.queue.add_unschedulable_if_not_present(pi, moves)
-                return
-        t_rb1 = time.monotonic()
+            self._consecutive_device_loss = 0
+        except Exception as e:
+            for b in batches:
+                if b.ticket is not None:
+                    hostcallback.discard(b.ticket)
+                tracer.finish(b.wave_tid, outcome="readback_failed")
+                for pi in b.pis:
+                    tracer.event(pi.trace_id, "readback.failed")
+            # device error: the kernels' on-device commits are
+            # unknowable — rebuild HBM from the host masters and retry
+            with self.cache.lock:
+                self.cache.encoder.invalidate_device()
+            logger.exception(
+                "wave pipeline readback failed (%d batches)", len(batches)
+            )
+            lost = is_device_loss_error(e)
+            if lost:
+                metrics.inc(
+                    "scheduler_device_loss_total", {"stage": "readback"}
+                )
+                self._handle_device_loss(e)
+            moves = self.queue.moves_snapshot()
+            for b in batches:
+                for pi in b.pis:
+                    if self.cache.has_pod(pi.pod.metadata.key):
+                        continue
+                    if lost:
+                        # infrastructure failure, not pod
+                        # unschedulability: backoff retries in 1-10 s
+                        # instead of sitting out unschedulableQ's
+                        # 30-60 s leftover flush
+                        self.queue.requeue_backoff(pi)
+                    else:
+                        self.queue.add_unschedulable_if_not_present(pi, moves)
+            return
         for b in batches:
             # fan-in: the shared device wait (launch -> resolve entry) and
             # the combined readback land on the wave trace AND on every
@@ -2415,7 +2554,13 @@ class Scheduler:
             tracer.add_span_many(tids, "readback", t_rb0, t_rb1)
         tails = []
         quarantined = False
+        # the first batch's guard stage starts where the readback ended;
+        # a younger sibling's where its elder's commit ended
+        t_g0 = t_rb1
         for b, arrays in zip(batches, fetched):
+            if t_g0 is None:
+                t_g0 = ph.switch("guard")
+            t_guard0, t_g0 = t_g0, None
             if quarantined:
                 # an older sibling's output failed validation: this
                 # batch's kernel chained on the same suspect snapshot —
@@ -2438,7 +2583,7 @@ class Scheduler:
                 # readback — validation/decode below run with score=None
                 arrays = (*arrays, None)
             try:
-                tails.append(self._commit_batch(b, arrays, t_rb1))
+                tails.append(self._commit_batch(b, arrays, t_rb1, t_guard0))
                 if b.trailing is None:
                     # combined mode — or a split batch that placed
                     # nothing: the guard story is complete right here.
@@ -2465,6 +2610,7 @@ class Scheduler:
                 for pi in b.pis:
                     if not self.cache.has_pod(pi.pod.metadata.key):
                         self.queue.add_unschedulable_if_not_present(pi, moves)
+        ph.switch("finish")
         for b, tail in zip(batches, tails):
             if tail is None:
                 continue
@@ -2479,8 +2625,36 @@ class Scheduler:
                 for pi, _i in tail[1]:
                     self.queue.add_unschedulable_if_not_present(pi, moves)
 
+    def _note_deferrals(self, p: "_InFlightBatch", deferred_pis: List) -> None:
+        """scheduler_wave_deferred_pods_total and the most deferrals any
+        pod still waiting has had. A pod of this batch that was not
+        deferred has left that state (placed, failed or fallen back)."""
+        counts = self._deferred_counts
+        if not deferred_pis and not counts:
+            return
+        now = time.monotonic()
+        deferred_keys = set()
+        for pi in deferred_pis:
+            c = counts.setdefault(pi.key, [0, now])
+            c[0] += 1
+            c[1] = now
+            deferred_keys.add(pi.key)
+        for pi in p.pis:
+            if pi.key not in deferred_keys:
+                counts.pop(pi.key, None)
+        for key in [k for k, c in counts.items()
+                    if now - c[1] > _DEFERRED_FORGET_S]:
+            del counts[key]
+        if deferred_pis:
+            metrics.inc(COUNTER_WAVE_DEFERRED, by=float(len(deferred_pis)))
+        metrics.set_gauge(
+            GAUGE_WAVE_DEFERRED_MAX,
+            float(max((c[0] for c in counts.values()), default=0)),
+        )
+
     def _commit_batch(
-        self, p: "_InFlightBatch", arrays, t_rb1: Optional[float] = None
+        self, p: "_InFlightBatch", arrays, t_rb1: Optional[float] = None,
+        t_guard0: Optional[float] = None,
     ) -> tuple:
         """Act on one read-back batch's placements: assume + bind, re-add
         deferred pods. Returns (fallback_pis, failed) for _finish_batch.
@@ -2489,12 +2663,14 @@ class Scheduler:
 
         t_rb1: the combined readback's completion stamp — the pod traces'
         `guard` span runs from it to the assume hand-off, so waiting out
-        an earlier sibling's commit is attributed, not lost in a gap."""
+        an earlier sibling's commit is attributed, not lost in a gap.
+        t_guard0: where THIS batch's guard work began on the loop's clock
+        (t_rb1 for the eldest batch, the elder's commit end for a
+        sibling): stage="guard" and the wave's `guard` span run from it."""
         pis, eb, row_names = p.pis, p.eb, p.row_names
         chosen, placed, deferred, score = arrays
-        trace, t_start = p.trace, p.t_start
-        trace.step("kernel")
-        algo_dur = time.monotonic() - t_start
+        t_start = p.t_start
+        algo_dur = (t_guard0 or time.monotonic()) - t_start
         metrics.observe("scheduling_algorithm_duration_seconds", algo_dur)
         if self.cfg.kernel_output_guards:
             # structural validation first: the decode loop below indexes
@@ -2564,12 +2740,23 @@ class Scheduler:
                 self.queue.readd(pi)
             else:
                 self.queue.requeue_backoff(pi)
+        self._note_deferrals(p, deferred_pis)
+        # the guard stage ends here (one read): the hand-off to assume.
+        # Registering the trailing half (split-phase) is `trailing` work;
+        # _assume_and_bind_bulk switches to `assume`.
+        t_g1 = self._phase.switch(
+            "trailing" if self._split_phase else "other"
+        )
+        if t_guard0 is not None:
+            _observe_stage("guard", t_guard0, t_g1)
+            tracer.add_span(p.wave_tid, "guard", t_guard0, t_g1)
         if t_rb1 is not None:
-            # guard = readback done -> assume hand-off (output validation,
-            # decode, oracle sample, and any elder-sibling commit wait)
+            # pods: guard = readback done -> assume hand-off (output
+            # validation, decode, oracle sample, and any elder-sibling
+            # commit wait)
             tracer.add_span_many(
                 [pi.trace_id for pi, _n, _b, _p in to_bind],
-                "guard", t_rb1, time.monotonic(),
+                "guard", t_rb1, t_g1,
             )
 
         entry = None
@@ -2599,8 +2786,10 @@ class Scheduler:
                 if entry is not None
                 else None
             ),
+            wave_tid=p.wave_tid,
         )
-        trace.step("assume+bind")
+        if entry is not None and entry.finish_outcome:
+            tracer.finish(p.wave_tid, outcome=entry.finish_outcome)
         if entry is not None and not entry.quarantined:
             entry.binds_issued = True
         if entry is None or not entry.quarantined:
@@ -2670,6 +2859,7 @@ class Scheduler:
                 # before each resolve)
                 with _stage_timer("finish.resolve"):
                     self._resolve_pending()
+                    self._phase.switch("finish")
                 with _stage_timer("finish.snapshot"):
                     self._snapshot = self.cache.update_snapshot()
             if fallback_pis:
@@ -2679,7 +2869,14 @@ class Scheduler:
             if failed:
                 with _stage_timer("finish.failed"):
                     self._finish_failed(p, failed)
-        p.trace.log_if_long(0.1)
+        if time.monotonic() - p.t_start >= _SLOW_BATCH_S:
+            # the slow-batch report, span by span from the wave's trace
+            # (none with KTPU_TRACING=0: there is no wave trace then)
+            report = tracer.render_if_long(
+                p.wave_tid, "schedule_batch", _SLOW_BATCH_S
+            )
+            if report:
+                logger.warning(report)
 
     def _finish_failed(self, p: "_InFlightBatch", failed: List) -> None:
         eb, row_names, res, moves0 = p.eb, p.row_names, p.res, p.moves0
@@ -3277,7 +3474,7 @@ class Scheduler:
 
     def _assume_and_bind_bulk(
         self, to_bind: List, t_start: float, device_synced: bool = False,
-        trailing_gate=None,
+        trailing_gate=None, wave_tid: str = "",
     ) -> None:
         """Assume + bind a whole wave of placements ((pi, node, band,
         proto) tuples; proto may be None for host-path placements). When
@@ -3288,7 +3485,8 @@ class Scheduler:
         goroutine-per-bind at scheduler.go:666)."""
         if not to_bind:
             return
-        t_a0 = time.monotonic()
+        ph = self._phase
+        t_a0 = ph.switch("assume")
         # ONE lock acquisition + vectorized encoder scatters for the whole
         # wave (device_synced path); the host fallback path still assumes
         # per pod through the same cache method semantics
@@ -3311,11 +3509,17 @@ class Scheduler:
                     errors.append(None)
                 except ValueError as e:
                     errors.append(str(e))
+        # one read: the end of the assume stage, span and phase; the
+        # pre-bind gate is `trailing` work, the building of the bind call
+        # rides `other`
+        t_a1 = ph.switch("trailing" if trailing_gate is not None else "other")
+        _observe_stage("assume", t_a0, t_a1)
+        tracer.add_span(wave_tid, "assume", t_a0, t_a1)
         tracer.add_span_many(
             [pi.trace_id
              for (pi, _n, _b, _p), err in zip(to_bind, errors)
              if err is None],
-            "assume", t_a0, time.monotonic(),
+            "assume", t_a0, t_a1,
         )
         if trailing_gate is not None and trailing_gate():
             # split-phase last-look: between assume and bind the trailing
@@ -3337,7 +3541,10 @@ class Scheduler:
                 metrics.inc(COUNTER_WAVE_TRAILING_UNWOUND)
                 tracer.event(pi.trace_id, "wave.trailing_unwound")
                 self.queue.requeue_backoff(pi)
+            ph.switch("other")
             return
+        if trailing_gate is not None:
+            ph.switch("other")
         simple: List = []
         for (pi, node_name, band, proto), err in zip(to_bind, errors):
             pod = pi.pod
@@ -3377,7 +3584,7 @@ class Scheduler:
             )
             for pi, node_name, _ in simple
         ]
-        b0 = time.monotonic()
+        b0 = ph.switch("bind")
         try:
             errors = self._bind_pods_fenced(bindings)
         except DegradedWrites as e:
@@ -3389,11 +3596,15 @@ class Scheduler:
         except LeaderFenced:
             # zombie ex-leader: the store holds a newer leadership grant.
             # Nothing applied — drop every placement and stand down.
+            ph.switch("other")
             self._on_fenced_binds([pi for pi, _n, _p in simple])
             return
-        t_b1 = time.monotonic()
+        # one read: the bind call's end, span and phase; what follows is
+        # the per-pod bookkeeping of the bound pods (`record`)
+        t_b1 = ph.switch("record")
         bind_dur = t_b1 - b0
         e2e = t_b1 - t_start
+        tracer.add_span(wave_tid, "bind", b0, t_b1)
         tracer.add_span_many(
             [pi.trace_id
              for (pi, _n, _p), err in zip(simple, errors)
@@ -3428,6 +3639,7 @@ class Scheduler:
                 )
         if to_buffer:
             self._buffer_pending_binds(to_buffer)
+        ph.switch("other")
 
     def _assume_and_bind_after_assume(
         self, pi: QueuedPodInfo, node_name: str, t_start: float

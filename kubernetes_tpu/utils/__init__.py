@@ -1,5 +1,4 @@
-"""Shared infra: metrics registry, step tracing, feature gates (component-base-lite)."""
+"""Shared infra: metrics registry, span tracing, feature gates (component-base-lite)."""
 
 from .metrics import Metrics, metrics  # noqa: F401
-from .trace import Trace  # noqa: F401
 from .featuregate import FeatureGate, default_feature_gate  # noqa: F401

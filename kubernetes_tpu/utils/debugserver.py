@@ -10,7 +10,9 @@ process family can start with ``--debug-port`` (default off):
     registry, exemplar comment lines included);
   * ``GET /debug/traces``  — the tracing ring (utils/tracing.py):
     ``?id=<trace_id>`` returns one trace with its store-side stamps,
-    otherwise the slowest-N completed traces (``?n=``, ``?kind=``);
+    ``?stalls=1`` the GC pauses and background passes this process
+    recorded (start + length, ``?min_ms=``), otherwise the slowest-N
+    completed traces (``?n=``, ``?kind=``);
   * ``GET /healthz``       — liveness.
 
 The apiserver's REST mux serves the same two payloads from its own
@@ -27,7 +29,7 @@ from typing import Optional, Tuple
 from urllib.parse import parse_qs, urlparse
 
 from .metrics import metrics
-from .tracing import tracer
+from .tracing import stall_events, tracer
 
 
 def metrics_payload() -> Tuple[bytes, str]:
@@ -47,6 +49,14 @@ def traces_payload(query: dict) -> Tuple[int, dict]:
     """The /debug/traces response body for a parsed query dict. Shared
     by this listener and the apiserver REST route so the two views
     cannot drift."""
+    if query.get("stalls") in ("1", "true"):
+        # GC pauses and periodic background passes with their starts on
+        # this process's monotonic clock (?min_ms= drops the short ones)
+        try:
+            min_ms = float(query.get("min_ms", "0"))
+        except ValueError:
+            min_ms = 0.0
+        return 200, stall_events(min_ms)
     trace_id = query.get("id", "")
     if trace_id:
         found = tracer.get(trace_id)

@@ -13,7 +13,7 @@ import bisect
 import random
 import threading
 from collections import defaultdict
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 _DEF_BUCKETS = [
     0.0001, 0.0002, 0.0005, 0.001, 0.002, 0.005, 0.01, 0.02, 0.05,
@@ -75,9 +75,33 @@ class Histogram:
     def quantile(self, q: float) -> float:
         return self.quantiles([q])[0]
 
+    def merge(self, counts: List[int], total: float, n: int) -> None:
+        """Fold pre-aggregated observations in (same bucket layout): the
+        tracer folds a pod's stages under its own leaf lock and flushes
+        them here at most once a second, instead of one registry-lock hop
+        per pod per stage. Merged observations carry no samples: the
+        quantiles of a histogram fed only this way come from its buckets."""
+        for i, c in enumerate(counts):
+            self.counts[i] += c
+        self.total += total
+        self.n += n
+
+    def _bucket_quantile(self, q: float) -> float:
+        """Upper bound of the bucket holding the q-quantile (the last
+        finite bound for the overflow bucket)."""
+        want = q * self.n
+        seen = 0
+        for i, c in enumerate(self.counts):
+            seen += c
+            if c and seen >= want:
+                return self.buckets[min(i, len(self.buckets) - 1)]
+        return 0.0
+
     def quantiles(self, qs) -> List[float]:
         """Several quantiles from ONE sort of the reservoir."""
         if not self._samples:
+            if self.n:
+                return [self._bucket_quantile(q) for q in qs]
             return [0.0] * len(qs)
         s = sorted(self._samples)
         return [s[min(int(q * len(s)), len(s) - 1)] for q in qs]
@@ -115,12 +139,74 @@ class Histogram:
         return self.total / self.n if self.n else 0.0
 
 
+class HistogramSet:
+    """A fixed list of histogram series observed TOGETHER on a hot path
+    (the stages of one request, of one commit): one registry-lock hop for
+    the lot, the series resolved once, no label handling per call, and
+    bucket counts only (no sample reservoir: quantiles of such a series
+    come from its buckets). `observe(values)` takes one value per series,
+    in the order the set was built; a None leaves that series alone.
+    Built by `Metrics.histogram_set`; sets over one registry add up
+    (`a + b`) into one that is still a single lock hop."""
+
+    __slots__ = ("_registry", "_keys", "_hists", "_epoch")
+
+    def __init__(self, registry: "Metrics", keys: List[Tuple[str, Tuple]]):
+        self._registry = registry
+        self._keys = keys
+        self._hists: List[Histogram] = []
+        self._epoch = -1
+
+    def __add__(self, other: "HistogramSet") -> "HistogramSet":
+        return HistogramSet(self._registry, self._keys + other._keys)
+
+    def observe(self, values) -> None:
+        reg = self._registry
+        with reg._lock:
+            if self._epoch != reg._epoch:
+                # first use, or the registry was reset under us
+                self._hists = [reg._hist_locked(k) for k in self._keys]
+                self._epoch = reg._epoch
+            for h, v in zip(self._hists, values):
+                if v is not None:
+                    h.counts[bisect.bisect_left(h.buckets, v)] += 1
+                    h.total += v
+                    h.n += 1
+
+
 class Metrics:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._counters: Dict[Tuple[str, Tuple], float] = defaultdict(float)
         self._gauges: Dict[Tuple[str, Tuple], float] = {}
         self._hists: Dict[Tuple[str, Tuple], Histogram] = {}
+        self._epoch = 0  # bumped by reset(): HistogramSets re-resolve
+        # callables run (outside the registry lock) before every render:
+        # series whose value is a reading taken AT the scrape — the
+        # process clock, the loop's open phase, the GC pauses counted by a
+        # lock-free hook — publish through these instead of on a hot path
+        self._collectors: List[Callable[[], None]] = []
+
+    def add_collector(self, fn: Callable[[], None]) -> None:
+        """Register `fn` to run before each render/dump (idempotent per
+        callable). A collector that raises is skipped, never fatal."""
+        with self._lock:
+            if fn not in self._collectors:
+                self._collectors.append(fn)
+
+    def remove_collector(self, fn: Callable[[], None]) -> None:
+        with self._lock:
+            if fn in self._collectors:
+                self._collectors.remove(fn)
+
+    def collect(self) -> None:
+        with self._lock:
+            fns = list(self._collectors)
+        for fn in fns:
+            try:
+                fn()
+            except Exception:
+                pass  # a scrape must never fail on one reader
 
     @staticmethod
     def _k(name: str, labels: Optional[dict]) -> Tuple[str, Tuple]:
@@ -133,6 +219,46 @@ class Metrics:
     def set_gauge(self, name: str, value: float, labels: Optional[dict] = None) -> None:
         with self._lock:
             self._gauges[self._k(name, labels)] = value
+
+    def adjust_gauge(self, name: str, delta: float, labels: Optional[dict] = None) -> None:
+        """Atomic read-modify-write of a gauge (an in-flight count kept by
+        many threads: +1 on entry, -1 on exit)."""
+        with self._lock:
+            k = self._k(name, labels)
+            self._gauges[k] = self._gauges.get(k, 0.0) + delta
+
+    def merge_histogram(
+        self,
+        name: str,
+        labels: Optional[dict],
+        counts: List[int],
+        total: float,
+        n: int,
+    ) -> None:
+        """Fold pre-aggregated observations (bucket counts in the default
+        layout, their sum and number) into a histogram: Histogram.merge."""
+        with self._lock:
+            self._hist_locked(self._k(name, labels)).merge(counts, total, n)
+
+    def _hist_locked(self, k: Tuple[str, Tuple]) -> Histogram:
+        h = self._hists.get(k)
+        if h is None:
+            h = self._hists[k] = Histogram()
+        return h
+
+    def histogram_set(self, name: str, labels: dict) -> HistogramSet:
+        """The series of `name` under `labels`, as one HistogramSet. At
+        most one label's value may be a tuple or list: the set then holds
+        one series per element, in that order (the stages of a family);
+        otherwise it holds the one series. Build it once and keep it."""
+        varying = [k for k, v in labels.items() if isinstance(v, (tuple, list))]
+        if not varying:
+            return HistogramSet(self, [self._k(name, labels)])
+        (key,) = varying
+        return HistogramSet(
+            self,
+            [self._k(name, dict(labels, **{key: v})) for v in labels[key]],
+        )
 
     def remove_gauge(self, name: str, labels: Optional[dict] = None) -> None:
         """Retire one labeled gauge series (e.g. a departed follower's lag
@@ -222,6 +348,7 @@ class Metrics:
             self._counters.clear()
             self._gauges.clear()
             self._hists.clear()
+            self._epoch += 1
 
     def render_prometheus(self) -> str:
         """Prometheus exposition text format (the wire form the reference's
@@ -245,6 +372,7 @@ class Metrics:
             )
             return "{" + inner + "}"
 
+        self.collect()
         lines = []
         # the whole render holds the lock (like dump()): histograms are
         # shared mutable objects, and a concurrent observe() between the
@@ -283,6 +411,7 @@ class Metrics:
         return "\n".join(lines) + "\n"
 
     def dump(self) -> dict:
+        self.collect()
         with self._lock:
             out = {}
             for (name, labels), v in self._counters.items():
@@ -306,3 +435,37 @@ class Metrics:
 
 
 metrics = Metrics()  # process-global registry (legacyregistry equivalent)
+
+# the default bucket layout, for callers that pre-aggregate (Histogram.merge)
+DEFAULT_BUCKETS = tuple(_DEF_BUCKETS)
+
+
+def rest_resource_label(path: str) -> str:
+    """The `resource` label of a REST path, server and client side alike:
+    `pods`, `pods/binding`, `nodes`, ... for /api/v1 and /apis/<g>/<v>
+    paths (a subresource keeps its name, an object's name never appears),
+    the first segment for the few non-resource routes (`metrics`,
+    `healthz`, `debug`), else `other`. Bounded: a label is a path KIND."""
+    q = path.find("?")
+    if q >= 0:
+        path = path[:q]
+    parts = [p for p in path.split("/") if p]
+    if len(parts) >= 2 and parts[0] == "api":
+        rest = parts[2:]
+    elif len(parts) >= 3 and parts[0] == "apis":
+        rest = parts[3:]
+    else:
+        if parts and parts[0] in _NON_RESOURCE_ROUTES:
+            return parts[0]
+        return "other"
+    if not rest:
+        return "other"
+    if rest[0] == "namespaces" and len(rest) >= 3:
+        rest = rest[2:]
+    # rest = [resource, name?, subresource?]
+    return f"{rest[0]}/{rest[2]}" if len(rest) > 2 else rest[0]
+
+
+_NON_RESOURCE_ROUTES = frozenset(
+    ("metrics", "healthz", "readyz", "livez", "debug", "version", "openapi")
+)

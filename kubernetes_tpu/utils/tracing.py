@@ -35,6 +35,20 @@ never wall clock (deflake guard: NTP steps and clock skew must not
 produce negative or inflated stages). Wall time appears only as trace
 attributes (`since_created_s`) for cross-referencing API objects.
 
+Window-long aggregates (the ring holds 1,024 traces; a benchmark window
+binds ten times that): at ``finish()`` a pod trace's per-stage durations
+fold into per-stage sum/count/buckets beside the counter deltas and flush
+with them, at most once a second, as
+``scheduling_pod_stage_duration_seconds{stage}``. The scheduling loop's
+own wall is accounted by a :class:`PhaseTracker` (exactly one phase at
+any instant, one clock read per switch; published as
+``scheduler_loop_phase_seconds_total{phase,inflight}`` and, while a
+profiler session runs, as ``ktpu.loop.<phase>`` annotations on the
+device trace's clock). Stalls — GC pauses and periodic background passes
+— land in a small bounded log (``stall_events``: ``/debug/traces?stalls=1``
+and the SIGUSR2 dump), so a stall seen from outside at t can be matched
+to what ran at t.
+
 Concurrency: one named lock (``tracing.ring``) guards the active table,
 the ring, and the store ledger; the lock is a leaf (nothing else is
 acquired under it) and the shared attributes are Eraser-tracked
@@ -45,6 +59,8 @@ the guard from day one. Disabled (``KTPU_TRACING=0`` or
 
 from __future__ import annotations
 
+import bisect
+import gc
 import itertools
 import os
 import threading
@@ -52,9 +68,10 @@ import time
 import uuid
 from collections import deque
 from contextlib import contextmanager
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from ..testing.lockgraph import named_lock, track_attrs
+from .metrics import DEFAULT_BUCKETS
 
 # the cross-process propagation header (attached by RESTClient
 # bind_pod/bind_pods, validated/consumed by the /binding route)
@@ -67,6 +84,15 @@ COUNTER_STORE_STAMPS = "tracing_store_stamps_total"
 GAUGE_RING_DEPTH = "tracing_ring_depth"
 GAUGE_ACTIVE = "tracing_active_traces"
 GAUGE_ENABLED = "tracing_enabled"
+# a finished pod trace's stages, folded over the whole process lifetime
+# (the ring is for "which pod", this is for "how long, on average")
+HIST_POD_STAGE = "scheduling_pod_stage_duration_seconds"
+# the scheduling loop's wall by phase; inflight="1" while a launched
+# batch's index payload has not been read back (the chip has work)
+COUNTER_LOOP_PHASE = "scheduler_loop_phase_seconds_total"
+# stall causes, in every process that installs the probes
+HIST_GC_PAUSE = "process_gc_pause_seconds"
+GAUGE_PROCESS_CLOCK = "process_clock_seconds"
 
 # pod-trace span names in waterfall order (the bench stage waterfall and
 # the SIGUSR2 renderer both order stages by this, unknown names last)
@@ -86,6 +112,18 @@ STAGE_ORDER = (
 )
 
 _tls = threading.local()
+
+
+def _new_agg() -> list:
+    """[n, total seconds, bucket counts]: observations folded where they
+    happen and merged into the registry later (Histogram.merge)."""
+    return [0, 0.0, [0] * (len(DEFAULT_BUCKETS) + 1)]
+
+
+def _fold(agg: list, dur: float) -> None:
+    agg[0] += 1
+    agg[1] += dur
+    agg[2][bisect.bisect_left(DEFAULT_BUCKETS, dur)] += 1
 
 
 class _TraceRecord:
@@ -218,6 +256,9 @@ class Tracer:
         # (measured: ~16% of a 6k-pod burst wall went to per-op
         # inc/set_gauge lock hops; batched, it is noise)
         self._counts: Dict[Tuple[str, str], int] = {}
+        # stage -> [n, total_s, bucket counts]: finished pod traces'
+        # stages, folded under the same lock and flushed with _counts
+        self._stage_agg: Dict[str, list] = {}
         self._last_pub = 0.0  # graftlint: unguarded(single-float publish throttle; a torn read double-publishes at worst)
         self._pub_interval_s = 1.0
 
@@ -280,7 +321,17 @@ class Tracer:
                 rec.attrs.update(attrs)
             self._ring.append(rec)
             self._bump_locked("completed", rec.kind)
+            if rec.kind == "pod":
+                self._fold_stages_locked(rec)
         self._maybe_publish()
+
+    def _fold_stages_locked(self, rec: _TraceRecord) -> None:
+        agg = self._stage_agg
+        for name, dur in rec.stages().items():
+            a = agg.get(name)
+            if a is None:
+                a = agg[name] = _new_agg()
+            _fold(a, dur)
 
     def discard(self, trace_id: str) -> None:
         """Drop an active trace without completing it (pod deleted while
@@ -492,6 +543,34 @@ class Tracer:
             )
         return lines
 
+    def render_if_long(
+        self, trace_id: str, title: str, threshold_s: float
+    ) -> Optional[str]:
+        """The slow-batch report: when trace `trace_id` (active, or the
+        newest match in the ring) has lasted `threshold_s` or more, its
+        spans in start order as one multi-line text, else None —
+        ``"<title>" {attrs} (total ms):`` then one ``+<dur>ms <span>``
+        line per span."""
+        if not self._enabled or not trace_id:
+            return None
+        with self._lock:
+            rec = self._active.get(trace_id)
+            if rec is None:
+                rec = next(
+                    (r for r in reversed(self._ring)
+                     if r.trace_id == trace_id),
+                    None,
+                )
+            if rec is None or rec.total_s() < threshold_s:
+                return None
+            total, attrs = rec.total_s(), dict(rec.attrs)
+            spans = sorted(rec.spans, key=lambda sp: sp[1])
+        parts = [f'"{title}" {attrs} ({total * 1e3:.1f}ms):']
+        parts.extend(
+            f"  +{(s1 - s0) * 1e3:.1f}ms {name}" for name, s0, s1, _a in spans
+        )
+        return "\n".join(parts)
+
     def _bump_locked(self, what: str, label: str) -> None:
         """Caller holds self._lock: accumulate one counter delta for the
         next batched publish (a plain dict bump — no registry lock)."""
@@ -514,8 +593,13 @@ class Tracer:
         with self._lock:
             depth, active = len(self._ring), len(self._active)
             deltas, self._counts = self._counts, {}
+            stages, self._stage_agg = self._stage_agg, {}
         from .metrics import metrics
 
+        for name, (n, total, counts) in sorted(stages.items()):
+            metrics.merge_histogram(
+                HIST_POD_STAGE, {"stage": name}, counts, total, n
+            )
         for (what, label), n in sorted(deltas.items()):
             by = float(n)
             if what == "started":
@@ -538,16 +622,263 @@ class Tracer:
             self._ring.clear()
             self._store_ledger.clear()
             self._counts.clear()
+            self._stage_agg.clear()
 
 
 # lockset sanitizer (testing/lockgraph.py Eraser mode): the active
 # table, pod-key index, completed ring, and store-stamp ledger are
 # shared by scheduler/informer/bind-pool/REST-handler threads — all
 # guarded by the one `tracing.ring` leaf lock, machine-checked in chaos
-track_attrs(Tracer, "_active", "_by_key", "_ring", "_store_ledger", "_counts")
+track_attrs(
+    Tracer, "_active", "_by_key", "_ring", "_store_ledger", "_counts",
+    "_stage_agg",
+)
 
 
 tracer = Tracer()  # process-global tracer (one ring per process)
+
+
+# -- the scheduling loop's wall, phase by phase ---------------------------------
+
+
+class PhaseTracker:
+    """Wall-clock accounting for ONE thread: at any instant the thread is
+    in exactly one phase, and a switch is one ``time.monotonic()`` read
+    (returned, so the span and the stage histogram that share the
+    boundary use the same instant). Seconds accumulate per (phase,
+    inflight); ``publish()`` — a metrics collector, run at every scrape —
+    adds the open phase up to now and incs
+    ``scheduler_loop_phase_seconds_total`` by what is new, so the series'
+    delta over two scrapes sums to their distance on this process's
+    clock, whatever the loop was in the middle of.
+
+    ``annotate`` (``jax.profiler.TraceAnnotation``, handed in by the
+    scheduler: this module stays importable without JAX) opens each phase
+    as a ``ktpu.loop.<phase>`` host event, so a profiler session started
+    from outside records the phases on the device trace's own clock; with
+    no session active an annotation is a flag test. Always on:
+    ``KTPU_TRACING=0`` does not reach here (operators read the series
+    from ``/metrics``)."""
+
+    def __init__(
+        self,
+        annotate: Optional[Callable[[str], object]] = None,
+        prefix: str = "ktpu.loop.",
+        start: str = "other",
+    ):
+        # a leaf: taken by the owner thread per switch (uncontended) and
+        # by the scrape thread in publish()
+        self._lock = named_lock("tracing.phase")
+        self._acc: Dict[Tuple[str, str], float] = {}
+        self._published: Dict[Tuple[str, str], float] = {}
+        self._phase = start
+        self._inflight = "0"
+        self._t = time.monotonic()
+        self._annotate = annotate
+        self._prefix = prefix
+        # the open annotation: touched by the owner thread only
+        self._ann = None  # graftlint: unguarded(owner-thread only: opened and closed by the thread the tracker belongs to)
+
+    @property
+    def phase(self) -> str:
+        with self._lock:
+            return self._phase
+
+    def switch(self, phase: str, inflight: Optional[bool] = None) -> float:
+        """Enter `phase` now; returns the instant. `inflight` (when given)
+        re-labels the time from here on: True while the chip holds a
+        launched batch whose index payload has not been read back."""
+        now = time.monotonic()
+        with self._lock:
+            k = (self._phase, self._inflight)
+            self._acc[k] = self._acc.get(k, 0.0) + (now - self._t)
+            self._t = now
+            changed = phase != self._phase
+            self._phase = phase
+            if inflight is not None:
+                self._inflight = "1" if inflight else "0"
+        if self._annotate is not None and (changed or self._ann is None):
+            self._reannotate(phase)
+        return now
+
+    def _reannotate(self, phase: Optional[str]) -> None:
+        ann, self._ann = self._ann, None
+        try:
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            if phase is not None:
+                ann = self._annotate(self._prefix + phase)
+                ann.__enter__()
+                self._ann = ann
+        except Exception:
+            self._annotate = None  # a profiler fault must not stop the loop
+
+    def close(self) -> None:
+        """The owner thread is leaving: account up to now as `other` and
+        close the open annotation."""
+        self.switch("other", inflight=False)
+        if self._annotate is not None:
+            self._reannotate(None)
+
+    def _totals_locked(self) -> Dict[Tuple[str, str], float]:
+        out = dict(self._acc)
+        k = (self._phase, self._inflight)
+        out[k] = out.get(k, 0.0) + (time.monotonic() - self._t)
+        return out
+
+    def totals(self) -> Dict[Tuple[str, str], float]:
+        """(phase, inflight) -> seconds, the open phase counted to now."""
+        with self._lock:
+            return self._totals_locked()
+
+    def publish(self) -> None:
+        from .metrics import metrics
+
+        with self._lock:
+            totals = self._totals_locked()
+            deltas = {
+                k: v - self._published.get(k, 0.0) for k, v in totals.items()
+            }
+            self._published = totals
+        for (phase, inflight), by in sorted(deltas.items()):
+            if by > 0.0:
+                metrics.inc(
+                    COUNTER_LOOP_PHASE,
+                    {"phase": phase, "inflight": inflight},
+                    by=by,
+                )
+
+
+track_attrs(PhaseTracker, "_acc", "_published", "_phase", "_inflight", "_t")
+
+
+# -- stalls: GC pauses and background passes -----------------------------------
+
+# A gc callback runs wherever an allocation tripped the collector — also
+# inside a `with` of the registry lock or of tracing.ring — so it may take
+# NO lock: it writes plain module state (collections never nest, the GIL
+# orders the writes) and a collector publishes it at scrape time.
+_GC_EVENT_MIN_S = 0.001  # shorter pauses are counted, not listed
+_GC_RING = 256
+_gc_t0 = [0.0]
+_gc_acc: Dict[int, list] = {g: _new_agg() for g in (0, 1, 2)}
+_gc_published: Dict[int, list] = {g: _new_agg() for g in (0, 1, 2)}
+_gc_events: List[Optional[tuple]] = [None] * _GC_RING
+_gc_n_events = [0]
+_probes_installed = [False]
+
+# periodic passes: (task, t0, dur) under a leaf lock of their own
+_PASS_RING = 1024
+_pass_lock = named_lock("tracing.stalls")
+_pass_events: deque = deque(maxlen=_PASS_RING)
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    if phase == "start":
+        _gc_t0[0] = time.monotonic()
+        return
+    t0 = _gc_t0[0]
+    dur = time.monotonic() - t0
+    gen = info.get("generation", 2)
+    a = _gc_acc.get(gen)
+    if a is None:
+        return
+    _fold(a, dur)
+    if dur >= _GC_EVENT_MIN_S:
+        i = _gc_n_events[0]
+        _gc_events[i % _GC_RING] = (t0, dur, gen)
+        _gc_n_events[0] = i + 1
+
+
+def _publish_process_series() -> None:
+    from .metrics import metrics
+
+    metrics.set_gauge(GAUGE_PROCESS_CLOCK, time.monotonic())
+    for gen, a in _gc_acc.items():
+        n, total, counts = a[0], a[1], list(a[2])
+        p = _gc_published[gen]
+        dn = n - p[0]
+        if dn <= 0:
+            continue
+        metrics.merge_histogram(
+            HIST_GC_PAUSE,
+            {"generation": str(gen)},
+            [c - pc for c, pc in zip(counts, p[2])],
+            total - p[1],
+            dn,
+        )
+        _gc_published[gen] = [n, total, counts]
+
+
+def install_stall_probes() -> None:
+    """Once per process (cmd/scheduler, cmd/apiserver): the gc.callbacks
+    hook behind ``process_gc_pause_seconds{generation}`` and the scrape-
+    time ``process_clock_seconds`` gauge (the delta of two scrapes is
+    their distance on this process's clock: the denominator of every
+    per-second reading)."""
+    from .metrics import metrics
+
+    if not _probes_installed[0]:
+        _probes_installed[0] = True
+        gc.callbacks.append(_gc_callback)
+    metrics.add_collector(_publish_process_series)
+
+
+def note_pass(task: str, t0: float, dur: float) -> None:
+    """One periodic background pass (anti-entropy audit, assume-TTL
+    sweep, queue flush, WAL compaction's locked part) ran [t0, t0+dur)
+    on time.monotonic(): kept for ``/debug/traces?stalls=1``. The caller
+    observes its own ``*_background_pass_seconds{task}`` series, outside
+    the lock the pass took."""
+    with _pass_lock:
+        _pass_events.append((task, t0, dur))
+
+
+def stall_events(min_ms: float = 0.0) -> dict:
+    """GC pauses (>= 1 ms) and background passes, oldest first, with
+    starts on this process's monotonic clock (`now` says where that
+    clock stands)."""
+    with _pass_lock:
+        passes = list(_pass_events)
+    n = _gc_n_events[0]
+    pauses = [
+        e for e in (
+            _gc_events[i % _GC_RING] for i in range(max(0, n - _GC_RING), n)
+        ) if e is not None
+    ]
+    floor = min_ms / 1e3
+    return {
+        "now": time.monotonic(),
+        "gc": [
+            {"t0": round(t0, 6), "ms": round(d * 1e3, 3), "generation": g}
+            for t0, d, g in pauses if d >= floor
+        ],
+        "passes": [
+            {"task": task, "t0": round(t0, 6), "ms": round(d * 1e3, 3)}
+            for task, t0, d in passes if d >= floor
+        ],
+    }
+
+
+def stall_lines(n: int = 8) -> List[str]:
+    """The SIGUSR2 "stalls" section: the n longest recent GC pauses and
+    background passes, with how long ago each began."""
+    ev = stall_events()
+    now = ev["now"]
+    rows = [
+        (e["ms"], f"gc gen{e['generation']}", now - e["t0"]) for e in ev["gc"]
+    ] + [(e["ms"], e["task"], now - e["t0"]) for e in ev["passes"]]
+    rows.sort(reverse=True)
+    lines = [
+        f"  recorded: {len(ev['gc'])} gc pauses >= 1 ms, "
+        f"{len(ev['passes'])} background passes "
+        f"(all: /debug/traces?stalls=1)"
+    ]
+    lines.extend(
+        f"  {ms:9.3f} ms  {what}  began {ago:.1f}s ago"
+        for ms, what, ago in rows[:n]
+    )
+    return lines
 
 
 # -- cross-process bind context ------------------------------------------------

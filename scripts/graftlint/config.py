@@ -254,6 +254,7 @@ GUARDEDBY_CLASSES = (
     "BindRideThrough",
     "LeaderElector",
     "Tracer",
+    "PhaseTracker",
     "WaveRingBuffer",
     "PolicyTuner",
 )
@@ -279,6 +280,7 @@ GUARD_LOCK_ALIASES = {
     # construction: its `with self.lock` IS the cache lock
     "SnapshotAntiEntropy.lock": "scheduler.cache",
     "Tracer._lock": "tracing.ring",
+    "PhaseTracker._lock": "tracing.phase",
     "WaveRingBuffer._lock": "tuner.ring",
     "PolicyTuner._lock": "tuner.state",
 }

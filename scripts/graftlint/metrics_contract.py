@@ -5,7 +5,8 @@ mint a series — which is exactly how the drift the PR-6 review rounds
 kept catching happened: the same logical series emitted under two label
 key sets, counters named like gauges, series that exist in code but in
 no documentation and no SIGUSR2 dump. This pass collects every
-``metrics.inc`` / ``metrics.set_gauge`` / ``metrics.observe`` call in
+``metrics.inc`` / ``metrics.set_gauge`` / ``metrics.observe`` (and
+``adjust_gauge`` / ``merge_histogram``) call in
 the tree (series names resolved through module-level string constants,
 the dominant idiom) and enforces:
 
@@ -36,9 +37,23 @@ import config
 
 PASS = "metrics"
 
-_METHODS = {"inc": "counter", "set_gauge": "gauge", "observe": "histogram"}
+_METHODS = {
+    "inc": "counter",
+    "set_gauge": "gauge",
+    "observe": "histogram",
+    # an in-flight count kept by +1/-1, and pre-aggregated observations
+    # folded in by a batched publisher (utils/metrics.py)
+    "adjust_gauge": "gauge",
+    "merge_histogram": "histogram",
+    # a pre-resolved family observed together (HistogramSet): the labels
+    # dict is literal, one value may be the tuple of a varying label
+    "histogram_set": "histogram",
+}
 # positional index of the labels argument per method (after name)
-_LABELS_POS = {"inc": 1, "set_gauge": 2, "observe": 2}
+_LABELS_POS = {
+    "inc": 1, "set_gauge": 2, "observe": 2,
+    "adjust_gauge": 2, "merge_histogram": 1, "histogram_set": 1,
+}
 
 
 class Series:

@@ -446,3 +446,499 @@ def test_spans_use_monotonic_never_wall_clock():
     src = inspect.getsource(tracing_mod)
     assert "time.time()" not in src
     assert "time.monotonic()" in src
+
+
+# -- ISSUE 25: the loop's wall, a pod's stages over a window, the store's ------
+# -- write path and the stalls, as series on /metrics --------------------------
+
+import glob  # noqa: E402
+import os  # noqa: E402
+import signal  # noqa: E402
+import socket  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+
+from kubernetes_tpu.utils import tracing as tracing_mod  # noqa: E402
+
+POD_STAGES = ("queue", "encode", "device", "readback", "guard", "assume", "bind")
+N_WINDOW = 3000  # three times the ring
+
+
+def _hist_n(name, labels=None):
+    h = metrics.histogram(name, labels)
+    return h.n if h is not None else 0
+
+
+def _device_sched(server):
+    # the device path at any batch size (the small-batch host lane is for
+    # clusters of <= 256 nodes: switched off, as at 5,000 nodes)
+    return Scheduler(server, KubeSchedulerConfiguration(small_batch_host_max=0))
+
+
+def _bound(server):
+    pods, _ = server.list("pods")
+    return sum(1 for p in pods if p.spec.node_name)
+
+
+@pytest.fixture(scope="module")
+def driven_loop():
+    """One driven loop for the tests below: 40 nodes, a warm-up, then
+    3,000 pods through the wave path of an in-process scheduler; the
+    phase totals and the registry before and after."""
+    tracer.reset()
+    metrics.reset()
+    server = APIServer()
+    sched = _device_sched(server)
+    for i in range(40):
+        server.create("nodes", make_node(f"ph-{i}", cpu="128"))
+    sched.start()
+    try:
+        for i in range(8):
+            server.create("pods", make_pod(f"warm-{i}"))
+        assert wait_until(lambda: _bound(server) >= 8, 180)
+        assert sched.wait_for_idle(30)
+        tracer.publish_gauges()
+        before = {
+            "phases": sched._phase.totals(),
+            "t": time.monotonic(),
+            "pod_stage": {s: _hist_n(tracing_mod.HIST_POD_STAGE, {"stage": s})
+                          for s in POD_STAGES},
+            "completed": metrics.counter(
+                "tracing_traces_completed_total", {"kind": "pod"}),
+        }
+        # no collector pause inside the window: a generation-2 pass over
+        # this test process's heap is 0.1 s wherever it happens to land,
+        # and would count against whatever phase that is
+        import gc
+
+        gc.collect()
+        gc.disable()
+        try:
+            for i in range(N_WINDOW):
+                server.create("pods", make_pod(f"win-{i}"))
+            assert wait_until(lambda: _bound(server) >= N_WINDOW + 8, 300)
+            assert sched.wait_for_idle(60)
+            after = {"phases": sched._phase.totals(), "t": time.monotonic()}
+        finally:
+            gc.enable()
+        sched._phase.publish()
+        tracer.publish_gauges()
+        after["pod_stage"] = {
+            s: _hist_n(tracing_mod.HIST_POD_STAGE, {"stage": s})
+            for s in POD_STAGES}
+        after["completed"] = metrics.counter(
+            "tracing_traces_completed_total", {"kind": "pod"})
+        after["page"] = metrics.render_prometheus()
+        yield before, after
+    finally:
+        sched.stop()
+        tracer.reset()
+
+
+def _phase_deltas(before, after):
+    d = {}
+    for (phase, _inflight), v in after["phases"].items():
+        d[phase] = d.get(phase, 0.0) + v - before["phases"].get(
+            (phase, _inflight), 0.0)
+    return d
+
+
+def test_loop_phases_sum_to_the_wall(driven_loop):
+    before, after = driven_loop
+    d = _phase_deltas(before, after)
+    wall = after["t"] - before["t"]
+    assert sum(d.values()) == pytest.approx(wall, rel=0.01), (d, wall)
+
+
+def test_loop_phase_other_stays_small(driven_loop):
+    before, after = driven_loop
+    d = _phase_deltas(before, after)
+    assert d.get("other", 0.0) < 0.02 * sum(d.values()), d
+
+
+@pytest.mark.parametrize(
+    "phase", ["pop", "lock_wait", "encode", "launch", "readback", "guard",
+              "assume", "bind"])
+def test_loop_phase_is_entered(driven_loop, phase):
+    before, after = driven_loop
+    assert _phase_deltas(before, after).get(phase, 0.0) > 0.0
+    assert (f'scheduler_loop_phase_seconds_total{{inflight="0",'
+            f'phase="{phase}"}}' in after["page"]
+            or f'scheduler_loop_phase_seconds_total{{inflight="1",'
+            f'phase="{phase}"}}' in after["page"])
+
+
+@pytest.mark.parametrize("stage", POD_STAGES)
+def test_pod_stage_series_counts_every_finished_pod(driven_loop, stage):
+    """The ring holds 1,024 traces; the series holds the whole window."""
+    before, after = driven_loop
+    assert after["completed"] - before["completed"] == N_WINDOW
+    # a requeued pod has several spans of a stage and counts once
+    assert after["pod_stage"][stage] - before["pod_stage"][stage] == N_WINDOW
+    assert f'scheduling_pod_stage_duration_seconds_count{{stage="{stage}"}}' \
+        in after["page"]
+
+
+@pytest.mark.parametrize("stage", ["encode", "flush", "kernel", "guard",
+                                   "assume"])
+def test_stage_histograms_share_the_phase_boundaries(driven_loop, stage):
+    _before, after = driven_loop
+    assert f'scheduling_stage_duration_seconds_count{{stage="{stage}"}}' \
+        in after["page"]
+
+
+def test_cache_lock_wait_is_observed_per_acquisition(driven_loop):
+    _before, after = driven_loop
+    assert _hist_n("scheduler_cache_lock_wait_seconds") >= _hist_n(
+        "scheduling_stage_duration_seconds", {"stage": "flush"}) > 0
+
+
+def test_tracing_off_keeps_loop_and_store_series():
+    """KTPU_TRACING=0: every tracer entry point is one attribute test, so
+    the pod-stage series stays empty; the loop's phases and the store's
+    commit stages are always-on."""
+    metrics.reset()
+    tracer.set_enabled(False)
+    server = APIServer()
+    sched = _device_sched(server)
+    try:
+        for i in range(4):
+            server.create("nodes", make_node(f"off-{i}"))
+        sched.start()
+        for i in range(12):
+            server.create("pods", make_pod(f"off-{i}"))
+        assert wait_until(lambda: _bound(server) >= 12, 180)
+        assert sched.wait_for_idle(30)
+        sched._phase.publish()
+        tracer.publish_gauges()
+        page = metrics.render_prometheus()
+        assert "scheduling_pod_stage_duration_seconds" not in page
+        assert "scheduler_loop_phase_seconds_total" in page
+        assert _hist_n("store_commit_stage_seconds",
+                       {"op": "bind", "kind": "pods", "stage": "apply"}) >= 1
+        assert _hist_n("store_lock_wait_seconds",
+                       {"op": "create", "kind": "pods"}) >= 12
+        assert _hist_n("scheduling_stage_duration_seconds",
+                       {"stage": "guard"}) >= 1
+    finally:
+        sched.stop()
+        tracer.set_enabled(True)
+
+
+def test_profiler_session_holds_loop_phase_annotations(tmp_path):
+    """A profiler session started from outside (as the benchmark's
+    --trace 1 does) records the loop's phases as ktpu.loop.* host events
+    on the trace's own clock."""
+    import jax
+
+    server = APIServer()
+    sched = _device_sched(server)
+    try:
+        for i in range(4):
+            server.create("nodes", make_node(f"pr-{i}"))
+        sched.start()
+        for i in range(6):
+            server.create("pods", make_pod(f"prw-{i}"))
+        assert wait_until(lambda: _bound(server) >= 6, 180)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            for k in range(4):
+                for i in range(5):
+                    server.create("pods", make_pod(f"pr-{k}-{i}"))
+                time.sleep(0.1)
+            assert wait_until(lambda: _bound(server) >= 26, 60)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        sched.stop()
+    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert files
+    data = jax.profiler.ProfileData.from_file(files[-1])
+    everything, loop_lines = [], []
+    for plane in data.planes:
+        for line in plane.lines:
+            evs = list(line.events)
+            everything.extend(evs)
+            mine = [e for e in evs if e.name.startswith("ktpu.loop.")]
+            if mine:
+                assert plane.name.startswith("/host:"), plane.name
+                loop_lines.append(mine)
+    # another scheduler alive in this process (a module fixture) idles in
+    # `pop` on a line of its own: this one's is the line that launched
+    loop_lines = [evs for evs in loop_lines
+                  if any(e.name == "ktpu.loop.launch" for e in evs)]
+    assert len(loop_lines) == 1, "one loop thread, one host line"
+    names = {e.name for e in loop_lines[0]}
+    assert {"ktpu.loop.pop", "ktpu.loop.launch", "ktpu.loop.readback",
+            "ktpu.loop.bind"} <= names, names
+    lo = min(e.start_ns for e in everything)
+    hi = max(e.start_ns + e.duration_ns for e in everything)
+    for e in loop_lines[0]:
+        assert lo <= e.start_ns and e.start_ns + e.duration_ns <= hi
+    # phases do not overlap: the thread is in one at a time
+    spans = sorted((e.start_ns, e.start_ns + e.duration_ns)
+                   for e in loop_lines[0])
+    assert all(a[1] <= b[0] + 1 for a, b in zip(spans, spans[1:]))
+
+
+def test_stall_log_lists_passes_and_gc_pauses(bound_cluster):
+    server, sched = bound_cluster
+    tracing_mod.install_stall_probes()
+    t0 = time.monotonic()
+    tracing_mod.note_pass("antientropy", t0, 0.0123)
+    import gc
+
+    junk = [[i] for i in range(200000)]
+    junk.append(junk)
+    del junk
+    gc.collect()
+    ev = tracing_mod.stall_events()
+    assert any(p["task"] == "antientropy" and p["ms"] == 12.3
+               for p in ev["passes"])
+    assert ev["now"] >= t0
+    metrics.render_prometheus()  # the collector publishes at a scrape
+    assert _hist_n("process_gc_pause_seconds", {"generation": "2"}) >= 1
+    assert metrics.gauge("process_clock_seconds") >= t0
+    # the same list over HTTP and in the SIGUSR2 dump
+    dbg = serve_debug(0)
+    try:
+        port = dbg.server_address[1]
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/debug/traces?stalls=1",
+                timeout=5) as r:
+            payload = json.loads(r.read())
+        assert any(p["task"] == "antientropy" for p in payload["passes"])
+        assert set(payload) == {"now", "gc", "passes"}
+    finally:
+        dbg.shutdown()
+    dump = CacheDebugger(sched).dump()
+    assert "Dump of stalls" in dump and "antientropy" in dump
+
+
+def test_background_passes_are_timed(bound_cluster):
+    """The queue's flush and the cache's assume-TTL sweep run every
+    second on their own threads: each leaves an observation and an
+    event."""
+    assert wait_until(
+        lambda: _hist_n("scheduler_background_pass_seconds",
+                        {"task": "queue_flush_backoff"}) >= 1
+        and _hist_n("scheduler_background_pass_seconds",
+                    {"task": "assume_ttl"}) >= 1, 10)
+    tasks = {p["task"] for p in tracing_mod.stall_events()["passes"]}
+    assert {"queue_flush_backoff", "assume_ttl"} <= tasks
+
+
+def test_deferred_pods_are_counted_and_forgotten():
+    """scheduler_wave_deferred_pods_total / _max_attempts: a pod deferred
+    wave after wave shows as a growing maximum, and leaves it when
+    placed."""
+    from types import SimpleNamespace
+
+    metrics.reset()
+    sched = Scheduler(APIServer(), KubeSchedulerConfiguration(use_device=False))
+    a, b = SimpleNamespace(key="d/a"), SimpleNamespace(key="d/b")
+    batch = SimpleNamespace(pis=[a, b])
+    for _ in range(3):
+        sched._note_deferrals(batch, [a])
+    assert metrics.counter("scheduler_wave_deferred_pods_total") == 3
+    assert metrics.gauge("scheduler_wave_deferred_max_attempts") == 3
+    sched._note_deferrals(batch, [b])  # a was placed this time
+    assert metrics.gauge("scheduler_wave_deferred_max_attempts") == 1
+    sched._note_deferrals(batch, [])
+    assert metrics.gauge("scheduler_wave_deferred_max_attempts") == 0
+
+
+def test_slow_batch_report_is_rendered_from_the_wave_trace():
+    t0 = time.monotonic() - 0.5
+    tid = tracer.start("wave", "wave/3pods", t0=t0, pods=3)
+    tracer.add_span(tid, "launch", t0 + 0.01, t0 + 0.4)
+    tracer.add_span(tid, "encode", t0, t0 + 0.01)
+    assert tracer.render_if_long(tid, "schedule_batch", 5.0) is None
+    text = tracer.render_if_long(tid, "schedule_batch", 0.1)
+    lines = text.splitlines()
+    assert lines[0].startswith('"schedule_batch" {\'pods\': 3} (')
+    assert lines[1].endswith("ms encode") and lines[2].endswith("ms launch")
+    assert lines[2].strip().startswith("+390.0ms")
+    tracer.finish(tid, outcome="committed")
+    assert tracer.render_if_long(tid, "schedule_batch", 0.1) is not None
+
+
+def test_histogram_merge_feeds_sum_count_and_bucket_quantiles():
+    h = Histogram()
+    counts = [0] * (len(h.buckets) + 1)
+    counts[3] = 9   # <= 1 ms
+    counts[8] = 1   # <= 50 ms
+    h.merge(counts, 0.059, 10)
+    assert (h.n, h.total) == (10, 0.059)
+    assert h.quantile(0.5) == h.buckets[3]
+    assert h.quantile(0.99) == h.buckets[8]
+
+
+def test_histogram_set_is_one_hop_for_a_family_and_survives_reset():
+    metrics.reset()
+    hs = metrics.histogram_set(
+        "t25_stage_seconds", {"op": "x", "stage": ("a", "b", "c")}
+    ) + metrics.histogram_set("t25_wait_seconds", {"op": "x"})
+    hs.observe((0.001, None, 0.02, 0.3))
+    hs.observe((0.003, 0.004, 0.02, 0.1))
+    assert _hist_n("t25_stage_seconds", {"op": "x", "stage": "a"}) == 2
+    assert _hist_n("t25_stage_seconds", {"op": "x", "stage": "b"}) == 1
+    assert metrics.histogram("t25_wait_seconds", {"op": "x"}).total == \
+        pytest.approx(0.4)
+    # bucket quantiles (no reservoir behind a set)
+    assert metrics.histogram(
+        "t25_stage_seconds", {"op": "x", "stage": "c"}).quantile(0.5) == 0.02
+    metrics.reset()  # the set re-resolves its series in the new registry
+    hs.observe((0.001, 0.001, 0.001, 0.001))
+    assert _hist_n("t25_stage_seconds", {"op": "x", "stage": "b"}) == 1
+    assert 't25_wait_seconds_count{op="x"} 1' in metrics.render_prometheus()
+
+
+# -- the apiserver's write path, from a real cmd/apiserver ---------------------
+
+
+def _free_port():
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def _scrape(port):
+    with urllib.request.urlopen(f"http://127.0.0.1:{port}/metrics",
+                                timeout=5) as r:
+        text = r.read().decode()
+    out = {}
+    for line in text.splitlines():
+        if line and not line.startswith("#"):
+            name, _, value = line.rpartition(" ")
+            out[name] = float(value)
+    return out
+
+
+@pytest.fixture(params=["native", "python"])
+def apiserver_process(request, tmp_path):
+    """`python -m kubernetes_tpu.cmd.apiserver --data-dir`: WAL + fsync
+    on, the native group-commit sink or (no compiler on PATH, an empty
+    build cache) the Python one."""
+    port = _free_port()
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    if request.param == "python":
+        env["PATH"] = str(tmp_path / "no-compiler-here")
+        env["TMPDIR"] = str(tmp_path / "tmp")
+        os.makedirs(env["TMPDIR"])
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "kubernetes_tpu.cmd.apiserver",
+         "--port", str(port), "--data-dir", str(tmp_path / "data")],
+        env=env, stdout=subprocess.DEVNULL, stderr=subprocess.PIPE,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    try:
+        def up():
+            try:
+                urllib.request.urlopen(
+                    f"http://127.0.0.1:{port}/healthz", timeout=1).read()
+                return True
+            except OSError:
+                return False
+
+        assert wait_until(up, 60), "cmd/apiserver did not come up"
+        yield request.param, port
+    finally:
+        proc.send_signal(signal.SIGKILL)
+        proc.wait(10)
+        proc.stderr.close()
+
+
+N_OPS = 25
+WRITE_SERIES = [
+    ('apiserver_request_duration_seconds_count'
+     '{resource="pods",verb="POST"}', "create"),
+    ('apiserver_request_duration_seconds_count'
+     '{resource="pods/binding",verb="POST"}', "bind"),
+] + [
+    (f'store_commit_stage_seconds_count'
+     f'{{kind="pods",op="{op}",stage="{stage}"}}', op)
+    for op in ("create", "bind")
+    for stage in ("apply", "wal_append", "fsync", "notify")
+] + [
+    (f'store_lock_wait_seconds_count{{kind="pods",op="{op}"}}', op)
+    for op in ("create", "bind")
+] + [
+    (f'apiserver_request_stage_seconds_count'
+     f'{{resource="{res}",stage="{stage}"}}', op)
+    for op, res in (("create", "pods"), ("bind", "pods/binding"))
+    for stage in ("authz", "read", "admit", "store", "observe", "respond")
+]
+
+
+def test_a_write_is_counted_once_per_stage(apiserver_process):
+    """N creates and N binds over REST: every request and stage series
+    reads exactly N per op, the WAL counts 2N + 1 records (one node) and
+    at most as many physical fsyncs, a bind sent with X-Trace-Context
+    shows its store stages under /debug/traces?id=, and the watch
+    delivered every event."""
+    sink, port = apiserver_process
+    client = RESTClient(f"http://127.0.0.1:{port}", timeout=10.0)
+    seen = []
+    watcher = client.watch("pods")
+
+    def pump():
+        for ev in watcher:
+            seen.append(ev)
+
+    import threading
+
+    threading.Thread(target=pump, daemon=True).start()
+    client.create("nodes", make_node("ws-0", cpu="64"))
+    before = _scrape(port)
+    metrics.reset()
+    for i in range(N_OPS):
+        client.create("pods", make_pod(f"ws-{i}"))
+    binds = [Binding(pod_name=f"ws-{i}", pod_namespace="default",
+                     target_node="ws-0") for i in range(N_OPS)]
+    with bind_context({"default/ws-0": "feedbeefcafe0025"}):
+        assert client.bind_pods(binds) == [None] * N_OPS
+    assert wait_until(lambda: len(seen) >= 2 * N_OPS, 20)
+    watcher.stop()
+    # a stream folds its deliveries locally and merges them when it idles
+    assert wait_until(
+        lambda: _scrape(port).get(
+            'apiserver_watch_delivery_seconds_count{kind="pods"}', 0.0)
+        - before.get(
+            'apiserver_watch_delivery_seconds_count{kind="pods"}', 0.0)
+        >= 2 * N_OPS, 10)
+    page = _scrape(port)
+
+    def delta(name):
+        return page.get(name, 0.0) - before.get(name, 0.0)
+
+    for name, _op in WRITE_SERIES:
+        assert delta(name) == N_OPS, (name, delta(name))
+    assert delta("wal_records_appended_total") == 2 * N_OPS
+    assert 1 <= delta("wal_fsyncs_total") <= 2 * N_OPS
+    assert delta('wal_fsync_duration_seconds_count') == 2 * N_OPS
+    # one client of one thread: nothing to group, one fsync per record
+    assert delta("wal_fsyncs_total") == 2 * N_OPS, sink
+    assert delta('apiserver_watch_delivery_seconds_count{kind="pods"}') \
+        >= 2 * N_OPS
+    assert page["apiserver_requests_inflight"] == 1.0  # this scrape
+    assert page["process_clock_seconds"] > before["process_clock_seconds"]
+    # the client's own series, in this process: one exchange per bind
+    assert _hist_n("rest_client_request_duration_seconds",
+                   {"verb": "POST", "resource": "pods/binding"}) == N_OPS
+    assert _hist_n("rest_client_request_duration_seconds",
+                   {"verb": "POST", "resource": "pods"}) == N_OPS
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/debug/traces?id=feedbeefcafe0025",
+            timeout=5) as r:
+        stamp = json.loads(r.read())["store_stamps"][0]
+    assert stamp["event"] == "applied"
+    stages = {k for k in stamp if k.endswith("_ms")}
+    assert stages == {"lock_wait_ms", "apply_ms", "wal_append_ms",
+                      "fsync_ms", "notify_ms"}
+    assert stamp["fsync_ms"] > 0.0
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/debug/traces?stalls=1", timeout=5) as r:
+        assert set(json.loads(r.read())) == {"now", "gc", "passes"}
