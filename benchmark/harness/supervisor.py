@@ -39,6 +39,9 @@ SCHED_CHILD = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 SCHEDULER_START_DEADLINE_S = 600.0  # the smoke's: a cold start compiles
 WARMUP_BURST_DEADLINE_S = 600.0
 WARMUP_TRICKLE_DEADLINE_S = 180.0
+# past the drain, for the launcher to say that the profiler has written
+# the trace; a trace that has not stopped by then is a run with no result
+TRACE_STOP_DEADLINE_S = 120.0
 
 
 def say(msg: str) -> None:
@@ -258,6 +261,31 @@ class Run:
             time.sleep(0.05)
         return os.path.exists(path)
 
+    def _wait_for_trace(self, trace_dir: str) -> None:
+        """Wait until the launcher says the profiler has written the
+        trace (`stopped`). One that has not by the deadline leaves the
+        run without a result, and the reason names the trace: what the
+        launcher wrote when the stop was decided, and how long ago."""
+        t = time.monotonic()
+        if self._wait_for_file(os.path.join(trace_dir, "stopped"),
+                               TRACE_STOP_DEADLINE_S):
+            return
+        waited = time.monotonic() - t
+        try:
+            with open(os.path.join(trace_dir, "stopping")) as f:
+                rec = json.load(f)
+            age = time.time() - os.path.getmtime(f.name)
+            why = (f"its stop was decided {age:.0f} s ago by "
+                   f"{rec['stopped_by']!r} after {rec['launches']} launches "
+                   f"in {rec['window_s']:.1f} s and the profiler is still "
+                   f"writing it")
+        except (OSError, ValueError, KeyError):
+            why = "it was never stopped (the launcher wrote no `stopping`)"
+        raise RunFailure(
+            f"the device trace in {trace_dir} did not stop: waited "
+            f"{waited:.0f} s past the drain for `stopped`; {why} "
+            f"(scheduler alive: {self.sched.alive()})")
+
     # -- a window ------------------------------------------------------------
 
     def _offer(self, rate: float, seconds: float, seed: int, prefix: str):
@@ -309,7 +337,8 @@ class Run:
             os.makedirs(trace_dir, exist_ok=True)
             timers = [
                 threading.Timer(0.05 + start, self.sched.command,
-                                [f"trace-start {trace_dir}"]),
+                                [f"trace-start {trace_dir} "
+                                 f"{int(tr.get('max_launches', 0))}"]),
                 threading.Timer(0.05 + start + span, self.sched.command,
                                 ["trace-stop"]),
             ]
@@ -332,8 +361,7 @@ class Run:
         for t in timers:
             t.join()
         if trace_dir is not None:
-            # stop_trace writes the file before the launcher says so
-            self._wait_for_file(os.path.join(trace_dir, "stopped"), 120.0)
+            self._wait_for_trace(trace_dir)
         lat_ms = loadgen.latencies_ms(win, self.watch.t_bound, t_gave_up)
         stats = loadgen.window_stats(win, self.watch.t_bound, t_gave_up,
                                      lat_ms)
@@ -476,6 +504,9 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
             - w["start"]["sched"].total("scheduler_wave_batches_total")),
         largest_batch=int(end["final"].total("scheduler_wave_batch_pods_max")),
         audit_passes=int(end["final"].total("snapshot_audit_passes_total")),
+        # which rule ended the trace, after how many launches, how long
+        # the profiler took to write it (the launcher's `stopped`)
+        trace_stopped=(ctx["trace"] or {}).get("stopped"),
         result=result,
         # every pod of the window, in the order it was due: when it was
         # due (s from the window's start) and its create-to-bound (ms)

@@ -14,8 +14,14 @@ line holds one event per operation that ran on the device and the
   programs    per module name: launches and summed device seconds
               (averaged over the device planes)
   breakdown   the operations that took most device time, and the longest
-              gaps in which no operation ran, each named after the program
-              that ended it (the program writes no host spans yet)
+              gaps in which no operation ran. Where the host planes hold
+              the program's `ktpu.loop.<phase>` annotations (the scheduling
+              loop's phases, on the trace's own clock) a gap is named after
+              the phase that covers most of it (`bind`, `pop`, ...); where
+              they hold none, or cover under half of the gap, after the
+              program that ended it (`before <program>`)
+  stopped     (reduce_dir) what the launcher wrote beside the trace:
+              `window_s`, `launches`, `stopped_by`, `stop_s`
 """
 
 from __future__ import annotations
@@ -27,6 +33,7 @@ import re
 
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
+PHASE_PREFIX = "ktpu.loop."
 
 
 def _union(intervals: list) -> tuple:
@@ -50,12 +57,37 @@ def op_name(event_name: str) -> str:
     return event_name.split(" = ", 1)[0].lstrip("%")[:80]
 
 
+def loop_phases(host_lines: list) -> list:
+    """[(phase, start, end)] of the scheduling loop's thread: of the host
+    lines ([(name, start, duration)] each) that hold `ktpu.loop.<phase>`
+    annotations, the one with most `launch` phases."""
+    best, best_key = [], (-1, -1)
+    for evs in host_lines:
+        mine = [(n[len(PHASE_PREFIX):], s, s + d) for n, s, d in evs
+                if n.startswith(PHASE_PREFIX)]
+        key = (sum(1 for n, _, _ in mine if n == "launch"), len(mine))
+        if mine and key > best_key:
+            best, best_key = mine, key
+    return best
+
+
+def phase_cover(phases: list, g0: float, g1: float) -> dict:
+    """phase -> the length of [g0, g1] that its annotations cover."""
+    cover: dict = {}
+    for name, s, e in phases:
+        lo, hi = max(s, g0), min(e, g1)
+        if hi > lo:
+            cover[name] = cover.get(name, 0.0) + (hi - lo)
+    return cover
+
+
 def reduce_profile(pd, window_s: float | None = None) -> dict:
     """`pd`: a jax.profiler.ProfileData; `window_s`: the traced window's
     length where the launcher gave it."""
     t_min, t_max = None, None
-    device_planes = []
+    device_planes, host_lines = [], []
     for plane in pd.planes:
+        on_device = bool(DEVICE_PLANE.match(plane.name))
         lines = {}
         for line in plane.lines:
             evs = [(e.name, float(e.start_ns), float(e.duration_ns))
@@ -63,8 +95,11 @@ def reduce_profile(pd, window_s: float | None = None) -> dict:
             for _, s, d in evs:
                 t_min = s if t_min is None else min(t_min, s)
                 t_max = s + d if t_max is None else max(t_max, s + d)
-            lines[line.name] = evs
-        if DEVICE_PLANE.match(plane.name):
+            if on_device:
+                lines[line.name] = evs
+            else:
+                host_lines.append(evs)  # threads share names: never by name
+        if on_device:
             device_planes.append((plane.name, lines))
     out = {"devices": len(device_planes), "busy_s": 0.0, "programs": {},
            "window_s": (window_s if window_s else
@@ -88,15 +123,21 @@ def reduce_profile(pd, window_s: float | None = None) -> dict:
             p["launches"] += 1.0 / n
             p["device_s"] += d / 1e9 / n
         if k == 0:
-            gaps = []
             edges = [[t_min, t_min]] + merged + [[t_max, t_max]]
-            for (_, e0), (s1, _) in zip(edges, edges[1:]):
-                if s1 - e0 > 1e5:  # gaps over 0.1 ms
-                    nxt = next((module_name(nm) for nm, s, _ in mods
-                                if s >= s1 - 1), "end of trace")
-                    gaps.append((f"before {nxt}", (s1 - e0) / 1e9))
-            gaps.sort(key=lambda g: -g[1])
-            out["breakdown"]["idle_gaps"] = [list(g) for g in gaps[:10]]
+            gaps = sorted(((s1 - e0, e0, s1) for (_, e0), (s1, _)
+                           in zip(edges, edges[1:])
+                           if s1 - e0 > 1e5),  # gaps over 0.1 ms
+                          reverse=True)[:10]
+            phases = loop_phases(host_lines)
+            for length, g0, g1 in gaps:
+                cover = phase_cover(phases, g0, g1)
+                if sum(cover.values()) >= 0.5 * length:
+                    name = max(cover, key=cover.get)
+                else:
+                    name = "before " + next(
+                        (module_name(nm) for nm, s, _ in mods if s >= g1 - 1),
+                        "end of trace")
+                out["breakdown"]["idle_gaps"].append([name, length / 1e9])
     top = sorted(op_time.items(), key=lambda kv: -kv[1])[:10]
     out["breakdown"]["device_ops"] = [[k, v] for k, v in top]
     if out["window_s"] > 0:
@@ -117,10 +158,13 @@ def reduce_dir(trace_dir: str) -> dict:
         return {}
     from jax.profiler import ProfileData
 
-    window_s = None
+    stopped = {}
     try:
         with open(os.path.join(trace_dir, "stopped")) as f:
-            window_s = float(json.load(f)["window_s"])
+            stopped = dict(json.load(f))
+        window_s = float(stopped["window_s"])
     except (OSError, ValueError, KeyError, TypeError):
-        pass
-    return reduce_profile(ProfileData.from_file(path), window_s)
+        window_s = None
+    out = reduce_profile(ProfileData.from_file(path), window_s)
+    out["stopped"] = stopped
+    return out

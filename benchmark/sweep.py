@@ -6,6 +6,12 @@ check.
 
   python3 benchmark/sweep.py --workload <cell> --rates 50,100,200,400,800,1600 --seconds 8
 
+With `--trace 1` every step is traced with the `trace` block of the cell's
+traffic file (span and launch cap) and its line also carries what ended
+the trace, the launches the trace holds, how long the profiler took to
+write it, the file's size, and how long after the window's end the
+harness had its window: the numbers the launch cap rests on.
+
 Saturation is the highest `bound_pods_per_s` any step showed; the knee is
 the highest offered rate at whose end fewer pods were pending than one
 second of arrivals. One JSON line per step on standard output, then one
@@ -20,10 +26,30 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import shutil  # noqa: E402
 import sys  # noqa: E402
+import time  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+
+
+def _trace_facts(trace_dir: str) -> dict:
+    """What a step's trace holds, and what it cost; the trace is removed."""
+    from harness import trace_reduce
+
+    path = trace_reduce.find_xplane(trace_dir)
+    facts = {"xplane_bytes": os.path.getsize(path) if path else None}
+    t = time.monotonic()
+    reduced = trace_reduce.reduce_dir(trace_dir)
+    facts["reduce_s"] = time.monotonic() - t
+    facts.update({k: reduced.get(k) for k in (
+        "stopped", "devices", "busy_s", "window_s", "idle_share")})
+    facts["launches_in_trace"] = {
+        name: p["launches"] for name, p in reduced.get("programs", {}).items()}
+    facts["idle_gaps"] = reduced.get("breakdown", {}).get("idle_gaps", [])[:5]
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return facts
 
 
 def main(argv=None) -> int:
@@ -32,6 +58,7 @@ def main(argv=None) -> int:
     ap.add_argument("--rates", default="50,100,200,400,800,1600")
     ap.add_argument("--seconds", type=float, default=8.0)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--out", default=None)
     ap.add_argument("--rehearse-cpu", action="store_true")
     ap.add_argument("--nodes", type=int, default=None)
@@ -48,7 +75,9 @@ def main(argv=None) -> int:
     try:
         run.setup()
         for rate in (float(r) for r in args.rates.split(",")):
-            w = run.window(args.seconds, rate=rate, drain_deadline_s=120.0)
+            w = run.window(args.seconds, rate=rate, drain_deadline_s=120.0,
+                           trace=bool(args.trace))
+            t_back = time.monotonic()
             s = w["stats"]
             step = {k: s[k] for k in (
                 "rate_offered", "attempted", "failed", "bound_pods_per_s",
@@ -58,6 +87,10 @@ def main(argv=None) -> int:
             step["wave_batches"] = int(
                 w["end"]["sched"].total("scheduler_wave_batches_total")
                 - w["start"]["sched"].total("scheduler_wave_batches_total"))
+            if args.trace:
+                step["window_returned_after_s"] = (
+                    t_back - w["window"].t0 - args.seconds)
+                step["trace"] = _trace_facts(w["trace_dir"])
             steps.append(step)
             print(json.dumps(step), flush=True)
         end = run.finish()

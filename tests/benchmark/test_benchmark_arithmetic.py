@@ -166,8 +166,13 @@ def test_trace_reduce_on_a_small_trace():
     wave = r["programs"]["jit_wave_kernel"]
     assert wave["launches"] == 2 and wave["device_s"] == pytest.approx(0.003)
     assert r["breakdown"]["device_ops"][0] == ["fusion.1", pytest.approx(0.002)]
-    assert r["breakdown"]["idle_gaps"][0] == [
-        "before end of trace", pytest.approx(0.003)]
+    # a gap is named after the loop phase that covers most of it, and
+    # after the program that ended it where the loop's line is bare
+    assert r["breakdown"]["idle_gaps"] == [
+        ["before end of trace", pytest.approx(0.003)],
+        ["bind", pytest.approx(0.0015)],
+        ["bind", pytest.approx(0.001)],
+        ["before jit_wave_kernel", pytest.approx(0.001)]]
     # where the launcher gives the traced window's length, that is the window
     given = trace_reduce.reduce_profile(ProfileData.from_text_proto(text), 0.02)
     assert given["window_s"] == 0.02 and given["busy_s"] == r["busy_s"]
@@ -183,6 +188,32 @@ def test_trace_reduce_on_a_small_trace():
         ctx, program="jit_wave_kernel", work="wave_kernel")
     assert share == pytest.approx(100 * 26395168 / 819e9 / 0.0015)
     assert cat.reader("trace_program_ms")(ctx, program="jit_absent") is None
+
+
+def test_a_trace_without_loop_annotations_names_gaps_by_program():
+    """The parent of a program that writes none, or a CPU rehearsal."""
+    from jax.profiler import ProfileData
+
+    text = (pathlib.Path(__file__).parent / "small_trace.textproto").read_text()
+    r = trace_reduce.reduce_profile(
+        ProfileData.from_text_proto(text.replace("ktpu.loop.", "other.")))
+    assert [g[0] for g in r["breakdown"]["idle_gaps"]] == [
+        "before end of trace", "before jit_wave_kernel",
+        "before jit_scatter", "before jit_wave_kernel"]
+
+
+def test_the_loop_line_and_what_covers_a_gap():
+    loop = [("ktpu.loop.launch", 0.0, 1.0), ("ktpu.loop.bind", 1.0, 8.0),
+            ("host_work", 0.0, 9.0), ("ktpu.loop.pop", 9.0, 1.0)]
+    other = [("ktpu.loop.bind", 0.0, 10.0), ("ktpu.loop.bind", 10.0, 10.0),
+             ("ktpu.loop.bind", 20.0, 10.0), ("ktpu.loop.bind", 30.0, 10.0)]
+    phases = trace_reduce.loop_phases([other, [("host_work", 0.0, 5.0)], loop])
+    assert phases == [("launch", 0.0, 1.0), ("bind", 1.0, 9.0),
+                      ("pop", 9.0, 10.0)]
+    assert trace_reduce.loop_phases([[("host_work", 0.0, 5.0)]]) == []
+    assert trace_reduce.phase_cover(phases, 0.5, 9.5) == {
+        "launch": 0.5, "bind": 8.0, "pop": 0.5}
+    assert trace_reduce.phase_cover(phases, 20.0, 30.0) == {}
 
 
 def test_a_trace_with_no_device_plane_reads_nothing():

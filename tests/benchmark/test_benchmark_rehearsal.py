@@ -61,6 +61,11 @@ def test_rehearsal_twice_in_a_row(cell, tmp_path_factory):
             assert all(m["value"] > 0 for m in last["metrics"].values())
         else:
             assert {"busy_s", "window_s"} <= set(last["device"])
+            # which rule ended the trace: the span, the cap is far off
+            stopped = json.loads(
+                (out / "detail.json").read_text())["trace_stopped"]
+            assert stopped["stopped_by"] == "span"
+            assert stopped["window_s"] == last["device"]["window_s"]
         # each number compared stands beside its limit at the end of stderr
         tail = r.stderr.strip().splitlines()[-len(last["compared"]):]
         assert all(ln.startswith("compared ") for ln in tail), tail
