@@ -90,6 +90,16 @@ _RETRYABLE_METHODS = ("GET", "HEAD")
 
 _WATCH_RESUME_ATTEMPTS = 4
 
+# RESTClient.bind_pods sends a wave's bindings as BindingLists of at most
+# this many: it bounds one request's body and one hold of the store's
+# lock, under which creates wait. 256 is the scheduler's small batch
+# bucket, so a steady wave is always one request (PERF.md has what one
+# full hold costs).
+BIND_CHUNK = 256
+# how often the chunking engages: bindings sent over binding requests
+COUNTER_BINDINGS_SENT = "rest_client_bindings_sent_total"
+COUNTER_BINDING_REQUESTS = "rest_client_binding_requests_total"
+
 
 class _NoDelayHTTPConnection(http.client.HTTPConnection):
     """HTTPConnection with Nagle disabled. http.client writes the header
@@ -1085,79 +1095,137 @@ class RESTClient:
             raise self._classify_bind_transport(e) from e
 
     def bind_pods(self, bindings, fence=None) -> list:
-        """Per-binding error list (None = bound). Retryable degraded-store
-        refusals come back as the EXCEPTION OBJECT (DegradedWrites /
-        QuorumLost), not a string — the scheduler's ride-through layer
-        parks those placements instead of failing them. After the first
-        degraded refusal the remaining bindings are not attempted (each
-        would burn its own client-side retry budget against a store that
-        just said "read-only"); they get a fresh DegradedWrites — none of
-        them was applied, so replaying them later is safe. Transport
-        failures classify through _classify_bind_transport: refused
-        connect = retryable DegradedWrites, anything after the connect =
-        QuorumLost (outcome unknown, read back before retrying).
+        """Per-binding error list (None = bound), same length and order as
+        `bindings`. They go out as BindingLists of at most BIND_CHUNK, one
+        POST /api/v1/bindings each; the server applies a list under one
+        hold of the store's lock and one fsync and answers per item, so
+        NotFound / Conflict come back typed for the one binding they
+        concern and the others of its request are applied. What concerns
+        a whole request is held per chunk:
+
+        Retryable degraded-store refusals come back as the EXCEPTION
+        OBJECT (DegradedWrites / QuorumLost), not a string — the
+        scheduler's ride-through layer parks those placements instead of
+        failing them. A degraded refusal marks every binding of its chunk
+        (the gate refused before applying anything) and the chunks after
+        it are not attempted (each would burn its own client-side retry
+        budget against a store that just said "read-only"); they get a
+        fresh DegradedWrites — none of them was applied, so replaying
+        them later is safe. Transport failures classify through
+        _classify_bind_transport: refused connect = retryable
+        DegradedWrites, anything after the connect = QuorumLost for EVERY
+        binding of that chunk (outcome unknown, read each pod back before
+        retrying), and the rest is not attempted. No request is replayed.
 
         fence: the leadership fencing token (BindFence), attached to every
-        binding POST as the X-Leadership-Fence header and validated by the
-        server against the live lease under the bind lock. A LeaderFenced
-        rejection RAISES (mirroring the in-process store's whole-batch
-        reject): the remaining bindings are not attempted — the caller is
-        not the leader anymore. Bindings that already landed in this batch
-        were applied while the grant was still valid and stay applied
-        exactly once; the new leader's adoption pass reads them back."""
-        errors = []
-        degraded: Optional[DegradedWrites] = None
+        binding request as the X-Leadership-Fence header and validated by
+        the server against the live lease under the bind lock. A
+        LeaderFenced rejection RAISES (mirroring the in-process store's
+        whole-batch reject): nothing of that request was applied and the
+        remaining chunks are not attempted — the caller is not the leader
+        anymore. Chunks that already landed were applied while the grant
+        was still valid and stay applied exactly once; the new leader's
+        adoption pass reads them back."""
+        errors: list = []
+        stopped: Optional[DegradedWrites] = None
         fence_headers = self._fence_headers(fence)  # one token per batch
-        for b in bindings:
-            if degraded is not None:
-                errors.append(
-                    DegradedWrites(f"not attempted: {degraded}")
+        for i in range(0, len(bindings), BIND_CHUNK):
+            chunk = bindings[i : i + BIND_CHUNK]
+            if stopped is not None:
+                errors.extend(
+                    DegradedWrites(f"not attempted: {stopped}") for _ in chunk
                 )
                 continue
+            metrics.inc(COUNTER_BINDING_REQUESTS)
+            metrics.inc(COUNTER_BINDINGS_SENT, by=len(chunk))
             try:
-                self._request(
+                reply = self._request(
                     "POST",
-                    self.base
-                    + f"/api/v1/namespaces/{b.pod_namespace}/pods/"
-                    + f"{b.pod_name}/binding",
-                    codec.encode(b),
-                    headers=self._bind_headers(fence_headers, b),
+                    self.base + "/api/v1/bindings",
+                    {
+                        "kind": "BindingList",
+                        "apiVersion": "v1",
+                        "items": [self._binding_item(b) for b in chunk],
+                    },
+                    headers=fence_headers,
                 )
-                errors.append(None)
+                items = reply.get("items")
+                if not isinstance(items, list) or len(items) != len(chunk):
+                    # a 2xx that does not answer per item: the store call
+                    # may have run — unknown, like a lost ack
+                    raise QuorumLost(
+                        "binding reply does not match its request"
+                    )
+                outcome = [self._bind_item_error(it) for it in items]
+                # (a frontend relays its upstream's refusal per item)
+                stopped = next(
+                    (e for e in outcome if isinstance(e, DegradedWrites)),
+                    None,
+                )
             except LeaderFenced:
-                # deposed mid-batch: nothing further may apply. Raise like
-                # the in-process store's atomic whole-batch reject; the
-                # scheduler's _on_fenced_binds drops every placement (the
-                # already-landed prefix is re-adopted from informer state)
+                # deposed: nothing of this request applied, nothing
+                # further may. Raise like the in-process store's atomic
+                # whole-batch reject; the scheduler's _on_fenced_binds
+                # drops every placement (chunks that landed before are
+                # re-adopted from informer state)
                 raise
-            except QuorumLost as e:
-                # THIS binding applied remotely but missed quorum: its
-                # outcome is unknown — surface the exception itself so the
-                # caller reads the pod back before any retry
-                errors.append(e)
-                degraded = e
             except DegradedWrites as e:
-                errors.append(e)
-                degraded = e
+                # Degraded: the gate refused the request before applying
+                # anything. QuorumLost: it applied remotely but missed
+                # quorum — outcome unknown, the caller reads each pod
+                # back before any retry. Either way the exception itself
+                # marks every binding of the chunk
+                outcome = [e] * len(chunk)
+                stopped = e
             except (NotFound, Conflict) as e:
-                # typed like the in-process store's error list, so the
-                # scheduler's reconciler branches identically over REST
-                errors.append(e)
+                outcome = [e] * len(chunk)
             except urllib.error.HTTPError as e:
-                # a non-2xx the taxonomy doesn't know (500, 403, ...):
+                # a non-2xx the taxonomy doesn't know (400, 403, 500):
                 # the server DID answer — a known refusal, not unknown
-                errors.append(str(e))
+                outcome = [str(e)] * len(chunk)
             except OSError as e:
                 # transport failure (partition, reset, blackholed ack):
                 # classify, then stop attempting the rest of the batch —
                 # the network just proved undeliverable and each further
                 # attempt would burn its own timeout
-                err = self._classify_bind_transport(e)
-                errors.append(err)
-                degraded = err
+                stopped = self._classify_bind_transport(e)
+                outcome = [stopped] * len(chunk)
             except Exception as e:
-                errors.append(str(e))
+                outcome = [str(e)] * len(chunk)
+            errors.extend(outcome)
         return errors
+
+    @staticmethod
+    def _binding_item(binding) -> dict:
+        """One item of a BindingList: the Binding, and beside it the
+        pod's trace id (what X-Trace-Context carries for a single
+        binding POST), so the store stamps each apply — or its
+        LeaderFenced rejection — under the id this process minted."""
+        item = codec.encode(binding)
+        tid = trace_for_binding(binding)
+        if tid:
+            item["traceContext"] = tid
+        return item
+
+    @staticmethod
+    def _bind_item_error(item):
+        """One Status of a BindingList reply as bind_pods' entry for that
+        binding: None, or the typed error the single route's status code
+        would have raised."""
+        if not isinstance(item, dict):
+            return "malformed binding status"
+        if item.get("status") == "Success":
+            return None
+        code, reason = item.get("code"), item.get("reason", "")
+        msg = item.get("message", f"binding failed: {reason or code}")
+        if code == 404:
+            return NotFound(msg)
+        if code == 409:
+            return Conflict(msg)
+        if code == 503:
+            return (QuorumLost if reason == "WriteQuorumLost"
+                    else DegradedWrites)(msg)
+        return msg
 
 
 def serving_health_lines() -> List[str]:

@@ -16,6 +16,9 @@ to panic recovery + optional admit hooks):
   PUT    /api/v1/namespaces/{ns}/{resource}/{name}
   DELETE /api/v1/namespaces/{ns}/{resource}/{name}
   POST   /api/v1/namespaces/{ns}/pods/{name}/binding     (bind subresource)
+  POST   /api/v1/bindings                      (a BindingList: one store
+         call, one WAL group and one fsync for the list; one Status per
+         item in the reply, in order)
 
 Watch responses stream newline-delimited JSON events
 ({"type": "ADDED"|"MODIFIED"|"DELETED", "object": {...}}), the same wire
@@ -73,6 +76,43 @@ def _request_set(verb: str, resource: str):
         "apiserver_request_stage_seconds",
         {"resource": resource, "stage": REQUEST_STAGES},
     )
+
+
+def _failure_status(code: int, reason: str, message: str) -> dict:
+    return {
+        "kind": "Status",
+        "apiVersion": "v1",
+        "status": "Failure",
+        "reason": reason,
+        "message": message,
+        "code": code,
+    }
+
+
+def _degraded_reason(e: DegradedWrites) -> str:
+    if isinstance(e, DiskFailed):
+        return "DiskFailed"
+    if isinstance(e, DiskPressure):
+        return "DiskPressure"
+    if isinstance(e, QuorumLost):
+        return "WriteQuorumLost"
+    return "Degraded"
+
+
+def _bind_outcome_status(err) -> dict:
+    """One item of the BindingList reply: the store's typed entry for
+    that binding, under the code the single route answers it with. A
+    vanished pod is 404 (the scheduler's reconciler branches on
+    NotFound), a real bind conflict (already bound / uid mismatch) 409.
+    A degraded entry comes only from a frontend, whose store is a
+    RESTClient that marks bindings instead of raising."""
+    if err is None:
+        return {"kind": "Status", "apiVersion": "v1", "status": "Success"}
+    if isinstance(err, NotFound):
+        return _failure_status(404, "NotFound", str(err))
+    if isinstance(err, DegradedWrites):
+        return _failure_status(503, _degraded_reason(err), str(err))
+    return _failure_status(409, "Conflict", str(err))
 
 
 def _publish_inflight() -> None:
@@ -154,14 +194,7 @@ class _Handler(BaseHTTPRequestHandler):
         # RESTClient honors it)
         self._json(
             code,
-            {
-                "kind": "Status",
-                "apiVersion": "v1",
-                "status": "Failure",
-                "reason": reason,
-                "message": message,
-                "code": code,
-            },
+            _failure_status(code, reason, message),
             extra_headers=(
                 {"Retry-After": str(max(1, round(retry_after_s)))}
                 if retry_after_s is not None
@@ -182,17 +215,9 @@ class _Handler(BaseHTTPRequestHandler):
         recovery is leader failover) from transient volume pressure
         ("DiskPressure": lifts when space frees). Reads and watches keep
         serving — only mutations land here."""
-        if isinstance(e, DiskFailed):
-            reason = "DiskFailed"
-        elif isinstance(e, DiskPressure):
-            reason = "DiskPressure"
-        elif isinstance(e, QuorumLost):
-            reason = "WriteQuorumLost"
-        else:
-            reason = "Degraded"
         self._status_error(
             503,
-            reason,
+            _degraded_reason(e),
             str(e),
             retry_after_s=getattr(e, "retry_after_s", 1.0),
         )
@@ -1151,6 +1176,8 @@ class _Handler(BaseHTTPRequestHandler):
         resource, ns, name, _q = self._parse()
         if resource is None:
             return self._status_error(404, "NotFound", "unknown path")
+        # POST /api/v1/bindings: a BindingList (_bind_list)
+        bind_list = resource == "bindings" and ns is None and not name
         # any authenticated user may ask "can I?" about themselves — the
         # review endpoint is exempt from the resource gate and authz
         # (apiserver authorizes selfsubjectaccessreviews for system:authenticated)
@@ -1166,7 +1193,12 @@ class _Handler(BaseHTTPRequestHandler):
             authz_resource = resource
             if resource == "pods" and name and name.endswith("/binding"):
                 authz_resource = "bindings"
-            if not self._authorize("create", authz_resource, ns):
+            # a BindingList names its namespaces in its body: authn now,
+            # `create` on `bindings` in each of them once it is read
+            if bind_list:
+                if not self._authenticate()[1]:
+                    return
+            elif not self._authorize("create", authz_resource, ns):
                 return
         # stages of a write around the store: `authz` (limiter, authn,
         # routing, authz) ends here; `read` (body read + decode) where the
@@ -1175,6 +1207,8 @@ class _Handler(BaseHTTPRequestHandler):
         self._t_authz = self._t_read = self._t_store = time.monotonic()
         try:
             body = self._read_body()
+            if bind_list:
+                return self._bind_list(body)
             if resource == "pods" and name and name.endswith("/exec"):
                 # pods/{name}/exec subresource (ExecSync through the pod's
                 # kubelet); body: {"command": [...]} — plain-text reply
@@ -1342,6 +1376,65 @@ class _Handler(BaseHTTPRequestHandler):
             return self._status_error(400, "Invalid", str(e))
         except (KeyError, json.JSONDecodeError) as e:
             return self._status_error(400, "BadRequest", str(e))
+
+    def _bind_list(self, body: dict):
+        """POST /api/v1/bindings: a BindingList, applied by ONE call of the
+        store's bind_pods — one hold of the `store` lock, the fence
+        checked under it, one WAL group and one fsync, the watch events
+        after it — and answered with one Status per item, in order. The
+        reply is written after that call returned: every binding it
+        reports as applied is in a WAL record an fsync has covered.
+        Refused as a whole, nothing applied: a namespace the caller may
+        not bind in (403), a malformed fence (400), a superseded one (409
+        LeaderFenced), a degraded store (503); the caller's handlers map
+        what the store raises, as for the single route."""
+        items = body.get("items")
+        if (
+            not isinstance(items, list)
+            or not items
+            or not all(isinstance(it, dict) for it in items)
+        ):
+            return self._status_error(
+                400, "BadRequest", "a BindingList needs a list of Bindings"
+            )
+        bindings = [codec.from_dict(Binding, it) for it in items]
+        for b in bindings:
+            b.pod_namespace = b.pod_namespace or "default"
+        for ns in sorted({b.pod_namespace for b in bindings}):
+            if not self._authorize("create", "bindings", ns):
+                return
+        # one fence for the list, rebuilt and validated as the single
+        # route does: malformed is 400, never an unfenced bind
+        from ..client.leaderelection import FENCE_HEADER, fence_from_header
+
+        fence = None
+        fence_hdr = self.headers.get(FENCE_HEADER)
+        if fence_hdr:
+            try:
+                fence = fence_from_header(fence_hdr)
+            except ValueError as fe:
+                return self._status_error(400, "BadRequest", str(fe))
+        # the scheduler-minted trace ids travel per item: the store's
+        # apply (or its fenced rejection) stamps each bind under its own
+        from ..utils.tracing import bind_context
+
+        traces = {
+            f"{b.pod_namespace}/{b.pod_name}": str(it["traceContext"])
+            for b, it in zip(bindings, items)
+            if it.get("traceContext")
+        }
+        self._t_read = time.monotonic()
+        with bind_context(traces):
+            errs = self.store.bind_pods(bindings, fence=fence)
+        self._t_store = time.monotonic()
+        return self._json(
+            200,
+            {
+                "kind": "StatusList",
+                "apiVersion": "v1",
+                "items": [_bind_outcome_status(e) for e in errs],
+            },
+        )
 
     def _handle_PUT(self):
         if self._maybe_proxy():

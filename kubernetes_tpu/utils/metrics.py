@@ -443,7 +443,9 @@ DEFAULT_BUCKETS = tuple(_DEF_BUCKETS)
 def rest_resource_label(path: str) -> str:
     """The `resource` label of a REST path, server and client side alike:
     `pods`, `pods/binding`, `nodes`, ... for /api/v1 and /apis/<g>/<v>
-    paths (a subresource keeps its name, an object's name never appears),
+    paths (a subresource keeps its name, an object's name never appears;
+    the `bindings` collection, which takes a list of them, is
+    `pods/binding` too: one series for "a binding request"),
     the first segment for the few non-resource routes (`metrics`,
     `healthz`, `debug`), else `other`. Bounded: a label is a path KIND."""
     q = path.find("?")
@@ -463,6 +465,8 @@ def rest_resource_label(path: str) -> str:
     if rest[0] == "namespaces" and len(rest) >= 3:
         rest = rest[2:]
     # rest = [resource, name?, subresource?]
+    if rest[0] == "bindings":
+        return "pods/binding"
     return f"{rest[0]}/{rest[2]}" if len(rest) > 2 else rest[0]
 
 

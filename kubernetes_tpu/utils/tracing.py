@@ -17,8 +17,9 @@ tail-latency attribution layer:
     REST view (slowest-N, by-id lookup), and the `--debug-port`
     listener on scheduler/controller-manager processes;
   * trace context **propagates across process boundaries**: the REST
-    client attaches an ``X-Trace-Context`` header to every `/binding`
-    POST, the route re-establishes the context thread-locally, and the
+    client attaches an ``X-Trace-Context`` header to a `/binding` POST
+    and a ``traceContext`` to each item of a BindingList, the route
+    re-establishes the context thread-locally, and the
     store stamps the apply — or the LeaderFenced rejection — under the
     same id into a bounded store-side ledger (`stamp_bind`), so a
     zombie's fenced bind is visible as a trace event in the store
@@ -73,8 +74,9 @@ from typing import Callable, Dict, List, Optional, Tuple
 from ..testing.lockgraph import named_lock, track_attrs
 from .metrics import DEFAULT_BUCKETS
 
-# the cross-process propagation header (attached by RESTClient
-# bind_pod/bind_pods, validated/consumed by the /binding route)
+# the cross-process propagation header (attached by RESTClient.bind_pod,
+# consumed by the /binding route; bind_pods sends the same id per item of
+# its BindingList)
 TRACE_HEADER = "X-Trace-Context"
 
 COUNTER_STARTED = "tracing_traces_total"
@@ -887,8 +889,9 @@ def stall_lines(n: int = 8) -> List[str]:
 @contextmanager
 def bind_context(mapping: Dict[str, str]):
     """Establish pod-key -> trace-id context for the current thread (the
-    REST /binding route enters this from the X-Trace-Context header so
-    the store's stamps land under the scheduler-minted id)."""
+    REST binding routes enter this from the X-Trace-Context header, or
+    from the items of a BindingList, so the store's stamps land under
+    the scheduler-minted id)."""
     prev = getattr(_tls, "bind_ctx", None)
     _tls.bind_ctx = mapping
     try:

@@ -286,13 +286,16 @@ def test_rest_bind_fence_malformed_header_is_400(rest):
     assert client.get("pods", "default", "mp0").spec.node_name == ""
 
 
-def test_rest_fenced_mid_batch_leaves_prefix_applied_once(rest):
-    """A fenced 409 arriving mid-batch raises (the remaining bindings
-    are never attempted) while the bindings that landed before the
-    takeover stay applied exactly once."""
+def test_rest_fenced_mid_batch_leaves_prefix_applied_once(rest, monkeypatch):
+    """A fenced 409 arriving mid-batch raises (the remaining chunks are
+    never attempted) while the chunk that landed before the takeover
+    stays applied exactly once. Chunks of one binding, so that three
+    bindings are three requests."""
+    from kubernetes_tpu.apiserver import client as client_mod
     from kubernetes_tpu.client.apiserver import LeaderFenced
     from kubernetes_tpu.api.objects import Binding
 
+    monkeypatch.setattr(client_mod, "BIND_CHUNK", 1)
     client, store, _port = rest
     client.create("nodes", make_node("n0"))
     _make_lease(store, holder="sched-a", transitions=3)

@@ -205,7 +205,7 @@ def test_stale_pooled_socket_reopens_once_and_never_double_sends_bind():
         opened0 = metrics.counter(COUNTER_CONN_OPENED)
         b = Binding(pod_name="p", pod_namespace="default", target_node="n1")
         client.bind_pods([b])
-        binds = [r for r in server.requests if r[1].endswith("/binding")]
+        binds = [r for r in server.requests if r[1].endswith("/bindings")]
         assert len(binds) == 1, f"bind sent {len(binds)} times"
         assert metrics.counter(COUNTER_CONN_OPENED) - opened0 == 1
         assert server.connections == 2
@@ -229,7 +229,7 @@ def test_reused_conn_dying_mid_bind_post_classifies_quorum_lost():
         b = Binding(pod_name="p", pod_namespace="default", target_node="n1")
         errs = client.bind_pods([b])
         assert isinstance(errs[0], QuorumLost), errs
-        binds = [r for r in server.requests if r[1].endswith("/binding")]
+        binds = [r for r in server.requests if r[1].endswith("/bindings")]
         assert len(binds) == 1  # delivered once, NEVER re-sent
         assert server.connections == 1  # the bind rode the reused socket
     finally:

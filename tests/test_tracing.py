@@ -451,6 +451,7 @@ def test_spans_use_monotonic_never_wall_clock():
 # -- ISSUE 25: the loop's wall, a pod's stages over a window, the store's ------
 # -- write path and the stalls, as series on /metrics --------------------------
 
+import contextlib  # noqa: E402
 import glob  # noqa: E402
 import os  # noqa: E402
 import signal  # noqa: E402
@@ -818,14 +819,15 @@ def _scrape(port):
     return out
 
 
-@pytest.fixture(params=["native", "python"])
-def apiserver_process(request, tmp_path):
+@contextlib.contextmanager
+def apiserver_child(sink, tmp_path):
     """`python -m kubernetes_tpu.cmd.apiserver --data-dir`: WAL + fsync
-    on, the native group-commit sink or (no compiler on PATH, an empty
-    build cache) the Python one."""
+    on, the native group-commit sink or (`sink == "python"`: no compiler
+    on PATH, an empty build cache) the Python one. Yields (port, process,
+    WAL path); the process is SIGKILLed on the way out."""
     port = _free_port()
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    if request.param == "python":
+    if sink == "python":
         env["PATH"] = str(tmp_path / "no-compiler-here")
         env["TMPDIR"] = str(tmp_path / "tmp")
         os.makedirs(env["TMPDIR"])
@@ -844,11 +846,18 @@ def apiserver_process(request, tmp_path):
                 return False
 
         assert wait_until(up, 60), "cmd/apiserver did not come up"
-        yield request.param, port
+        yield port, proc, str(tmp_path / "data" / "cluster")
     finally:
-        proc.send_signal(signal.SIGKILL)
+        if proc.poll() is None:
+            proc.send_signal(signal.SIGKILL)
         proc.wait(10)
         proc.stderr.close()
+
+
+@pytest.fixture(params=["native", "python"])
+def apiserver_process(request, tmp_path):
+    with apiserver_child(request.param, tmp_path) as (port, _proc, _wal):
+        yield request.param, port
 
 
 N_OPS = 25
@@ -876,7 +885,7 @@ WRITE_SERIES = [
 def test_a_write_is_counted_once_per_stage(apiserver_process):
     """N creates and N binds over REST: every request and stage series
     reads exactly N per op, the WAL counts 2N + 1 records (one node) and
-    at most as many physical fsyncs, a bind sent with X-Trace-Context
+    at most as many physical fsyncs, a bind sent with its trace id
     shows its store stages under /debug/traces?id=, and the watch
     delivered every event."""
     sink, port = apiserver_process
@@ -898,8 +907,11 @@ def test_a_write_is_counted_once_per_stage(apiserver_process):
         client.create("pods", make_pod(f"ws-{i}"))
     binds = [Binding(pod_name=f"ws-{i}", pod_namespace="default",
                      target_node="ws-0") for i in range(N_OPS)]
+    # one binding per request, so that every series reads N (a list of N
+    # in one request: tests/test_bind_batch.py)
     with bind_context({"default/ws-0": "feedbeefcafe0025"}):
-        assert client.bind_pods(binds) == [None] * N_OPS
+        for b in binds:
+            assert client.bind_pods([b]) == [None]
     assert wait_until(lambda: len(seen) >= 2 * N_OPS, 20)
     watcher.stop()
     # a stream folds its deliveries locally and merges them when it idles
