@@ -10,6 +10,14 @@ that is there.
                  by the end-to-end metric it moves (`x.backlog`), the file
                  of the name before its last dot (`x.json`)
   reader         benchmark/readers/<kind>.py, one function `read(ctx, **args)`
+  reference rule benchmark/reference_rules/<name>.py, for each name a
+                 configuration lists under `"reference_rules"`: one more
+                 filter of the plain reference (harness/check.py), two
+                 functions `why_not(manifest, node_name, cluster)` and
+                 `bind(manifest, node_name, cluster)`, nothing of the program
+
+A configuration may also state `"rehearsal_nodes"`: the size of its CPU
+rehearsal (tests/benchmark), 64 unless it needs more, 256 at most.
 """
 
 from __future__ import annotations
@@ -17,6 +25,10 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+
+from .check import SMALL_CLUSTER_NODES
+
+REHEARSAL_NODES = 64
 
 
 class Catalog:
@@ -64,13 +76,33 @@ class Catalog:
                 return self._json(path)
         raise KeyError(f"no benchmark/layer_metrics file for {name!r}")
 
-    def reader(self, kind: str):
-        path = os.path.join(self.dir, "readers", kind + ".py")
+    def _module(self, folder: str, name: str):
+        path = os.path.join(self.dir, folder, name + ".py")
+        if not os.path.exists(path):
+            raise KeyError(f"no benchmark/{folder}/{name}.py for {name!r}")
         spec = importlib.util.spec_from_file_location(
-            f"benchmark_reader_{kind}", path)
+            f"benchmark_{folder}_{name}", path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        return mod.read
+        return mod
+
+    def reader(self, kind: str):
+        return self._module("readers", kind).read
+
+    def reference_rules(self, config: dict) -> list:
+        """The modules of the rules `config` names, in its order; none for
+        a configuration that names none."""
+        return [self._module("reference_rules", name)
+                for name in config.get("reference_rules", [])]
+
+    @staticmethod
+    def rehearsal_nodes(config: dict) -> int:
+        n = int(config.get("rehearsal_nodes", REHEARSAL_NODES))
+        # up to there the program's own small-batch host lane is tolerated
+        if not 1 <= n <= SMALL_CLUSTER_NODES:
+            raise ValueError(f"rehearsal_nodes {n}: a rehearsal has 1 to "
+                             f"{SMALL_CLUSTER_NODES} nodes")
+        return n
 
     def read_layer_metrics(self, cell_name: str, ctx: dict) -> dict:
         """name -> {"value", "unit"} for every per-layer metric of the

@@ -130,7 +130,9 @@ def start_serving(name: str, argv_of_port, env: dict, log_path: str,
         deadline = time.monotonic() + deadline_s
         while child.alive() and time.monotonic() < deadline:
             try:
-                http_get(f"http://127.0.0.1:{port}{health_path}", timeout=1.0)
+                # 5 s, not 1: a poll that gives up on a child busy starting
+                # leaves a BrokenPipeError traceback in the child's log
+                http_get(f"http://127.0.0.1:{port}{health_path}", timeout=5.0)
                 return child, port
             except OSError:
                 time.sleep(0.1)

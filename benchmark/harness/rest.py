@@ -12,56 +12,72 @@ import time
 
 
 class Rest:
+    """Kept-alive connections, shared by every thread that asks: one is
+    taken for a request and put back after it. `warm(n, path)` opens n of
+    them one after another, each answered once before the next is opened;
+    after that a window's senders open none, however many start at once.
+    The apiserver listens with a backlog of 5: 64 connects in one instant
+    had 3 creates reset at the start of a window (1 run of 16 on the chip,
+    PR 29), and 64 one after another, unanswered, still overflowed it and
+    cost set-up 7 s of SYN retransmissions."""
+
     def __init__(self, port: int, host: str = "127.0.0.1",
                  timeout: float = 30.0):
         self.host, self.port, self.timeout = host, port, timeout
-        self._local = threading.local()
+        self._idle: list = []  # open connections nobody is using
+        self.refused: list = []  # why a create was not acknowledged
 
-    def _conn(self) -> http.client.HTTPConnection:
-        c = getattr(self._local, "conn", None)
-        if c is None:
-            c = http.client.HTTPConnection(self.host, self.port,
-                                           timeout=self.timeout)
-            c.connect()
-            c.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-            self._local.conn = c
+    def _open(self) -> http.client.HTTPConnection:
+        c = http.client.HTTPConnection(self.host, self.port,
+                                       timeout=self.timeout)
+        c.connect()
+        c.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         return c
 
-    def _drop(self) -> None:
-        c = getattr(self._local, "conn", None)
-        self._local.conn = None
-        if c is not None:
-            try:
-                c.close()
-            except OSError:
-                pass
+    def warm(self, n: int, path: str) -> None:
+        for _ in range(n - len(self._idle)):
+            c = self._open()
+            c.request("GET", path)
+            c.getresponse().read()  # accepted and served: now the next
+            self._idle.append(c)
 
     def request(self, method: str, path: str, body: bytes | None = None):
-        """(status, body bytes) over this thread's kept-alive connection.
-        A connection that died idle is reopened once for a GET; a write is
-        never replayed (it may have been applied)."""
+        """(status, body bytes) over a kept-alive connection. One that
+        died idle is replaced once for a GET; a write is never replayed
+        (it may have been applied)."""
         for attempt in (1, 2):
             try:
-                c = self._conn()
+                c = self._idle.pop()
+            except IndexError:
+                c = self._open()
+            try:
                 c.request(method, path, body=body,
                           headers={"Content-Type": "application/json"})
                 r = c.getresponse()
-                return r.status, r.read()
+                data = r.read()
             except (OSError, http.client.HTTPException):
-                self._drop()
+                c.close()
                 if method != "GET" or attempt == 2:
                     raise
+                continue
+            self._idle.append(c)
+            return r.status, data
 
     def create(self, path: str, body: bytes) -> bool:
         """POST one object; True when the server acknowledged it."""
         try:
             status, _ = self.request("POST", path, body)
-        except (OSError, http.client.HTTPException):
+        except (OSError, http.client.HTTPException) as e:
+            self.refused.append(repr(e))
             return False
-        return 200 <= status < 300
+        if not 200 <= status < 300:
+            self.refused.append(f"HTTP {status}")
+            return False
+        return True
 
     def close(self) -> None:
-        self._drop()
+        while self._idle:
+            self._idle.pop().close()
 
 
 class BindWatch:

@@ -53,6 +53,19 @@ def _render(manifest: dict) -> str:
     return json.dumps(manifest, separators=(",", ":"))
 
 
+def offered_rate(traffic: dict, scale: float) -> float:
+    """The traffic file's rate; a rehearsal on a small cluster (`scale`
+    under 1: its share of the configuration's nodes) offers the same share
+    of it, and 20 pods/s at the least."""
+    r = float(traffic["rate_per_s"])
+    return r if scale == 1 else max(20.0, r * scale)
+
+
+def warmup_burst(traffic: dict, scale: float) -> int:
+    """The pods of the warm-up burst, scaled as the rate is; 10 at least."""
+    return max(10, int(round(traffic["warmup"]["burst_pods"] * scale)))
+
+
 class Cluster:
     """The configuration's objects, made from the seed: node manifests,
     the residents with the node each is created bound to, and pod bodies
@@ -116,6 +129,8 @@ class Run:
         self.cell = catalog.cell(cell_name)
         self.config = catalog.config(self.cell["config"])
         self.traffic = catalog.traffic(self.cell)
+        # found now: a rule that is named and not there ends the run here
+        self.rules = catalog.reference_rules(self.config)
         self.seed = seed
         self.out = out_dir
         self.rehearse_cpu = rehearse_cpu
@@ -149,6 +164,8 @@ class Run:
             env, os.path.join(self.out, "apiserver.log"), "/healthz", 60.0)
         self.children.append(self.api)
         self.rest = Rest(self.api_port)
+        # every connection the run will use, opened now and one by one
+        self.rest.warm(max(8, self.senders), "/healthz")
 
         # the watch first, from rv 0 while no pod exists: every pod event
         # of the run then reaches it, the residents' too, in commit order
@@ -196,7 +213,7 @@ class Run:
         # warm-up with the cell's own pod template: one burst larger than
         # the small batch bucket, then a little of the cell's own arrivals
         w = self.traffic["warmup"]
-        burst = max(10, int(round(w["burst_pods"] * c.scale)))
+        burst = warmup_burst(self.traffic, c.scale)
         t = time.monotonic()
         names = [f"u-{i}" for i in range(burst)]
         tpl = self.traffic["pod_template"]
@@ -218,10 +235,11 @@ class Run:
         self.detail["warmup_unbound"] = left
 
     def rate(self) -> float:
-        """The traffic file's rate; a rehearsal on a small cluster offers
-        the same share of it."""
-        r = float(self.traffic["rate_per_s"])
-        return r if self.cluster.scale == 1 else max(20.0, r * self.cluster.scale)
+        return offered_rate(self.traffic, self.cluster.scale)
+
+    @property
+    def senders(self) -> int:
+        return int(self.traffic.get("senders", 16))
 
     def _create_all(self, path: str, bodies: list, threads: int = 8) -> None:
         with ThreadPoolExecutor(threads) as pool:
@@ -288,9 +306,13 @@ class Run:
 
     # -- a window ------------------------------------------------------------
 
-    def _offer(self, rate: float, seconds: float, seed: int, prefix: str):
+    def _offer(self, rate: float, seconds: float, seed: int, prefix: str,
+               at_end=None):
         """Offer `seconds` of arrivals at `rate`, open loop, and return
-        (Window, the instant the offering ended)."""
+        (Window, the instant the offering ended). `at_end()` is called
+        from a thread of its own at the instant the window closes, however
+        far behind its schedule the offering runs by then (past the knee
+        the last creates leave many seconds late)."""
         c = self.cluster
         due = loadgen.schedule(rate, seconds, seed,
                                self.traffic.get("arrivals", "poisson"))
@@ -300,10 +322,17 @@ class Run:
         path = f"/api/v1/namespaces/{c.ns}/pods"
         win = loadgen.Window(time.monotonic() + 0.05, seconds, due,
                              [f"{c.ns}/{n}" for n in names])
+        closing = None
+        if at_end is not None:
+            closing = threading.Timer(
+                win.t0 + seconds - time.monotonic(), at_end)
+            closing.start()
         loadgen.send_open_loop(
-            win, lambda i: self.rest.create(path, bodies[i]),
-            int(self.traffic.get("senders", 16)))
-        return win, time.monotonic()
+            win, lambda i: self.rest.create(path, bodies[i]), self.senders)
+        t_offered = time.monotonic()
+        if closing is not None:
+            closing.join()
+        return win, t_offered
 
     def scrapes(self) -> dict:
         out = {}
@@ -315,6 +344,25 @@ class Run:
             except OSError as e:
                 say(f"/metrics of {name} did not answer: {e}")
                 out[name] = Scrape("")
+        return out
+
+    def long_passes(self, t0: float, floor_ms: float = 50.0) -> list:
+        """[who, task, seconds from `t0`, ms] of every background pass of
+        either child since `t0` that took `floor_ms` or more (anti-entropy,
+        the queue's flushes, the locked part of a WAL compaction), from
+        their `/debug/traces?stalls=1`: for detail.json, never a metric.
+        All three processes read one monotonic clock."""
+        out = []
+        for who, port in (("sched", self.health_port), ("api", self.api_port)):
+            try:
+                ev = json.loads(http_get(
+                    f"http://127.0.0.1:{port}/debug/traces?stalls=1",
+                    timeout=10.0))
+            except (OSError, ValueError):
+                continue
+            out += [[who, e["task"], round(e["t0"] - t0, 2), e["ms"]]
+                    for e in ev.get("passes", [])
+                    if e["ms"] >= floor_ms and e["t0"] >= t0]
         return out
 
     def window(self, seconds: float, rate: float | None = None,
@@ -348,11 +396,17 @@ class Run:
             self.setup_s = time.monotonic() + 0.05 - T_PROCESS_START
         for t in timers:
             t.start()
-        win, t_offered = self._offer(rate, seconds, self.seed, prefix)
-        wait = win.t0 + seconds - time.monotonic()
-        if wait > 0:
-            time.sleep(wait)
-        end_scrapes = self.scrapes()
+        # the closing scrapes are taken AT the window's end, not when the
+        # offering has ended: past the knee that is many seconds later
+        closed: dict = {}
+
+        def close_window():
+            closed["scrapes"] = self.scrapes()
+            closed["at"] = time.monotonic()
+
+        win, t_offered = self._offer(rate, seconds, self.seed, prefix,
+                                     at_end=close_window)
+        end_scrapes = closed["scrapes"]
         acked = [k for k, a in zip(win.keys, win.acked) if a]
         left = self._wait_bound(
             acked, max(0.0, win.t0 + seconds + drain_deadline_s
@@ -368,7 +422,10 @@ class Run:
         stats.update(rate_offered=rate, seconds=seconds,
                      offering_overran_s=max(0.0, t_offered - win.t0 - seconds),
                      drain_s=t_gave_up - (win.t0 + seconds),
-                     unbound_after_drain=left)
+                     end_scrape_s=closed["at"] - (win.t0 + seconds),
+                     unbound_after_drain=left,
+                     refused_why=self.rest.refused[-5:],
+                     long_passes=self.long_passes(win.t0))
         compiles = (end_scrapes["sched"].total("jax_backend_compiles_total")
                     - start_scrapes["sched"].total("jax_backend_compiles_total"))
         stats["compiles_in_window"] = int(compiles)
@@ -435,7 +492,7 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
         stats = w["stats"]
         infeasible = check.check_placements(
             c.node_manifests, run.watch.order, c.manifest_of,
-            run.watch.rebinds)
+            run.watch.rebinds, run.rules)
         wal = check.check_wal(run.data_dir, run.watch.bound)
         off_device, off_why = check.check_device_path(
             end["final"], end["sched_log"], run.platform, c.n_nodes,
@@ -504,6 +561,10 @@ def run_cell(root: str, cell_name: str, seed: int, seconds: float,
             - w["start"]["sched"].total("scheduler_wave_batches_total")),
         largest_batch=int(end["final"].total("scheduler_wave_batch_pods_max")),
         audit_passes=int(end["final"].total("snapshot_audit_passes_total")),
+        # the WAL's record count at the window's two ends: the log is
+        # compacted (the store locked for the copy) every 50,000 records
+        wal_records=[int(w[k]["api"].total("wal_records_appended_total"))
+                     for k in ("start", "end")],
         # which rule ended the trace, after how many launches, how long
         # the profiler took to write it (the launcher's `stopped`)
         trace_stopped=(ctx["trace"] or {}).get("stopped"),

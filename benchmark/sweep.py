@@ -59,6 +59,9 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=8.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--senders", type=int, default=None,
+                    help="sender threads, in place of the traffic file's: "
+                    "a sweep is made once per count before the file says one")
     ap.add_argument("--out", default=None)
     ap.add_argument("--rehearse-cpu", action="store_true")
     ap.add_argument("--nodes", type=int, default=None)
@@ -71,6 +74,8 @@ def main(argv=None) -> int:
     out = args.out or os.path.join(ROOT, "chiprun_out", "sweep", args.workload)
     run = Run(Catalog(ROOT), args.workload, args.seed, out,
               rehearse_cpu=args.rehearse_cpu, nodes=args.nodes)
+    if args.senders:
+        run.traffic["senders"] = args.senders
     steps = []
     try:
         run.setup()
@@ -80,7 +85,8 @@ def main(argv=None) -> int:
             t_back = time.monotonic()
             s = w["stats"]
             step = {k: s[k] for k in (
-                "rate_offered", "attempted", "failed", "bound_pods_per_s",
+                "rate_offered", "attempted", "failed", "refused_creates",
+                "bound_pods_per_s",
                 "pending_at_end", "create_to_bound_p50_ms",
                 "create_to_bound_p99_ms", "loadgen_lag_p99_ms", "drain_s",
                 "compiles_in_window")}
@@ -103,6 +109,7 @@ def main(argv=None) -> int:
                 if s["pending_at_end"] < s["rate_offered"]), default=None)
     print(json.dumps({
         "workload": args.workload, "device": end["device"],
+        "senders": run.senders,
         "saturation_pods_per_s": max(s["bound_pods_per_s"] for s in steps),
         "knee_pods_per_s": knee}), flush=True)
     return 0

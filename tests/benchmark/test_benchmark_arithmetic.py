@@ -304,6 +304,56 @@ def test_reference_replay_finds_what_is_infeasible():
     assert "seen bound to n1 and then to n0" in text
 
 
+class _Quiet:
+    """A configuration's rule that refuses nothing and counts its calls."""
+
+    def __init__(self):
+        self.asked = self.told = 0
+
+    def why_not(self, manifest, node, cluster):
+        self.asked += 1
+        return None
+
+    def bind(self, manifest, node, cluster):
+        self.told += 1
+
+
+@pytest.mark.parametrize("rules", ["none", "empty", "quiet"])
+def test_the_four_standing_rules_read_the_same_beside_a_configurations_rule(
+        rules):
+    """The replay of test_reference_replay_finds_what_is_infeasible, with
+    no rules argument (as before PR 29), an empty list, and a rule that
+    refuses nothing: the same violations, in the same order."""
+    nodes = [_node("n0", "z0"), _node("n1", "z1")]
+    pods = {
+        "b/r": _pod("r", {"app": "bench"}),
+        "b/a": _pod("a", {"app": "bench"}, AFF),
+        "b/c": _pod("c", {"app": "bench"}, AFF),
+        "b/d": _pod("d", {}, None),
+        "b/e": _pod("e", {}, ANTI),
+    }
+    order = [("b/r", "n0"), ("b/a", "n1"), ("b/c", "n0"), ("b/d", "n0"),
+             ("b/e", "n1"), ("b/x", "n0"), ("b/r", "n9")]
+    quiet = _Quiet()
+    args = {"none": (), "empty": ([],), "quiet": ([quiet],)}[rules]
+    got = check.check_placements(nodes, order, pods.get,
+                                 [("b/a", "n1", "n0")], *args)
+    assert got == [
+        "b/a: seen bound to n1 and then to n0",
+        "b/a on n1: no pod matching {'app': 'bench'} in "
+        "topology.kubernetes.io/zone=z1",
+        "b/d on n0: over the node's allocatable cpu",
+        "b/e on n1: a pod matching {'app': 'bench'} already in "
+        "topology.kubernetes.io/zone=z1",
+        "b/x: a bind of a pod this run never created",
+        "b/r on n9: unknown node",
+    ]
+    if rules == "quiet":
+        # asked only where the four let the bind pass; told of every bind
+        # of a pod of ours to a node that exists
+        assert (quiet.asked, quiet.told) == (2, 5)
+
+
 def test_first_pod_of_a_self_matching_affinity_group_may_go_anywhere():
     nodes = [_node("n0", "z0"), _node("n1", "z1")]
     pods = {"b/a": _pod("a", {"app": "bench"}, AFF),
@@ -368,6 +418,37 @@ def test_device_path_check_counts_what_left_the_device():
     # only a rehearsal-sized cluster may use the small-batch host lane
     assert check.check_device_path(bad, "", "tpu", 64, 100)[0] == 100 + 2
     assert check.check_device_path(ok, "", "cpu", 5000, 100)[0] == 100
+
+
+def _served_block(last_line):
+    return ("-" * 40 + "\nException occurred during processing of request "
+            "from ('127.0.0.1', 32411)\nTraceback (most recent call last):\n"
+            '  File "socketserver.py", line 845, in write\n'
+            "    self._sock.sendall(b)\n" + last_line + "\n" + "-" * 40 + "\n")
+
+
+@pytest.mark.parametrize("log,counted", [
+    # the harness's own poll hung up on a slow /healthz: not the scheduler's
+    ("up\n" + _served_block("BrokenPipeError: [Errno 32] Broken pipe")
+     + "fine\n", 0),
+    ("up\n" + _served_block("ConnectionResetError: [Errno 104] reset"), 0),
+    # a handler that failed for any other reason is counted
+    ("up\n" + _served_block("ValueError: bad page"), 1),
+    # and so is every traceback outside such a block, beside a hung-up one
+    (_served_block("BrokenPipeError: [Errno 32] Broken pipe")
+     + "Traceback (most recent call last):\n  x\nBrokenPipeError: y\n", 1),
+    # a block that never closes is left as it is
+    ("-" * 40 + "\nException occurred during processing of request from x\n"
+     "Traceback (most recent call last):\nBrokenPipeError: z\n", 1),
+], ids=["hung-up", "reset", "other-exception", "bare-traceback", "unclosed"])
+def test_a_poll_that_hung_up_is_not_a_failure_of_the_scheduler(log, counted):
+    ok = Scrape('scheduler_device_info{platform="tpu",pallas_fit="on",'
+                'pallas_interpret="false"} 1\n')
+    off, why = check.check_device_path(ok, log, "tpu", 5000, 100)
+    assert off == counted and len(why) == counted
+    kept = check.without_hung_up_clients(log)
+    assert kept.count("Traceback") == counted
+    assert "up\n" not in log or kept.startswith("up\n")
 
 
 # -- BENCHMARK.json ------------------------------------------------------------------

@@ -1,5 +1,6 @@
-"""The one command, rehearsed on the CPU at 64 nodes: each cell twice in
-a row, the last line, no process left; the refusal without a chip; and
+"""The one command, rehearsed on the CPU at its configuration's
+`rehearsal_nodes` (64 unless it says more): each cell twice in a row, the
+last line, no process left; the refusal without a chip; and
 the run driven with the timed path broken underneath, or with a guarantee
 of the configuration switched off, which has to come out not correct."""
 
@@ -16,6 +17,22 @@ sys.path.insert(0, str(REPO / "benchmark"))
 CELLS = [w["name"] for w in
          json.loads((REPO / "BENCHMARK.json").read_text())["workloads"]]
 KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
+SECONDS = 4
+
+
+def _rehearsal(cell: str) -> tuple:
+    """(--nodes, the pods the window has to attempt) of a cell's
+    rehearsal: the configuration's `rehearsal_nodes`, and the supervisor's
+    own arithmetic of a small cluster's rate."""
+    from harness.catalog import Catalog
+    from harness.supervisor import offered_rate
+
+    cat = Catalog(str(REPO))
+    c = cat.cell(cell)
+    config = cat.config(c["config"])
+    nodes = cat.rehearsal_nodes(config)
+    rate = offered_rate(cat.traffic(c), nodes / config["nodes"]["count"])
+    return nodes, int(rate * SECONDS + 1e-9)
 
 
 def _env(tmp_path):
@@ -35,12 +52,13 @@ def _leftovers(marker: str) -> list:
 @pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_twice_in_a_row(cell, tmp_path_factory):
     tmp_path = tmp_path_factory.mktemp("rehearsal")
+    nodes, attempted = _rehearsal(cell)
     for attempt, trace in ((1, 0), (2, 1)):
         out = tmp_path / f"out{attempt}"
         r = subprocess.run(
             [sys.executable, "benchmark/run.py", "--workload", cell,
-             "--seed", str(2**31 + attempt), "--seconds", "4",
-             "--trace", str(trace), "--rehearse-cpu", "--nodes", "64",
+             "--seed", str(2**31 + attempt), "--seconds", str(SECONDS),
+             "--trace", str(trace), "--rehearse-cpu", "--nodes", str(nodes),
              "--out", str(out)],
             capture_output=True, text=True, timeout=300, cwd=REPO,
             env=_env(tmp_path))
@@ -49,7 +67,7 @@ def test_rehearsal_twice_in_a_row(cell, tmp_path_factory):
         want = KEYS[:5] + (["breakdown"] if trace else []) + KEYS[5:]
         assert list(last) == want
         assert last["correct"] is True and last["failed"] == 0
-        assert last["attempted"] == 80
+        assert last["attempted"] == attempted
         assert last["device"]["platform"] == "cpu"
         assert all(v == 0 and lim == 0 for v, lim in last["compared"].values())
         bench = json.loads((REPO / "BENCHMARK.json").read_text())

@@ -1,5 +1,6 @@
 """The per-layer metrics of ISSUE 25 (loop phases, pod stages, the
-apiserver's write path, stalls): each entry of BENCHMARK.json has its
+apiserver's write path, stalls) and ISSUE 29's `api_background_ms_per_s`
+(the apiserver's background passes: WAL compaction's locked copy): each entry of BENCHMARK.json has its
 file, each file reads what it says from two scrapes, nothing from two
 empty ones, and a number in a CPU rehearsal of its cell."""
 
@@ -24,10 +25,16 @@ STEMS = [
     "api_bind_ms", "store_lock_wait_ms", "store_apply_ms", "wal_append_ms",
     "watch_notify_ms", "wal_records_per_fsync", "watch_delivery_ms",
     "sched_gc_pause_ms_per_s", "api_gc_pause_ms_per_s",
-    "sched_background_ms_per_s",
+    "sched_background_ms_per_s", "api_background_ms_per_s",
 ]
-CELL_OF = {"backlog": ("perf5k-podaffinity.backlog", "bound_pods_per_s"),
-           "steady": ("perf5k-basic.steady", "create_to_bound_p50_ms")}
+# suffix -> (the cells that report it, the end-to-end metric it moves)
+CELLS_OF = {"backlog": (["perf5k-podaffinity.backlog", "perf5k-basic.backlog"],
+                        "bound_pods_per_s"),
+            "steady": (["perf5k-basic.steady"], "create_to_bound_p50_ms")}
+CELL_OF = {suffix: (cells[0], moves)
+           for suffix, (cells, moves) in CELLS_OF.items()}
+SUFFIX_OF = {cell: suffix for suffix, (cells, _) in CELLS_OF.items()
+             for cell in cells}
 
 # two scrapes of each child, 10 s apart on the process's own clock, with
 # round numbers: what each metric has to read from them
@@ -104,6 +111,8 @@ apiserver_watch_delivery_seconds_sum{kind="nodes"} 50.0
 apiserver_watch_delivery_seconds_count{kind="nodes"} 1
 process_gc_pause_seconds_sum{generation="1"} 0.05
 process_gc_pause_seconds_count{generation="1"} 30
+store_background_pass_seconds_sum{task="wal_compact_copy"} 0.8
+store_background_pass_seconds_count{task="wal_compact_copy"} 1
 """
 EXPECT = {
     "queue_wait_ms": 8.0,
@@ -126,6 +135,7 @@ EXPECT = {
     "sched_gc_pause_ms_per_s": 30.0,         # 0.3 s of pauses in 10 s
     "api_gc_pause_ms_per_s": 5.0,
     "sched_background_ms_per_s": 5.0,
+    "api_background_ms_per_s": 80.0,         # one 0.8 s compaction copy in 10 s
 }
 
 
@@ -143,8 +153,8 @@ def _entries():
 @pytest.mark.parametrize("stem", STEMS)
 def test_every_new_entry_has_its_file(stem, suffix):
     entry = _entries()[f"{stem}.{suffix}"]
-    cell, moves = CELL_OF[suffix]
-    assert entry["workloads"] == [cell] and entry["moves"] == moves
+    cells, moves = CELLS_OF[suffix]
+    assert entry["workloads"] == cells and entry["moves"] == moves
     assert entry["source"] in ("program_span", "program_counter")
     assert set(entry) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
@@ -154,13 +164,19 @@ def test_every_new_entry_has_its_file(stem, suffix):
     assert (REPO / "benchmark" / "readers" / f"{spec['reader']}.py").is_file()
 
 
+LAYERS = {"load generator", "queue + batch former", "host encode",
+          "wave kernel", "readback + guards", "REST + store + WAL", "device",
+          "whole path"}
+
+
 def test_the_layers_are_ones_the_benchmark_already_names():
+    """PERF.md section 3's eight, letter for letter; a retired metric
+    (PR 29: `readback_wait_ms_per_wave`, the last of `readback + guards`
+    that was not ISSUE 25's) takes no layer with it."""
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    old = {m["layer"] for m in bench["per_layer"]
-           if m["name"].rsplit(".", 1)[0] not in STEMS}
-    new = {m["layer"] for m in bench["per_layer"]
-           if m["name"].rsplit(".", 1)[0] in STEMS}
-    assert new <= old
+    assert {m["layer"] for m in bench["per_layer"]} == LAYERS
+    assert {m["layer"] for m in bench["per_layer"]
+            if m["name"].rsplit(".", 1)[0] in STEMS} <= LAYERS
 
 
 @pytest.mark.parametrize("stem", STEMS)
@@ -199,12 +215,12 @@ def _env(tmp_path):
     return env
 
 
-@pytest.mark.parametrize("suffix", sorted(CELL_OF))
-def test_the_rehearsal_reads_a_number_for_every_new_metric(suffix, tmp_path):
+@pytest.mark.parametrize("cell", sorted(SUFFIX_OF))
+def test_the_rehearsal_reads_a_number_for_every_new_metric(cell, tmp_path):
     """300 nodes, not 64: on a cluster of <= 256 nodes the program's own
     host lane takes the window's 1-4 pod batches, no wave is launched,
     and the per-wave metrics have nothing to divide by."""
-    cell = CELL_OF[suffix][0]
+    suffix = SUFFIX_OF[cell]
     r = subprocess.run(
         [sys.executable, "benchmark/run.py", "--workload", cell,
          "--seed", str(2**31 + 25), "--seconds", "4", "--trace", "1",
