@@ -40,10 +40,15 @@ import dataclasses
 import json
 import struct
 import typing
-from typing import Any, Dict, List, Optional, Tuple, Type, get_args, get_origin, get_type_hints
+from typing import Any, Dict, List, Optional, Tuple, Type, get_args, get_origin
 
 from . import objects as v1
-from .serialization import KIND_TO_RESOURCE, RESOURCE_KINDS
+from .serialization import (
+    KIND_TO_RESOURCE,
+    RESOURCE_KINDS,
+    _resolve_optional,
+    resolved_hints,
+)
 
 MAGIC = b"k8s\x00"
 CONTENT_TYPE = "application/vnd.kubernetes.protobuf"
@@ -99,14 +104,6 @@ _SCHEMA: Dict[type, List[Tuple[int, str, Any]]] = {}
 _DEFAULTS: Dict[type, Dict[str, Any]] = {}
 
 
-def _resolve_optional(tp):
-    if get_origin(tp) is typing.Union:
-        args = [a for a in get_args(tp) if a is not type(None)]
-        if len(args) == 1:
-            return args[0]
-    return tp
-
-
 # bare container hints (list, not List[X]) have no get_origin/get_args;
 # normalize them to their Any-parameterized forms so the container
 # branches fire
@@ -120,7 +117,7 @@ _BARE_HINTS = {
 def _schema(cls: type) -> List[Tuple[int, str, Any]]:
     s = _SCHEMA.get(cls)
     if s is None:
-        hints = get_type_hints(cls)
+        hints = resolved_hints(cls)
         s = _SCHEMA[cls] = [
             (i, f.name, _BARE_HINTS.get(hints[f.name], hints[f.name]))
             for i, f in enumerate(dataclasses.fields(cls), start=1)

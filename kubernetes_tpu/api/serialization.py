@@ -15,8 +15,9 @@ from __future__ import annotations
 import base64
 import dataclasses
 import typing
-from typing import Any, Dict, Optional, Type, get_args, get_origin, get_type_hints
+from typing import Any, Callable, Dict, Optional, Type, get_args, get_origin, get_type_hints
 
+from ..utils.metrics import metrics
 from . import objects as v1
 
 # resource name -> (kind string, class)
@@ -115,27 +116,133 @@ def _snake(name: str) -> str:
     return "".join(out)
 
 
+def _resolve_optional(tp):
+    if get_origin(tp) is typing.Union:
+        args = [a for a in get_args(tp) if a is not type(None)]
+        if len(args) == 1:
+            return args[0]
+    return tp
+
+
+# -- the per-class plan -------------------------------------------------------
+
+# Values of these types are their own wire form.
+_PLAIN = frozenset({str, int, float, bool, type(None)})
+
+
+class _Plan:
+    """What the codec needs of one dataclass, worked out once.
+
+    `encode`: one row per field in declaration order (the wire's key
+    order): (attribute name, wire name, default or MISSING, whether an
+    encoded "" is dropped). `decode`: (wire name, the snake_case spelling
+    where it differs else None, attribute name, decoder or None for a
+    value that passes through). `hints`: the class's resolved annotations,
+    or None with `error` set when they do not resolve yet; such a plan
+    still encodes (encoding never needed them), decodes by raising
+    `error`, and is not kept."""
+
+    __slots__ = ("encode", "decode", "hints", "error")
+
+    def __init__(self, cls: Type):
+        self.error: Optional[Exception] = None
+        try:
+            # every api/ module has `from __future__ import annotations`:
+            # this parses and evaluates each annotation string of the class
+            self.hints: Optional[Dict[str, Any]] = get_type_hints(cls)
+        except Exception as e:  # a forward reference not importable yet
+            self.hints, self.error = None, e
+        fields = dataclasses.fields(cls)
+        wires = [_camel(f.name) for f in fields]
+        self.encode = tuple(
+            (
+                f.name,
+                wire,
+                f.default,
+                f.default is dataclasses.MISSING or f.default == "",
+            )
+            for f, wire in zip(fields, wires)
+        )
+        self.decode = tuple(
+            (
+                wire,
+                f.name if f.name != wire else None,
+                f.name,
+                _decoder(self.hints[f.name]),
+            )
+            for f, wire in zip(fields, wires)
+        ) if self.hints is not None else ()
+
+
+_PLANS: Dict[Type, _Plan] = {}
+
+
+def _plan(cls: Type) -> _Plan:
+    """The plan of a dataclass, built on first use and kept for the life
+    of the process once its annotations resolved. No lock: two threads
+    that build the same plan at once build equal plans, and the first to
+    store its own is the one counted."""
+    plan = _PLANS.get(cls)
+    if plan is None:
+        plan = _Plan(cls)
+        if plan.error is None and _PLANS.setdefault(cls, plan) is plan:
+            metrics.inc("api_codec_plans_built_total")
+    return plan
+
+
+def resolved_hints(cls: Type) -> Dict[str, Any]:
+    """`typing.get_type_hints(cls)` of a dataclass, evaluated once per
+    class: the one home of resolved annotations in api/ (this codec's
+    plans and protocodec's schema read the same cache)."""
+    plan = _plan(cls)
+    if plan.error is not None:
+        raise plan.error
+    return plan.hints
+
+
 def to_dict(obj: Any) -> Any:
     """Dataclass → JSON-ready dict (camelCase keys, omitempty)."""
-    if dataclasses.is_dataclass(obj) and not isinstance(obj, type):
-        out = {}
-        for f in dataclasses.fields(obj):
-            val = getattr(obj, f.name)
-            # omitempty: skip values equal to the field default (and empty
-            # containers from default factories)
-            if f.default is not dataclasses.MISSING and val == f.default:
+    tp = type(obj)
+    if tp in _PLAIN:
+        return obj
+    plan = _PLANS.get(tp)
+    if plan is None:
+        if tp is list or tp is tuple:
+            return [x if type(x) in _PLAIN else to_dict(x) for x in obj]
+        if tp is dict:
+            return {
+                k: x if type(x) in _PLAIN else to_dict(x)
+                for k, x in obj.items()
+            }
+        if not dataclasses.is_dataclass(tp):
+            return _to_dict_other(obj)
+        plan = _plan(tp)
+    out = {}
+    for name, wire, default, drop_empty_str in plan.encode:
+        val = getattr(obj, name)
+        # omitempty: skip values equal to the field default (and empty
+        # containers from default factories)
+        if default is not dataclasses.MISSING and val == default:
+            continue
+        if type(val) in _PLAIN:
+            enc = val
+            if enc is None:
                 continue
+        else:
             enc = to_dict(val)
             if enc is None or enc == {} or enc == []:
                 continue
-            if enc == "" and (
-                f.default is dataclasses.MISSING or f.default == ""
-            ):
-                # an explicit empty string that differs from a non-empty
-                # default is meaningful (e.g. cluster-scoped namespace="")
-                continue
-            out[_camel(f.name)] = enc
-        return out
+        if enc == "" and drop_empty_str:
+            # an explicit empty string that differs from a non-empty
+            # default is meaningful (e.g. cluster-scoped namespace="")
+            continue
+        out[wire] = enc
+    return out
+
+
+def _to_dict_other(obj: Any) -> Any:
+    """to_dict of what is neither plain, an exact list / tuple / dict,
+    nor a dataclass: their subclasses, frozenset, bytes, anything else."""
     if isinstance(obj, (list, tuple)):
         return [to_dict(x) for x in obj]
     if isinstance(obj, frozenset):
@@ -148,55 +255,95 @@ def to_dict(obj: Any) -> Any:
     return obj
 
 
-def _resolve_optional(tp):
-    if get_origin(tp) is typing.Union:
-        args = [a for a in get_args(tp) if a is not type(None)]
-        if len(args) == 1:
-            return args[0]
-    return tp
+def _decode_float(data: Any) -> Any:
+    return float(data) if isinstance(data, int) else data
+
+
+def _decode_bytes(data: Any) -> Any:
+    return base64.b64decode(data) if isinstance(data, str) else data
+
+
+def _decoder(tp: Any) -> Optional[Callable[[Any], Any]]:
+    """What from_dict does with a value of type `tp`, decided once: a
+    function of the raw value, or None where the value passes through
+    (str, int, bool, Any, a scalar union such as Quantity, a bare
+    container). Every decoder maps None to None."""
+    tp = _resolve_optional(tp)
+    if isinstance(tp, str):  # unresolved forward ref — shouldn't happen
+
+        def unresolved(data):
+            if data is None:
+                return None
+            raise TypeError(f"unresolved type {tp}")
+
+        return unresolved
+    origin = get_origin(tp)
+    if origin in (list, tuple):
+        (item_tp, *_rest) = get_args(tp) or (Any,)
+        item = _decoder(item_tp)
+        as_tuple = origin is tuple
+
+        def sequence(data):
+            if data is None:
+                return None
+            seq = list(data) if item is None else [item(x) for x in data]
+            return tuple(seq) if as_tuple else seq
+
+        return sequence
+    if origin is dict:
+        _k, val_tp = get_args(tp) or (str, Any)
+        val = _decoder(val_tp)
+
+        def mapping(data):
+            if data is None:
+                return None
+            if val is None:
+                return dict(data.items())
+            return {k: val(x) for k, x in data.items()}
+
+        return mapping
+    if origin is typing.Union:
+        # scalar union (e.g. Quantity = str|int|float): pass through
+        return None
+    if dataclasses.is_dataclass(tp):
+        # the nested class's plan is looked up when a value arrives, not
+        # now: classes may refer to themselves and to each other
+
+        def nested(data):
+            return None if data is None else _decode_dataclass(tp, data)
+
+        return nested
+    if tp is float:
+        return _decode_float
+    if tp is bytes:
+        return _decode_bytes
+    return None
+
+
+def _decode_dataclass(cls: Type, data: Any) -> Any:
+    plan = _PLANS.get(cls) or _plan(cls)
+    if plan.error is not None:
+        raise plan.error
+    kwargs = {}
+    for wire, alias, name, dec in plan.decode:
+        if wire in data:
+            raw = data[wire]
+        elif alias is not None and alias in data:
+            raw = data[alias]
+        else:
+            continue
+        kwargs[name] = raw if dec is None else dec(raw)
+    return cls(**kwargs)
 
 
 def from_dict(cls: Type, data: Any) -> Any:
     """JSON dict → dataclass instance (inverse of to_dict)."""
     if data is None:
         return None
-    cls = _resolve_optional(cls)
-    if isinstance(cls, str):  # unresolved forward ref — shouldn't happen
-        raise TypeError(f"unresolved type {cls}")
-    origin = get_origin(cls)
-    if origin in (list, tuple):
-        (item_tp, *_rest) = get_args(cls) or (Any,)
-        seq = [from_dict(item_tp, x) for x in data]
-        return tuple(seq) if origin is tuple else seq
-    if origin is dict:
-        _k, val_tp = get_args(cls) or (str, Any)
-        return {k: from_dict(val_tp, val) for k, val in data.items()}
-    if origin is typing.Union:
-        resolved = _resolve_optional(cls)
-        if get_origin(resolved) is typing.Union:
-            # scalar union (e.g. Quantity = str|int|float): pass through
-            return data
-        return from_dict(resolved, data)
-    if dataclasses.is_dataclass(cls):
-        hints = get_type_hints(cls)
-        kwargs = {}
-        for f in dataclasses.fields(cls):
-            camel = _camel(f.name)
-            if camel in data:
-                raw = data[camel]
-            elif f.name in data:
-                raw = data[f.name]
-            else:
-                continue
-            kwargs[f.name] = from_dict(hints[f.name], raw)
-        return cls(**kwargs)
-    if cls in (Any, object):
-        return data
-    if cls is float and isinstance(data, int):
-        return float(data)
-    if cls is bytes and isinstance(data, str):
-        return base64.b64decode(data)
-    return data
+    if isinstance(cls, type) and cls in _PLANS:
+        return _decode_dataclass(cls, data)
+    dec = _decoder(cls)
+    return data if dec is None else dec(data)
 
 
 def decode(resource: str, data: dict, allow_unstructured: bool = True) -> Any:
