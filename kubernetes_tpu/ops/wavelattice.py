@@ -861,55 +861,3 @@ def make_wave_kernel_jit(
         ),
         donate_argnums=(0,),
     )
-
-
-@functools.lru_cache(maxsize=32)
-def make_wave_kernel_cb_jit(
-    v_cap: int,
-    m_cand: int = 128,
-    n_waves: int = 8,
-    hard_pod_affinity_weight: float = 1.0,
-    use_pallas_fit: bool = False,
-    score_refresh: bool = True,
-    rtc_shape: tuple = DEFAULT_RTC_SHAPE,
-    has_pinned: bool = True,
-    pallas_interpret: bool = False,
-):
-    """host_callback_binds variant of the wave kernel: identical compute,
-    plus a ``jax.experimental.io_callback`` that posts the fast index
-    payload (chosen/placed/deferred) to ops.hostcallback's ticket
-    registry the moment the kernel resolves ON DEVICE — the depth-
-    infinity micro-wave mode where the host never issues a device->host
-    sync for the bind-critical data. `ticket` is a traced int32 scalar so
-    distinct launches share one compiled variant. The full WaveResult is
-    still returned: the trailing bulk validation and the failure paths
-    (resolvable_tpl) read it the ordinary way."""
-    from jax.experimental import io_callback
-
-    from . import hostcallback
-
-    base = make_wave_kernel(
-        v_cap,
-        m_cand,
-        n_waves,
-        hard_pod_affinity_weight,
-        use_pallas_fit,
-        score_refresh,
-        rtc_shape,
-        has_pinned,
-        pallas_interpret,
-    )
-
-    def kernel_cb(snap, tb, pt, weights, rng, ticket):
-        new_snap, res = base(snap, tb, pt, weights, rng)
-        io_callback(
-            hostcallback.deliver,
-            None,
-            ticket,
-            res.chosen,
-            res.placed,
-            res.deferred,
-        )
-        return new_snap, res
-
-    return jax.jit(kernel_cb, donate_argnums=(0,))

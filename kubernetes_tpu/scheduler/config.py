@@ -78,22 +78,13 @@ class KubeSchedulerConfiguration:
     # 0 = auto = 2: on a local device or the CPU a deeper pipeline only
     # adds latency and host/device CPU contention.
     pipeline_depth: int = 0
-    # split-phase readback (round 17): the kernel's chosen/placed/deferred
-    # index payload (a few KB) streams back through an async device->host
-    # copy started AT DISPATCH, so the bind-critical resolve never joins
-    # with the bulk score/audit tensors — those trail in a second transfer
-    # the guards consume off the critical path (a late disagreement
-    # quarantines + unwinds through the suspect-row machinery). None =
-    # auto (on); False restores the round-16 combined readback.
-    split_phase_readback: Optional[bool] = None
-    # depth-infinity micro-waves (experimental): deliver the fast index
-    # payload through a jax.experimental.io_callback fired ON DEVICE the
-    # moment the kernel resolves, so the host observes wave N without
-    # issuing any device->host sync call at all. Off by default — the
-    # async-copy fast path already removes the readback join, and the
-    # callback variant is a separate jit cache entry per kernel shape.
-    host_callback_binds: bool = False
-    # bound on trailing bulk readbacks awaiting validation: past this the
+    # split-phase readback: the kernel's chosen/placed/deferred index
+    # payload (a few KB) streams back through an async device->host copy
+    # started AT DISPATCH, so the bind-critical resolve never joins with
+    # the bulk score tensor — that trails in a second transfer the guards
+    # consume off the critical path (a late disagreement quarantines +
+    # unwinds through the suspect-row machinery). This bounds the
+    # trailing bulk readbacks awaiting validation: past this the
     # oldest is force-drained (one blocking readback) rather than letting
     # unvalidated payloads — and their generation pins — pile up behind a
     # slow transfer
@@ -142,7 +133,6 @@ class KubeSchedulerConfiguration:
     # marginally faster still, but 16 keeps headroom for dense hard-pair
     # shapes the sweep didn't cover).
     wave_n_waves: int = 16
-    sync_batch_bind: bool = True  # bulk bind in-cycle when no permit/prebind
     # degraded-store ride-through (scheduler/ridethrough.py): placements
     # whose bind 503s retryably park here (pods stay assumed, HBM snapshot
     # stays warm) while the breaker pauses dispatch; beyond capacity the

@@ -46,7 +46,6 @@ from ..ops.encoding import ETERM_ANTI_REQ as _ETERM_ANTI_REQ
 from ..ops.preemptlattice import validate_preempt_outputs
 from ..ops.templates import TemplateCache, build_pair_table
 from ..ops.wavelattice import make_wave_kernel_jit
-from ..ops import hostcallback
 from ..ops.lattice import (
     GUARD_TRAILING_LOSS,
     KernelGuardTrip,
@@ -115,13 +114,11 @@ COUNTER_HOST_PATH_PODS = "scheduler_host_path_pods_total"
 # split-phase readback counters (round 17): fast = index-payload fetches
 # (the bind-critical resolve), blocking = fetches that actually had to
 # wait on the device (the readbacks_per_bind numerator), trailing = bulk
-# score fetches consumed off the critical path, hostcb = fast payloads
-# delivered by the kernel's own io_callback (no host-issued sync at all)
+# score fetches consumed off the critical path
 COUNTER_WAVE_FAST_READBACKS = "scheduler_wave_fast_readbacks_total"
 COUNTER_WAVE_BLOCKING_READBACKS = "scheduler_wave_readbacks_blocking_total"
 COUNTER_WAVE_TRAILING_READBACKS = "scheduler_wave_trailing_readbacks_total"
 COUNTER_WAVE_TRAILING_UNWOUND = "scheduler_wave_trailing_unwound_assumes_total"
-COUNTER_WAVE_HOSTCB = "scheduler_wave_hostcb_deliveries_total"
 GAUGE_WAVE_TRAILING_BACKLOG = "scheduler_wave_trailing_backlog"
 # pods a wave deferred (feasible nodes existed, in-batch contention ran
 # out of waves) and the most deferrals any pod still waiting has had: a
@@ -192,13 +189,12 @@ class _InFlightBatch:
     __slots__ = (
         "pis", "eb", "row_names", "res", "moves0", "t_start",
         "snapshot", "launch_gen", "wave_tid", "t_launched", "weights",
-        "rng_key", "ticket", "trailing",
+        "rng_key", "trailing",
     )
 
     def __init__(
         self, pis, eb, row_names, res, moves0, t_start, snapshot=None,
         launch_gen=0, wave_tid="", t_launched=0.0, weights=None, rng_key=None,
-        ticket=None,
     ):
         self.pis = pis
         self.eb = eb
@@ -228,12 +224,8 @@ class _InFlightBatch:
         # live policy is by then
         self.weights = weights
         self.rng_key = rng_key
-        # host_callback_binds: the delivery-registry ticket the kernel's
-        # io_callback posts this batch's fast index payload under
-        self.ticket = ticket
-        # split-phase readback: the _TrailingReadback registered at fast
-        # commit (None when nothing was placed, or in combined mode) —
-        # whoever consumes it finishes the wave trace
+        # the _TrailingReadback registered at fast commit (None when
+        # nothing was placed) — whoever consumes it finishes the wave trace
         self.trailing = None
 
 
@@ -447,18 +439,11 @@ class Scheduler:
         self._deferred_counts: Dict[str, list] = {}
         self._wave_inflight_peak = 0  # high-water mark of len(_pending)
         self._wave_batch_pods_peak = 0  # most pods in one wave launch
-        # split-phase readback (round 17): resolve on the fast index
-        # payload alone (async-copied at dispatch), validate the trailing
-        # bulk score off the critical path. auto = on; False restores the
-        # combined readback (the A/B baseline arm).
-        self._split_phase = (
-            self.cfg.split_phase_readback
-            if self.cfg.split_phase_readback is not None
-            else True
-        )
-        # trailing bulk readbacks registered at fast commit, oldest
-        # first; drained non-blocking before each launch and in the
-        # loop's idle beat (scheduling-loop thread only)
+        # split-phase readback: a batch resolves on the fast index payload
+        # alone (async-copied at dispatch); its bulk score is validated
+        # off the critical path. The trailing bulk readbacks registered
+        # at fast commit, oldest first; drained non-blocking before each
+        # launch and in the loop's idle beat (scheduling-loop thread only)
         self._trailing: List[_TrailingReadback] = []
         # 0 (auto) is depth 2: one batch computing on the device while the
         # host reads back and binds the one before it
@@ -698,14 +683,6 @@ class Scheduler:
             pallas_fit = "untiled"  # every trace takes the jnp broadcast
         else:
             pallas_fit = "on"
-        # the kernel can only post its own results from an unsharded
-        # program: under a mesh the option is off whatever the config says
-        if not self.cfg.host_callback_binds:
-            host_cb = "off"
-        elif self._mesh is not None:
-            host_cb = "off (mesh)"
-        else:
-            host_cb = "on"
         platform, kind = devs[0].platform, devs[0].device_kind
         interpret = str(self._pallas_interpret).lower()
         metrics.set_gauge(
@@ -724,11 +701,10 @@ class Scheduler:
         logger.info(
             "device path: platform=%s device_kind=%r devices=%d mesh=%d "
             "batch_bucket=%d pallas_fit=%s pallas_interpret=%s n_cap=%d "
-            "m_cand=%d score_refresh=%s pipeline_depth=%d split_phase=%s "
-            "host_callback_binds=%s",
+            "m_cand=%d score_refresh=%s pipeline_depth=%d",
             platform, kind, len(devs), n_mesh, self._batch_size, pallas_fit,
             interpret, enc_cfg.n_cap, self._m_cand, self._score_refresh,
-            self._pipeline_depth, self._split_phase, host_cb,
+            self._pipeline_depth,
         )
         with self.cache.encoder.pin_generation() as lease:
             if lease.snap is None:
@@ -1642,7 +1618,7 @@ class Scheduler:
             c_k0 = time.thread_time()
             try:
                 try:
-                    res, chosen, score = self._run_serial_kernel(
+                    res, chosen = self._run_serial_kernel(
                         kern, snap, eb.batch, sub, w_launch
                     )
                 finally:
@@ -1694,9 +1670,10 @@ class Scheduler:
             # legitimate unplaced sentinel, so any other negative index
             # is corruption that must trip GUARD_ROW_RANGE — a `>= 0`
             # mask would silently route a sign-flipped row (and its
-            # poisoned score) into the unschedulable/preemption path
+            # poisoned score) into the unschedulable/preemption path.
+            # The score is not here yet: the trailing validation reads it
             reason = validate_batch_outputs(
-                chosen, np.asarray(chosen) != -1, score, len(row_names)
+                chosen, np.asarray(chosen) != -1, None, len(row_names)
             )
             if reason:
                 # serial path (no pipeline): quarantine this batch to the
@@ -1748,13 +1725,13 @@ class Scheduler:
                 failed.append((pi, -1))
                 continue
             serial_to_bind.append((pi, node_name))
-        # split-phase serial: the fast chosen-index payload was acted on
-        # with score=None; register the trailing bulk validation before
-        # any bind leaves the process, and take one last non-blocking
-        # look — on CPU the score has usually landed by now, so the
-        # common case still validates before the first bind
+        # the fast chosen-index payload was decoded without the score:
+        # register the trailing bulk validation before any bind leaves
+        # the process, and take one last non-blocking look — on CPU the
+        # score has usually landed by now, so the common case still
+        # validates before the first bind
         entry = None
-        if self._split_phase and score is None and serial_to_bind:
+        if serial_to_bind:
             entry = self._register_trailing(
                 res.score,
                 np.asarray(chosen) != -1,
@@ -1990,86 +1967,47 @@ class Scheduler:
             # to static analysis at this call — the marker makes it the
             # checked donation site (graftlint donation pass)
             new_snap, res = kern(dl.snap, batch, ptab, weights, key)  # graftlint: donating-call
-            if self._split_phase:
-                # split-phase: start BOTH device->host copies at dispatch.
-                # The few-KB index payload (chosen/placed/deferred) lands
-                # the moment the kernel resolves — the fast resolve below
-                # never joins with it over a fresh RTT — and the bulk
-                # score streams behind it for the trailing validation.
-                # Inside the donation lease on purpose (graftlint fastpath
-                # rule): the early transfer is tied to the generation
-                # lifecycle it reads from, and the trailing entry keeps a
-                # pin until its half lands.
-                try:
-                    res.chosen.copy_to_host_async()
-                    res.placed.copy_to_host_async()
-                    res.deferred.copy_to_host_async()
-                    res.score.copy_to_host_async()
-                except Exception:
-                    # sharded outputs on exotic meshes may not support the
-                    # async copy; the fetch below degrades to a plain
-                    # (blocking) device_get — correctness unchanged
-                    logger.debug(
-                        "async fast-path copy unavailable", exc_info=True
-                    )
+            # start BOTH device->host copies at dispatch. The few-KB index
+            # payload (chosen/placed/deferred) lands the moment the kernel
+            # resolves — the fast resolve below never joins with it over
+            # a fresh RTT — and the bulk score streams behind it for the
+            # trailing validation. Inside the donation lease on purpose
+            # (graftlint fastpath rule): the early transfer is tied to
+            # the generation lifecycle it reads from, and the trailing
+            # entry keeps a pin until its half lands.
+            try:
+                res.chosen.copy_to_host_async()
+                res.placed.copy_to_host_async()
+                res.deferred.copy_to_host_async()
+                res.score.copy_to_host_async()
+            except Exception:
+                # sharded outputs on exotic meshes may not support the
+                # async copy; the fetch below degrades to a plain
+                # (blocking) device_get — correctness unchanged
+                logger.debug(
+                    "async fast-path copy unavailable", exc_info=True
+                )
             dl.result = new_snap
         return new_snap, res
 
-    def _fetch_wave_results(self, batches: List["_InFlightBatch"]):
-        """Seam for the fault injector: the combined device->host readback
-        for k in-flight batches (the non-split-phase path)."""
-        metrics.inc(COUNTER_WAVE_BLOCKING_READBACKS)
-        metrics.inc("scheduler_wave_readbacks_total")
-        return jax.device_get(
-            [
-                (b.res.chosen, b.res.placed, b.res.deferred, b.res.score)
-                for b in batches
-            ]
-        )
-
     def _fetch_wave_index(self, batches: List["_InFlightBatch"]):
         """Seam for the fault injector: the split-phase FAST readback —
-        just the index payload (chosen, placed, deferred) per batch. The
-        async copy started at dispatch means this usually consumes an
-        already-landed transfer; a host-callback ticket beats even that
-        (the kernel pushed the payload itself). Blocking fetches (payload
-        not materialized yet — the resolve overtook the kernel) count
-        separately: they are the readbacks_per_bind numerator."""
+        just the index payload (chosen, placed, deferred) per batch, k
+        batches in one device->host fetch. The async copy started at
+        dispatch means this usually consumes an already-landed transfer.
+        Blocking fetches (payload not materialized yet — the resolve
+        overtook the kernel) count separately: they are the
+        readbacks_per_bind numerator."""
         metrics.inc(COUNTER_WAVE_FAST_READBACKS)
-        out: List = []
-        for b in batches:
-            payload = None
-            if b.ticket is not None:
-                payload = hostcallback.take(b.ticket, timeout=2.0)
-                if payload is not None:
-                    metrics.inc(COUNTER_WAVE_HOSTCB)
-            out.append(payload)
-        missing = [i for i, p in enumerate(out) if p is None]
-        if missing:
-            if not all(
-                _device_ready(batches[i].res.chosen)
-                and _device_ready(batches[i].res.placed)
-                and _device_ready(batches[i].res.deferred)
-                for i in missing
-            ):
-                # the resolve overtook the transfer: this fetch is a real
-                # host-blocking device sync — the only kind the legacy
-                # readbacks_total series (and readbacks_per_bind) counts
-                metrics.inc(COUNTER_WAVE_BLOCKING_READBACKS)
-                metrics.inc("scheduler_wave_readbacks_total")
-            got = jax.device_get(
-                [
-                    (
-                        batches[i].res.chosen,
-                        batches[i].res.placed,
-                        batches[i].res.deferred,
-                    )
-                    for i in missing
-                ]
-            )
-            for i, p in zip(missing, got):
-                out[i] = p
-        return out
+        if not all(self._fast_payload_ready(b) for b in batches):
+            # the resolve overtook the transfer: this fetch is a real
+            # host-blocking device sync — the only kind the
+            # readbacks_total series (and readbacks_per_bind) counts
+            metrics.inc(COUNTER_WAVE_BLOCKING_READBACKS)
+            metrics.inc("scheduler_wave_readbacks_total")
+        return jax.device_get(
+            [(b.res.chosen, b.res.placed, b.res.deferred) for b in batches]
+        )
 
     def _fetch_wave_bulk(self, entries: List["_TrailingReadback"]):
         """Seam for the fault injector: the split-phase TRAILING readback
@@ -2359,20 +2297,6 @@ class Scheduler:
             enc_cfg, m_cand, n_waves, batch_has_hard, has_pinned
         )
         kern = self._wave_kernel(variant)
-        ticket = None
-        if self.cfg.host_callback_binds and self._mesh is None:
-            # depth-infinity micro-waves: the kernel posts its own fast
-            # index payload through io_callback under this ticket — the
-            # resolve consumes the delivery instead of issuing any sync
-            from ..ops.wavelattice import make_wave_kernel_cb_jit
-
-            cb_kern = make_wave_kernel_cb_jit(*variant)
-            ticket = hostcallback.new_ticket()
-            t_arr = np.int32(ticket)
-
-            def kern(s, b, p, w, k, _cb=cb_kern, _t=t_arr):
-                return _cb(s, b, p, w, k, _t)
-
         self._rng_key, sub = jax.random.split(self._rng_key)
         w_launch = np.asarray(self._weights)
         try:
@@ -2381,8 +2305,6 @@ class Scheduler:
             )
         except Exception:
             ph.switch("other")
-            if ticket is not None:
-                hostcallback.discard(ticket)
             with self.cache.lock:
                 self.cache.encoder.invalidate_device()
             raise
@@ -2406,7 +2328,7 @@ class Scheduler:
         self._pending.append(
             _InFlightBatch(
                 pis, eb, row_names, res, moves0, t_start, verify_snap,
-                launch_gen, wave_tid, t_launched, w_launch, sub, ticket,
+                launch_gen, wave_tid, t_launched, w_launch, sub,
             )
         )
         metrics.inc("scheduler_wave_batches_total")
@@ -2425,14 +2347,13 @@ class Scheduler:
             # the readback + the host-side bind work below
             keep = 0 if self._pipeline_depth == 1 else 1
             self._resolve_oldest(len(self._pending) - keep)
-        elif self._split_phase and len(self._pending) > 1:
+        elif len(self._pending) > 1:
             # continuous micro-waves: any older wave whose fast index
-            # payload ALREADY landed (async copy started at dispatch, or
-            # the kernel's own io_callback) commits now instead of
-            # waiting for the pipeline to fill — its pods stop paying the
-            # pipeline-fill wait, and the device keeps computing the
-            # newest wave while the host binds. Never the newest: its
-            # device time is what overlaps this host work.
+            # payload ALREADY landed (async copy started at dispatch)
+            # commits now instead of waiting for the pipeline to fill —
+            # its pods stop paying the pipeline-fill wait, and the device
+            # keeps computing the newest wave while the host binds. Never
+            # the newest: its device time is what overlaps this host work.
             n_ready = 0
             for b in self._pending[:-1]:
                 if not self._fast_payload_ready(b):
@@ -2445,8 +2366,6 @@ class Scheduler:
         ph.switch("other")
 
     def _fast_payload_ready(self, b: "_InFlightBatch") -> bool:
-        if b.ticket is not None and hostcallback.ready(b.ticket):
-            return True
         return (
             _device_ready(b.res.chosen)
             and _device_ready(b.res.placed)
@@ -2475,7 +2394,6 @@ class Scheduler:
     def _resolve_batches(self, k: int) -> None:
         batches, self._pending = self._pending[:k], self._pending[k:]
         metrics.set_gauge(GAUGE_WAVE_INFLIGHT, float(len(self._pending)))
-        split = self._split_phase
         ph = self._phase
         t_rb0 = ph.switch("readback")
         c_rb0 = time.thread_time()
@@ -2483,16 +2401,11 @@ class Scheduler:
             # transient device blips get bounded jittered
             # retries (the fetched refs are re-gettable — no donation
             # on the read side) before the loss path takes over.
-            # Split mode fetches ONLY the index payload here; the bulk
-            # score trails through _fetch_wave_bulk off this path.
-            fetch = (
-                self._fetch_wave_index
-                if split
-                else self._fetch_wave_results
-            )
+            # ONLY the index payload is fetched here; the bulk score
+            # trails through _fetch_wave_bulk off this path.
             try:
                 fetched = call_with_device_retry(
-                    lambda: fetch(batches),
+                    lambda: self._fetch_wave_index(batches),
                     attempts=self.cfg.device_retry_attempts,
                     on_retry=lambda n, e: metrics.inc(
                         "scheduler_device_retries_total",
@@ -2511,8 +2424,6 @@ class Scheduler:
             self._consecutive_device_loss = 0
         except Exception as e:
             for b in batches:
-                if b.ticket is not None:
-                    hostcallback.discard(b.ticket)
                 tracer.finish(b.wave_tid, outcome="readback_failed")
                 for pi in b.pis:
                     tracer.event(pi.trace_id, "readback.failed")
@@ -2578,19 +2489,14 @@ class Scheduler:
                     tracer.event(pi.trace_id, "wave.quarantined")
                     self.queue.readd(pi)
                 continue
-            if split:
-                # fast payload only: score arrives with the trailing bulk
-                # readback — validation/decode below run with score=None
-                arrays = (*arrays, None)
             try:
                 tails.append(self._commit_batch(b, arrays, t_rb1, t_guard0))
                 if b.trailing is None:
-                    # combined mode — or a split batch that placed
-                    # nothing: the guard story is complete right here.
-                    # With a trailing entry registered, the trip counter
-                    # resets only when the TRAILING validation passes
-                    # (else a poisoned device alternating commit/unwind
-                    # would never latch off).
+                    # the batch placed nothing: the guard story is
+                    # complete right here. With a trailing entry
+                    # registered, the trip counter resets only when the
+                    # TRAILING validation passes (else a poisoned device
+                    # alternating commit/unwind would never latch off).
                     self._consecutive_guard_trips = 0
                     tracer.finish(b.wave_tid, outcome="committed")
             except KernelGuardTrip as trip:
@@ -2668,7 +2574,9 @@ class Scheduler:
         (t_rb1 for the eldest batch, the elder's commit end for a
         sibling): stage="guard" and the wave's `guard` span run from it."""
         pis, eb, row_names = p.pis, p.eb, p.row_names
-        chosen, placed, deferred, score = arrays
+        # the fast index payload; the score arrives with the trailing
+        # bulk readback and is validated there
+        chosen, placed, deferred = arrays
         t_start = p.t_start
         algo_dur = (t_guard0 or time.monotonic()) - t_start
         metrics.observe("scheduling_algorithm_duration_seconds", algo_dur)
@@ -2678,7 +2586,7 @@ class Scheduler:
             # would either crash the commit or (negative wrap) silently
             # pick the WRONG node
             reason = validate_batch_outputs(
-                chosen, placed, score, len(row_names)
+                chosen, placed, None, len(row_names)
             )
             if reason:
                 raise KernelGuardTrip(reason)
@@ -2742,11 +2650,9 @@ class Scheduler:
                 self.queue.requeue_backoff(pi)
         self._note_deferrals(p, deferred_pis)
         # the guard stage ends here (one read): the hand-off to assume.
-        # Registering the trailing half (split-phase) is `trailing` work;
+        # Registering the trailing half is `trailing` work;
         # _assume_and_bind_bulk switches to `assume`.
-        t_g1 = self._phase.switch(
-            "trailing" if self._split_phase else "other"
-        )
+        t_g1 = self._phase.switch("trailing")
         if t_guard0 is not None:
             _observe_stage("guard", t_guard0, t_g1)
             tracer.add_span(p.wave_tid, "guard", t_guard0, t_g1)
@@ -2760,10 +2666,8 @@ class Scheduler:
             )
 
         entry = None
-        if self._split_phase and (
-            to_bind or bool(np.asarray(placed, dtype=bool).any())
-        ):
-            # split-phase trailing half: the bulk score payload validates
+        if to_bind or bool(np.asarray(placed, dtype=bool).any()):
+            # the trailing half: the bulk score payload validates
             # off the critical path. Registered BEFORE assume so the
             # pre-bind gate below can catch an own-batch disagreement
             # while the assumes are still revertible.
@@ -3165,8 +3069,6 @@ class Scheduler:
             metrics.inc(
                 "kernel_guard_trips_total", {"reason": "sibling_quarantine"}
             )
-            if b.ticket is not None:
-                hostcallback.discard(b.ticket)
             tracer.finish(b.wave_tid, outcome="sibling_quarantine")
             for pi in b.pis:
                 tracer.event(pi.trace_id, "wave.quarantined")
@@ -3277,32 +3179,27 @@ class Scheduler:
     def _run_serial_kernel(self, kern, snap, batch, key, weights=None):
         """Launch + readback of the serial batch kernel — one synchronous
         call, split out as an injectable seam for the chaos fault
-        injector (mirrors _launch_wave_kernel/_fetch_wave_results).
+        injector (mirrors _launch_wave_kernel/_fetch_wave_index).
         ``weights`` pins the exact launch vector (the tuner records it
         for differential replay); None reads the live policy.
 
-        Split-phase mode: only the small chosen-index vector is fetched
-        on the critical path (its device→host copy was started at
-        dispatch); the bulk score tensor streams back behind it and is
-        validated by the trailing machinery — the caller sees score=None
-        and registers a _TrailingReadback."""
+        Only the small chosen-index vector is fetched on the critical
+        path (its device→host copy was started at dispatch); the bulk
+        score tensor streams back behind it and is validated by the
+        trailing machinery — the caller registers a _TrailingReadback
+        on res.score."""
         if weights is None:
             weights = np.asarray(self._weights)
         res = kern(snap, batch, weights, key)
-        if self._split_phase:
-            with self.cache.encoder.pin_generation():
-                try:
-                    res.chosen.copy_to_host_async()
-                    res.score.copy_to_host_async()
-                except Exception:
-                    logger.debug(
-                        "async readback start failed", exc_info=True
-                    )
-                metrics.inc(COUNTER_WAVE_BLOCKING_READBACKS)
-                chosen = np.asarray(jax.device_get(res.chosen))
-            return res, chosen, None
-        chosen, score = jax.device_get((res.chosen, res.score))
-        return res, chosen, score
+        with self.cache.encoder.pin_generation():
+            try:
+                res.chosen.copy_to_host_async()
+                res.score.copy_to_host_async()
+            except Exception:
+                logger.debug("async readback start failed", exc_info=True)
+            metrics.inc(COUNTER_WAVE_BLOCKING_READBACKS)
+            chosen = np.asarray(jax.device_get(res.chosen))
+        return res, chosen
 
     @staticmethod
     def _device_probe(device) -> bool:
@@ -3561,8 +3458,7 @@ class Scheduler:
             prof = self.profiles.for_pod(pod)
             ps = prof.framework.plugin_set
             plain = (
-                self.cfg.sync_batch_bind
-                and not ps.reserve
+                not ps.reserve
                 and not ps.permit
                 and not ps.pre_bind
                 and not ps.post_bind

@@ -78,17 +78,16 @@ def corrupt_device_rows(
 
 class DeviceFaultInjector:
     """Wraps one Scheduler's device seams (_launch_wave_kernel /
-    _fetch_wave_results / _fetch_wave_index / _fetch_wave_bulk /
-    _run_serial_kernel). Ordinals count calls made AFTER install().
+    _fetch_wave_index / _fetch_wave_bulk / _run_serial_kernel). Ordinals
+    count calls made AFTER install().
 
-    Split-phase mapping: the fast index fetch shares the readback
-    ordinal space with the legacy combined fetch — `fail_readbacks` and
+    The readback ordinals count fast index fetches: `fail_readbacks` and
     `wild_rows_on_readbacks` land there (the chosen-row payload rides
     the fast path). The score tensor only exists on the TRAILING bulk
-    fetch in split mode, so `nan_scores_on_readbacks` ordinals index
-    bulk calls there, and `fail_trailing_readbacks` kills the trailing
-    fetch itself — the exact late-disagreement the unwind machinery
-    must catch after the fast payload already drove assumes."""
+    fetch, so `nan_scores_on_readbacks` ordinals index bulk calls, and
+    `fail_trailing_readbacks` kills the trailing fetch itself — the
+    exact late-disagreement the unwind machinery must catch after the
+    fast payload already drove assumes."""
 
     def __init__(
         self,
@@ -120,12 +119,10 @@ class DeviceFaultInjector:
     def install(self, sched) -> "DeviceFaultInjector":
         self._sched = sched
         self._real_launch = sched._launch_wave_kernel
-        self._real_fetch = sched._fetch_wave_results
         self._real_fetch_index = sched._fetch_wave_index
         self._real_fetch_bulk = sched._fetch_wave_bulk
         self._real_serial = sched._run_serial_kernel
         sched._launch_wave_kernel = self._launch
-        sched._fetch_wave_results = self._fetch
         sched._fetch_wave_index = self._fetch_index
         sched._fetch_wave_bulk = self._fetch_bulk
         sched._run_serial_kernel = self._serial
@@ -134,7 +131,6 @@ class DeviceFaultInjector:
     def uninstall(self) -> None:
         if self._sched is not None:
             self._sched._launch_wave_kernel = self._real_launch
-            self._sched._fetch_wave_results = self._real_fetch
             self._sched._fetch_wave_index = self._real_fetch_index
             self._sched._fetch_wave_bulk = self._real_fetch_bulk
             self._sched._run_serial_kernel = self._real_serial
@@ -168,38 +164,9 @@ class DeviceFaultInjector:
             )
         return self._real_serial(kern, snap, batch, key, weights)
 
-    def _fetch(self, batches):
-        with self._lock:
-            n = self.readback_calls
-            self.readback_calls += 1
-            boom = n in self.fail_readbacks
-            nan = n in self.nan_scores_on_readbacks
-            wild = n in self.wild_rows_on_readbacks
-        if boom:
-            self.injected.append(("readback_loss", n))
-            raise DeviceLossError(
-                f"injected: device lost on readback #{n}"
-            )
-        fetched = self._real_fetch(batches)
-        out = []
-        for chosen, placed, deferred, score in fetched:
-            chosen = np.array(chosen)
-            placed = np.array(placed)
-            score = np.array(score)
-            if nan and placed.any():
-                score = score.copy()
-                score[np.nonzero(placed)[0][0]] = np.nan
-                self.injected.append(("nan_score", n))
-            if wild and placed.any():
-                chosen = chosen.copy()
-                chosen[np.nonzero(placed)[0][0]] = 2**30
-                self.injected.append(("wild_row", n))
-            out.append((chosen, placed, deferred, score))
-        return out
-
     def _fetch_index(self, batches):
-        """Split-phase FAST seam: index payload only. Shares the
-        readback ordinal space with the legacy combined fetch."""
+        """Split-phase FAST seam: index payload only; the one readback
+        ordinal space."""
         with self._lock:
             n = self.readback_calls
             self.readback_calls += 1
