@@ -400,6 +400,13 @@ class PairTable(NamedTuple):
     anti-affinity terms matched by batch pods (column = eterm id). Domain sums
     are computed ONCE per pair per batch instead of once per pod — the key
     restructuring that removes the per-pod segment-sum cost.
+
+    The pair axis J is the smallest rung of PAIR_SLOT_LADDER's rule (1, 4,
+    16, 64, ...) that holds the pairs the templates reference: Stage A's
+    segment scatters and gathers cost a TPU one serial update per [J, N]
+    element, used or not. The slots past the last pair are dead (col -1, no
+    template's *_pair points at them, contrib 0, etm_match False); a
+    template set that crosses a rung compiles the kernel once a bucket.
     """
 
     is_eterm: jnp.ndarray  # [J] bool (column indexes eterm_w vs sel_counts)
@@ -423,10 +430,23 @@ class PairTable(NamedTuple):
     #                         eterm predicate (filter/scoring vs existing pods)
 
 
+PAIR_SLOT_LADDER = 4  # each rung of the pair axis is this many times the last
+
+
+def pair_slots(n_pairs: int) -> int:
+    """The pair axis for `n_pairs` pairs: 1, 4, 16, 64, ... At least one
+    slot, because the kernel clips pair indices to J - 1 and its segment
+    operations need a non-empty axis."""
+    j = 1
+    while j < n_pairs:
+        j *= PAIR_SLOT_LADDER
+    return j
+
+
 def build_pair_table(
-    enc: SnapshotEncoder, tpl_batch: PodBatch, num_templates: int, j_cap: int = 32
-) -> Tuple[PairTable, bool]:
-    """Host-side pair dedup over a template batch. Returns (table, overflow).
+    enc: SnapshotEncoder, tpl_batch: PodBatch, num_templates: int
+) -> PairTable:
+    """Host-side pair dedup over a template batch.
 
     `tpl_batch` must be the host (numpy) mirror — passing device arrays here
     would pay a device round trip per field."""
@@ -450,9 +470,7 @@ def build_pair_table(
     aff_pair = np.full((TPL, A), -1, np.int32)
     anti_pair = np.full((TPL, B), -1, np.int32)
     pref_pair = np.full((TPL, PW), -1, np.int32)
-    overflow = False
 
-    eterm_pairs: List[Tuple[int, int]] = []  # (tid, j)
     for t in range(num_templates):
         for c in range(C):
             sid, key = int(b.spread_sid[t, c]), int(b.spread_key[t, c])
@@ -473,15 +491,9 @@ def build_pair_table(
         for tid in range(len(enc.eterm_vocab)):
             if b.match_eterm[t, tid]:
                 et = enc.eterm_vocab.items[tid]
-                j = intern(True, tid, et.topo_key_id, -1, et.kind)
-                eterm_pairs.append((tid, j))
+                intern(True, tid, et.topo_key_id, -1, et.kind)
 
-    J = len(pairs)
-    if J > j_cap:
-        overflow = True
-        j_cap = 1
-        while j_cap < J:
-            j_cap *= 2
+    j_cap = pair_slots(len(pairs))
     is_eterm = np.zeros(j_cap, np.bool_)
     col = np.full(j_cap, -1, np.int32)
     key_arr = np.zeros(j_cap, np.int32)
@@ -526,4 +538,4 @@ def build_pair_table(
         pref_w=jnp.asarray(b.ppref_w),
         etm_match=jnp.asarray(etm_match),
     )
-    return table, overflow
+    return table

@@ -1,15 +1,18 @@
 """Wave-commit lattice: vectorized bulk pass + bounded conflict-resolution.
 
 The first-cut kernel (ops/lattice.py) reproduced scheduleOne's serial
-semantics as a P-step lax.scan — measured at ~3.5 ms/pod on hardware because
-every step re-ran topology segment-sums and rewrote a multi-MB carry. This
-kernel restructures the batch cycle so nothing scales with P serially:
+semantics as a P-step lax.scan, in which every step re-ran topology
+segment-sums and rewrote a multi-MB carry. This kernel restructures the
+batch cycle so nothing scales with P serially:
 
   Stage A (fully vectorized, template granularity):
     * filter masks, score matrix, normalization per TEMPLATE [TPL, N] — a
       burst of Deployment pods is one template, not P pods;
     * topology-domain sums ONCE per (predicate, topology-key) pair [J, V]
-      (the PairTable), not once per pod;
+      (the PairTable), not once per pod; J is the table's own size (1, 4,
+      16, ... slots for the pairs the templates reference): the segment
+      scatters and gathers below cost a TPU one serial update per [J, N]
+      element, dead slot or not;
     * per-template top-M candidate nodes; per-pod candidate order =
       score-descending with per-pod random tie-noise (selectHost's uniform
       tie-break, generic_scheduler.go:235).
@@ -254,19 +257,11 @@ def make_wave_kernel(
             )
             node_cnt = jnp.where(dom >= 0, sums[jnp.clip(dom, 0, v_cap - 1)], 0.0)
             min_dom = jnp.min(jnp.where(present, sums, jnp.inf))
-            return node_cnt, min_dom, jnp.sum(sums), sums
+            return node_cnt, min_dom, jnp.sum(sums), sums, present
 
-        cnt0, min0, tot0, base_dom = jax.vmap(dom_sums)(
+        cnt0, min0, tot0, base_dom, present_dom = jax.vmap(dom_sums)(
             w_j, dom_j, elig_j, jnp.zeros((J, v_cap))
-        )  # cnt0 [J, N]; base_dom [J, V]
-        present_dom = jax.vmap(
-            lambda j: jax.ops.segment_max(
-                elig_j[j].astype(jnp.int32),
-                jnp.where(elig_j[j], dom_j[j], v_cap),
-                num_segments=v_cap,
-            )
-            > 0
-        )(jnp.arange(J))  # [J, V] — wave-invariant
+        )  # cnt0 [J, N]; base_dom, present_dom [J, V] — wave-invariant
 
         def tpl_pair_verdicts(t, cnt, min_d, tot, dom):
             """Carry-dependent filter verdicts for template t given pair
@@ -782,7 +777,7 @@ def make_wave_kernel(
         # Static trip count: the host picks n_waves per batch shape
         # (scheduler._batch_waves), so one compiled variant serves every
         # batch of that shape and its cost does not depend on the data. An
-        # early exit once nothing is left to place is ROADMAP A2 and
+        # early exit once nothing is left to place is ROADMAP A5 and
         # needs a measurement on the chip first.
         placed, chosen, req_d, port_d, dom_d, _nz2_d = jax.lax.fori_loop(
             0, n_waves, wave, state0

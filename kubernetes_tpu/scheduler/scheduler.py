@@ -1812,9 +1812,16 @@ class Scheduler:
         )
         if self._pair_cache is not None and self._pair_cache[0] == sig:
             return self._pair_cache[1]
-        table, overflow = build_pair_table(enc, eb.tpl_np, eb.num_templates)
-        if overflow:
-            logger.warning("pair table overflow; kernel capacity grew")
+        table = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+        slots = table.col.shape[0]
+        if self._pair_cache is not None:
+            before = self._pair_cache[1].col.shape[0]
+            if slots > before:
+                logger.warning(
+                    "pair axis grew from %d to %d slots: the wave kernel "
+                    "compiles once more a bucket",
+                    before, slots,
+                )
         self._pair_cache = (sig, table)
         return table
 
@@ -2332,6 +2339,9 @@ class Scheduler:
             )
         )
         metrics.inc("scheduler_wave_batches_total")
+        metrics.inc(
+            "scheduler_wave_pair_slots_total", by=float(ptab.col.shape[0])
+        )
         if len(pis) > self._wave_batch_pods_peak:
             self._wave_batch_pods_peak = len(pis)
             metrics.set_gauge(GAUGE_WAVE_BATCH_PODS_MAX, float(len(pis)))

@@ -231,8 +231,8 @@ def test_fuzz_device_mask_matches_host_filters(seed):
     while P < len(pods):
         P *= 2
     eb = tc.encode(pods, pad_to=P)
-    # overflow just grows the table's J capacity (scheduler logs + proceeds)
-    ptab, _overflow = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+    # the table's pair axis follows the pairs (ops/templates.pair_slots)
+    ptab = build_pair_table(enc, eb.tpl_np, eb.num_templates)
     snap = enc.flush()
     kern = make_wave_kernel_jit(enc.cfg.v_cap, 64, 8)
     new_snap, res = kern(
@@ -460,7 +460,7 @@ def test_fuzz_poisoned_readback_corpus_caught_by_guards(seed):
     while P < len(pods):
         P *= 2
     eb = tc.encode(pods, pad_to=P)
-    ptab, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+    ptab = build_pair_table(enc, eb.tpl_np, eb.num_templates)
     snap = enc.flush()
     kern = make_wave_kernel_jit(enc.cfg.v_cap, 64, 8)
     _new_snap, res = kern(
@@ -559,7 +559,7 @@ def test_fuzz_selector_spread_device_picks_min_service_count(seed):
     while P < len(pods):
         P *= 2
     eb = tc.encode(pods, pad_to=P)
-    ptab, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+    ptab = build_pair_table(enc, eb.tpl_np, eb.num_templates)
     snap = enc.flush()
     weights = np.zeros(NUM_SCORE_COMPONENTS, np.float32)
     weights[SC_SELECTOR_SPREAD] = 1.0
@@ -651,7 +651,7 @@ def test_fuzz_rtc_nondefault_shape_matches_host_plugin(seed):
     )
     tc = TemplateCache(enc)
     eb = tc.encode([pod], pad_to=1)
-    ptab, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+    ptab = build_pair_table(enc, eb.tpl_np, eb.num_templates)
     snap = enc.flush()
     weights = np.zeros(NUM_SCORE_COMPONENTS, np.float32)
     weights[SC_REQ_TO_CAP] = 1.0

@@ -60,7 +60,7 @@ _WORKER = textwrap.dedent(
     tc = TemplateCache(enc)
     pods = [make_pod(f"p{{i}}", cpu="500m") for i in range(8)]
     eb = tc.encode(pods, pad_to=8)
-    ptab, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+    ptab = build_pair_table(enc, eb.tpl_np, eb.num_templates)
 
     mesh = make_mesh()  # 8 global devices across the 2 processes
     enc.set_sharding(snapshot_shardings(mesh), replicated(mesh))

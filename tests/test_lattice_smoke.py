@@ -360,7 +360,7 @@ def test_wave_score_refresh_sees_in_batch_commits():
             make_pod("small", cpu="1"),
         ]
         eb = tc.encode(pods, pad_to=4)
-        ptab, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+        ptab = build_pair_table(enc, eb.tpl_np, eb.num_templates)
         snap = enc.flush()
         return enc, eb, ptab, snap
 

@@ -104,7 +104,7 @@ def test_wave_kernel_pallas_fit_parity():
         cache = TemplateCache(enc)
         pods = [make_pod(f"p{i}", cpu="500m") for i in range(10)]
         eb = cache.encode(pods, pad_to=16)
-        ptab, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+        ptab = build_pair_table(enc, eb.tpl_np, eb.num_templates)
         snap = enc.flush()
         kern = make_wave_kernel_jit(
             enc.cfg.v_cap, 64, 4, use_pallas_fit=use_pallas,
@@ -164,7 +164,7 @@ def test_sharded_wave_kernel_with_pallas_fit(caplog):
             for i in range(24)
         ]
         eb = cache.encode(pods, pad_to=32)
-        pt, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+        pt = build_pair_table(enc, eb.tpl_np, eb.num_templates)
         if sharded:
             enc.set_sharding(snapshot_shardings(mesh), replicated(mesh))
             kern = make_sharded_wave_kernel(

@@ -119,17 +119,27 @@ def _wave_inputs(enc, pods):
 
     tc = TemplateCache(enc)
     eb = tc.encode(pods, pad_to=4)
-    ptab, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+    ptab = build_pair_table(enc, eb.tpl_np, eb.num_templates)
     return eb, ptab
 
 
-def test_sharded_wave_matches_single_device(cluster):
+def _plain_pods():
+    return [make_pod(f"plain-{i}", cpu="1" if i % 2 else "500m") for i in range(4)]
+
+
+# the pair axis follows the pairs (ops/templates.pair_slots): no pair is one
+# dead slot, _mk_pods' spread + anti-affinity pairs take the rung of 4
+@pytest.mark.parametrize(
+    "mk_pods,slots", [(_plain_pods, 1), (_mk_pods, 4)], ids=["J1", "J4"]
+)
+def test_sharded_wave_matches_single_device(cluster, mk_pods, slots):
     from kubernetes_tpu.ops.wavelattice import make_wave_kernel_jit
     from kubernetes_tpu.parallel.sharded import make_sharded_wave_kernel
     from kubernetes_tpu.ops.lattice import DEFAULT_WEIGHTS
 
     enc = cluster
-    eb, ptab = _wave_inputs(enc, _mk_pods())
+    eb, ptab = _wave_inputs(enc, mk_pods())
+    assert ptab.col.shape[0] == slots
     w = np.asarray(DEFAULT_WEIGHTS)
     key = jax.random.PRNGKey(7)
 

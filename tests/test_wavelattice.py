@@ -30,8 +30,7 @@ from test_lattice_smoke import make_node, make_pod
 def run_wave(enc, pods, pad=None, cache=None):
     cache = cache or TemplateCache(enc)
     eb = cache.encode(pods, pad_to=pad or max(1, len(pods)))
-    pt, overflow = build_pair_table(enc, eb.tpl_np, eb.num_templates)
-    assert not overflow
+    pt = build_pair_table(enc, eb.tpl_np, eb.num_templates)
     snap = enc.flush()
     kern = make_wave_kernel_jit(enc.cfg.v_cap)
     new_snap, res = kern(
@@ -162,14 +161,14 @@ def test_wave_occupancy_chains_to_next_batch():
     enc.add_node(make_node("n1", cpu="3"))
     cache = TemplateCache(enc)
     eb = cache.encode([make_pod("a", cpu="2")], pad_to=1)
-    pt, _ = build_pair_table(enc, eb.tpl_np, eb.num_templates)
+    pt = build_pair_table(enc, eb.tpl_np, eb.num_templates)
     snap = enc.flush()
     kern = make_wave_kernel_jit(enc.cfg.v_cap)
     w = jnp.asarray(DEFAULT_WEIGHTS)
     snap, r1 = kern(snap, eb.batch, pt, w, jax.random.PRNGKey(0))
     first = int(r1.chosen[0])
     eb2 = cache.encode([make_pod("b", cpu="2")], pad_to=1)
-    pt2, _ = build_pair_table(enc, eb2.tpl_np, eb2.num_templates)
+    pt2 = build_pair_table(enc, eb2.tpl_np, eb2.num_templates)
     snap, r2 = kern(snap, eb2.batch, pt2, w, jax.random.PRNGKey(1))
     second = int(r2.chosen[0])
     assert {first, second} == {0, 1}
