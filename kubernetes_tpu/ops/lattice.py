@@ -677,6 +677,7 @@ preempt_whatif = jax.jit(_preempt_whatif)
 
 GUARD_ROW_RANGE = "row_out_of_range"
 GUARD_NONFINITE = "nonfinite_score"
+GUARD_COMMIT_WAVE = "commit_wave_mismatch"
 # split-phase readback: the trailing bulk transfer died after the fast
 # index payload already drove assumes — the batch's device commits are
 # unverifiable and must quarantine/unwind
@@ -693,14 +694,22 @@ class KernelGuardTrip(RuntimeError):
         self.reason = reason
 
 
-def validate_batch_outputs(chosen, placed, score, n_rows: int):
+def validate_batch_outputs(
+    chosen, placed, score, n_rows: int, commit_wave=None
+):
     """Cheap structural validation of a read-back batch result BEFORE any
     placement is acted on: every placed pod's chosen row must name a live
     node row (negative or past-capacity indices would mis-index
     row_names — numpy's negative wrap silently picks the WRONG node), and
     its score must be finite (a NaN/Inf in the score matrix poisons the
-    argmax for the whole column). Returns a trip reason or None."""
+    argmax for the whole column). A wave result also says in which
+    iteration each pod committed (`commit_wave`, the order its binds
+    leave in): one exactly where `placed`. Returns a trip reason or None."""
     placed = np.asarray(placed, dtype=bool)
+    if commit_wave is not None and not np.array_equal(
+        np.asarray(commit_wave) >= 0, placed
+    ):
+        return GUARD_COMMIT_WAVE
     if not placed.any():
         return None
     rows = np.asarray(chosen)[placed]

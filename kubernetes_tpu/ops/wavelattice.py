@@ -87,6 +87,10 @@ class WaveResult(NamedTuple):
     chosen: Any  # [P] int32 node row, -1 = not placed
     placed: Any  # [P] bool
     deferred: Any  # [P] bool — feasible nodes existed but waves ran out
+    commit_wave: Any  # [P] int32 — the Stage B iteration that committed the
+    # pod, -1 = not placed. Binds leave in (commit_wave, pod) order: the
+    # order in which every hard verdict held (one contributor per (pair,
+    # domain) an iteration; within one iteration any order is sound)
     feasible_count: Any  # [P] int32 base-feasible node count
     score: Any  # [P] float32
     resolvable_tpl: Any  # [TPL, N] bool — preemption candidates per template
@@ -558,8 +562,8 @@ def make_wave_kernel(
         )  # [TPL, R+PV]
 
         # ================= Stage B: waves =================
-        def wave(_, state):
-            placed, chosen, req_d, port_d, dom_d, nz2_d = state
+        def wave(w, state):
+            placed, chosen, commit_wave, req_d, port_d, dom_d, nz2_d = state
             free_d = free0 - req_d  # [N, R] (prefix-fit still needs full N)
             # ---- candidate-column re-checks: [TPL, M], never [TPL, N] ----
             free_c = free0_cols - req_d[top_i]  # [TPL, M, R]
@@ -764,10 +768,14 @@ def make_wave_kernel(
             )
             placed = placed | commit
             chosen = jnp.where(commit, cand_n, chosen)
-            return placed, chosen, req_d, port_d, dom_d, nz2_d
+            commit_wave = jnp.where(
+                commit, jnp.asarray(w, jnp.int32), commit_wave
+            )
+            return placed, chosen, commit_wave, req_d, port_d, dom_d, nz2_d
 
         state0 = (
             jnp.zeros(P, bool),
+            jnp.full(P, -1, jnp.int32),
             jnp.full(P, -1, jnp.int32),
             jnp.zeros_like(snap.requested),
             jnp.zeros_like(snap.port_counts),
@@ -779,8 +787,8 @@ def make_wave_kernel(
         # batch of that shape and its cost does not depend on the data. An
         # early exit once nothing is left to place is ROADMAP A5 and
         # needs a measurement on the chip first.
-        placed, chosen, req_d, port_d, dom_d, _nz2_d = jax.lax.fori_loop(
-            0, n_waves, wave, state0
+        placed, chosen, commit_wave, req_d, port_d, dom_d, _nz2_d = (
+            jax.lax.fori_loop(0, n_waves, wave, state0)
         )
 
         # ================= finalize: commit occupancy to snapshot ==========
@@ -821,6 +829,7 @@ def make_wave_kernel(
             chosen=jnp.where(placed, chosen, -1),
             placed=placed,
             deferred=deferred,
+            commit_wave=commit_wave,
             feasible_count=feas_cnt,
             score=score_out,
             resolvable_tpl=resolvable_tpl,

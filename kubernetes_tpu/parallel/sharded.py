@@ -210,6 +210,7 @@ def make_sharded_wave_kernel(
             chosen=rep,
             placed=rep,
             deferred=rep,
+            commit_wave=rep,
             feasible_count=rep,
             score=rep,
             resolvable_tpl=NamedSharding(mesh, P(None, NODES_AXIS)),

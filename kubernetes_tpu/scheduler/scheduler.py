@@ -125,6 +125,11 @@ GAUGE_WAVE_TRAILING_BACKLOG = "scheduler_wave_trailing_backlog"
 # deferral livelock otherwise shows only as pods that never bind
 COUNTER_WAVE_DEFERRED = "scheduler_wave_deferred_pods_total"
 GAUGE_WAVE_DEFERRED_MAX = "scheduler_wave_deferred_max_attempts"
+# the Stage B iterations in which a resolved launch committed a pod (the
+# last pod's commit_wave + 1; 0 for a launch that placed nothing), and the
+# launches whose batch carried a hard pair (the full-wave-count variant)
+COUNTER_WAVE_COMMIT_ITERATIONS = "scheduler_wave_commit_iterations_total"
+COUNTER_WAVE_HARD_BATCHES = "scheduler_wave_hard_batches_total"
 # a deferred pod re-enters a wave within seconds (readd, or 1-10 s of
 # backoff): an entry untouched this long belongs to a pod that is gone
 _DEFERRED_FORGET_S = 120.0
@@ -458,6 +463,8 @@ class Scheduler:
         # the batch-fill policy, the launch bucketing, and the standby
         # warm-up all reason about
         self._small_bucket = min(256, self._batch_size)
+        # the last wave launch carried a hard pair: see _batch_limit
+        self._hard_backlog = False
         # auto: serial-fidelity refresh where it's free (TPU); the same
         # [P, M] per-wave gathers are ~25% of CPU kernel wall
         self._score_refresh = (
@@ -1082,6 +1089,23 @@ class Scheduler:
         finally:
             self._phase.close()
 
+    def _batch_limit(self) -> int:
+        """The most pods the next batch takes from the queue. A launch
+        with a hard pair commits at most one pod per (pair, domain) an
+        iteration however many pods it carries (48 over three zones in
+        its 16 iterations; a template's 32 candidate columns bound a
+        hostname pair sooner), and every pod it carries is encoded,
+        read back and, if it was deferred, re-queued: at the 4,096 bucket
+        such a launch takes 970 ms and a backlog that fills it binds 0-2
+        pods/s; at 256 pods it defers 183 to commit 3 (my chip runs, PR
+        34). So while the launches carry hard pairs a batch takes four
+        pods an iteration (the rest waits in the queue, where it costs
+        nothing), and _schedule_batch_wave_once returns the tail of a
+        hard batch that was popped before its kind was known."""
+        if self._hard_backlog:
+            return min(self._small_bucket, 4 * self.cfg.wave_n_waves)
+        return self._batch_size
+
     def _scheduling_loop_body(self) -> None:
         ph = self._phase
         while not self._stop.is_set():
@@ -1117,10 +1141,8 @@ class Scheduler:
             # became safe when wave launches stopped serializing against
             # audits/what-ifs on the device lock.
             backlog = self.queue.active_len()
-            if (
-                self._pending
-                and self._small_bucket < backlog < self._batch_size
-            ):
+            limit = self._batch_limit()
+            if self._pending and self._small_bucket < backlog < limit:
                 self._busy = True
                 try:
                     self._resolve_pending()
@@ -1138,7 +1160,7 @@ class Scheduler:
             # batch is still on its way into the pipeline
             ph.switch("pop")
             pis = self.queue.pop_batch(
-                self._batch_size,
+                limit,
                 timeout=0.0 if inflight else 0.2,
                 window=0.0 if inflight else self.cfg.device_batch_window,
                 on_first=self._mark_busy,
@@ -1832,14 +1854,17 @@ class Scheduler:
         soft-only burst to the full wave count). No-hard batches:
         prefix-fit packing commits many pods per node per wave, so
         conflicts drain in 1-2 waves even at 4096-pod bursts; losers
-        defer and retry next batch. Measured (r5, CPU 5k nodes,
-        PodAffinity): 2 waves 2020 pods/s vs 4 waves 1602, all scheduled,
-        same batch count. Hard-pair batches keep the configured count —
-        and get the per-wave score refresh regardless of backend (see
-        _schedule_batch_wave): without it the candidate columns chase
-        batch-start domain counts while in-batch commits fill the
-        low-count domains, and a 5k-node hard-spread storm was measured
-        converging bimodally (7 vs 88 pods/s) on CPU."""
+        defer and retry next batch (`deferred_pods_per_wave` 0.0 in the
+        five monotone cells: ledger, PR 33). Hard-pair batches keep the
+        configured count and get the per-wave score refresh regardless of
+        backend (_wave_variant). What that program does on the chip (my
+        chip runs, PR 34, `perf5k-topologyspread.backlog`: 5,000 nodes, 3
+        zones, hard `maxSkew` 1): 7.3 ms a launch at the 256 bucket
+        against 3.3 ms for the 2-iteration program; it commits in 2 of
+        its 16 iterations (`commit_iterations_per_wave` 2.00), 3 pods a
+        launch, because Stage A picks the candidate columns from the
+        verdicts at the launch's start and a zone that is over the skew
+        then has none when it becomes the minimum (ROADMAP A11)."""
         enc = self.cache.encoder
         b = eb.tpl_np
         present = np.unique(eb.pod_tpl_np[eb.pod_tpl_np >= 0])
@@ -1882,8 +1907,7 @@ class Scheduler:
             self._use_pallas_fit,
             # hard-pair batches get the per-wave refresh on EVERY
             # backend: in-batch commits fill the low-count domains the
-            # batch-start candidate columns chase, and a CPU hard-
-            # spread storm measured bimodal convergence without it
+            # batch-start candidate columns chase
             self._score_refresh or batch_has_hard,
             self._rtc_shape or DEFAULT_RTC_SHAPE,
             has_pinned,
@@ -1975,8 +1999,8 @@ class Scheduler:
             # checked donation site (graftlint donation pass)
             new_snap, res = kern(dl.snap, batch, ptab, weights, key)  # graftlint: donating-call
             # start BOTH device->host copies at dispatch. The few-KB index
-            # payload (chosen/placed/deferred) lands the moment the kernel
-            # resolves — the fast resolve below never joins with it over
+            # payload (chosen/placed/deferred/commit_wave) lands the moment
+            # the kernel resolves — the fast resolve below never joins with it over
             # a fresh RTT — and the bulk score streams behind it for the
             # trailing validation. Inside the donation lease on purpose
             # (graftlint fastpath rule): the early transfer is tied to
@@ -1986,6 +2010,7 @@ class Scheduler:
                 res.chosen.copy_to_host_async()
                 res.placed.copy_to_host_async()
                 res.deferred.copy_to_host_async()
+                res.commit_wave.copy_to_host_async()
                 res.score.copy_to_host_async()
             except Exception:
                 # sharded outputs on exotic meshes may not support the
@@ -1999,9 +2024,10 @@ class Scheduler:
 
     def _fetch_wave_index(self, batches: List["_InFlightBatch"]):
         """Seam for the fault injector: the split-phase FAST readback —
-        just the index payload (chosen, placed, deferred) per batch, k
-        batches in one device->host fetch. The async copy started at
-        dispatch means this usually consumes an already-landed transfer.
+        just the index payload (chosen, placed, deferred, commit_wave)
+        per batch, k batches in one device->host fetch. The async copy
+        started at dispatch means this usually consumes an already-landed
+        transfer.
         Blocking fetches (payload not materialized yet — the resolve
         overtook the kernel) count separately: they are the
         readbacks_per_bind numerator."""
@@ -2013,7 +2039,10 @@ class Scheduler:
             metrics.inc(COUNTER_WAVE_BLOCKING_READBACKS)
             metrics.inc("scheduler_wave_readbacks_total")
         return jax.device_get(
-            [(b.res.chosen, b.res.placed, b.res.deferred) for b in batches]
+            [
+                (b.res.chosen, b.res.placed, b.res.deferred, b.res.commit_wave)
+                for b in batches
+            ]
         )
 
     def _fetch_wave_bulk(self, entries: List["_TrailingReadback"]):
@@ -2247,7 +2276,13 @@ class Scheduler:
         if self._pending and self.cache.encoder.has_pending_updates:
             self._resolve_pending()
         ph = self._phase
+        tail: List[QueuedPodInfo] = []
         while True:
+            # a hard batch's surplus (cut off below, under the lock) goes
+            # back to the queue outside it
+            for pi in tail:
+                self.queue.readd(pi)
+            tail = []
             # every instant below is ONE read shared by the loop's phase,
             # the stage histograms and the wave trace; the histograms are
             # observed after the lock is released
@@ -2259,6 +2294,18 @@ class Scheduler:
                 eb = self._tpl_cache.encode([pi.pod for pi in pis], pad_to=pad)
                 ptab = self._pair_table(eb)
                 n_waves, batch_has_hard = self._batch_waves(eb)
+                self._hard_backlog = batch_has_hard
+                limit = self._batch_limit()
+                if batch_has_hard and len(pis) > limit:
+                    # popped before its kind was known: a hard batch's
+                    # share now, the rest back where it waited (in place:
+                    # the caller's retry sees the batch it has)
+                    tail = pis[limit:]
+                    del pis[limit:]
+                    if pad != small:
+                        pad, small_bucket = small, True
+                        m_cand = min(self.cfg.wave_m_cand_small, self._m_cand)
+                    continue  # encode what is left
                 if small_bucket and not batch_has_hard:
                     # latency bucket, no hard pairs present: ≤256 pods
                     # across the cluster rarely conflict, and a deferred
@@ -2322,7 +2369,8 @@ class Scheduler:
         # batch shares — each pod's span chain carries `wave=<id>` so a
         # slow wave explains its N slow pods in one lookup
         wave_tid = tracer.start(
-            "wave", f"wave/{len(pis)}pods", t0=t_start, pods=len(pis)
+            "wave", f"wave/{len(pis)}pods", t0=t_start, pods=len(pis),
+            hard=batch_has_hard,
         )
         tracer.add_span(wave_tid, "encode", t_start, t_launch0)
         tracer.add_span(wave_tid, "launch", t_launch0, t_launched)
@@ -2342,6 +2390,8 @@ class Scheduler:
         metrics.inc(
             "scheduler_wave_pair_slots_total", by=float(ptab.col.shape[0])
         )
+        if batch_has_hard:
+            metrics.inc(COUNTER_WAVE_HARD_BATCHES)
         if len(pis) > self._wave_batch_pods_peak:
             self._wave_batch_pods_peak = len(pis)
             metrics.set_gauge(GAUGE_WAVE_BATCH_PODS_MAX, float(len(pis)))
@@ -2380,6 +2430,7 @@ class Scheduler:
             _device_ready(b.res.chosen)
             and _device_ready(b.res.placed)
             and _device_ready(b.res.deferred)
+            and _device_ready(b.res.commit_wave)
         )
 
     def _resolve_pending(self) -> None:
@@ -2586,7 +2637,7 @@ class Scheduler:
         pis, eb, row_names = p.pis, p.eb, p.row_names
         # the fast index payload; the score arrives with the trailing
         # bulk readback and is validated there
-        chosen, placed, deferred = arrays
+        chosen, placed, deferred, commit_wave = arrays
         t_start = p.t_start
         algo_dur = (t_guard0 or time.monotonic()) - t_start
         metrics.observe("scheduling_algorithm_duration_seconds", algo_dur)
@@ -2596,17 +2647,33 @@ class Scheduler:
             # would either crash the commit or (negative wrap) silently
             # pick the WRONG node
             reason = validate_batch_outputs(
-                chosen, placed, None, len(row_names)
+                chosen, placed, None, len(row_names), commit_wave
             )
             if reason:
                 raise KernelGuardTrip(reason)
 
+        # binds leave in the order the kernel committed them: (iteration,
+        # pod). A hard spread or anti-affinity verdict held when its
+        # iteration began and one pod a (pair, domain) commits in it, so
+        # this is an order in which every placement is feasible at its
+        # turn; pod-index order is not (pod 0 may have committed last).
+        # The unplaced sort behind the placed, in pod order (stable).
+        n_pods = len(pis)  # the arrays are padded to the bucket
+        order = np.argsort(
+            np.where(
+                np.asarray(placed, dtype=bool)[:n_pods],
+                np.asarray(commit_wave)[:n_pods],
+                np.iinfo(np.int32).max,
+            ),
+            kind="stable",
+        ).tolist()
         to_bind: List = []  # (pi, node_name, prio_band, proto)
         protos: dict = {}  # template -> shared encoder proto
         fallback_pis: List[QueuedPodInfo] = []
         failed: List = []  # (pi, tpl_index)
         deferred_pis: List[QueuedPodInfo] = []
-        for i, pi in enumerate(pis):
+        for i in order:
+            pi = pis[i]
             if eb.fallback[i]:
                 fallback_pis.append(pi)
                 continue
@@ -2659,13 +2726,17 @@ class Scheduler:
             else:
                 self.queue.requeue_backoff(pi)
         self._note_deferrals(p, deferred_pis)
+        iterations = int(np.max(commit_wave, initial=-1)) + 1
+        metrics.inc(COUNTER_WAVE_COMMIT_ITERATIONS, by=float(iterations))
         # the guard stage ends here (one read): the hand-off to assume.
         # Registering the trailing half is `trailing` work;
         # _assume_and_bind_bulk switches to `assume`.
         t_g1 = self._phase.switch("trailing")
         if t_guard0 is not None:
             _observe_stage("guard", t_guard0, t_g1)
-            tracer.add_span(p.wave_tid, "guard", t_guard0, t_g1)
+            tracer.add_span(
+                p.wave_tid, "guard", t_guard0, t_g1, iterations=iterations
+            )
         if t_rb1 is not None:
             # pods: guard = readback done -> assume hand-off (output
             # validation, decode, oracle sample, and any elder-sibling
@@ -3466,16 +3537,8 @@ class Scheduler:
                 )
                 continue
             prof = self.profiles.for_pod(pod)
-            ps = prof.framework.plugin_set
-            plain = (
-                not ps.reserve
-                and not ps.permit
-                and not ps.pre_bind
-                and not ps.post_bind
-                and ps.bind == ["DefaultBinder"]
-            )
             self.queue.delete_nominated_if_exists(pod)
-            if plain:
+            if self._binds_in_cycle(prof):
                 simple.append((pi, node_name, prof))
             else:
                 self._assume_and_bind_after_assume(pi, node_name, t_start)
@@ -3546,6 +3609,23 @@ class Scheduler:
         if to_buffer:
             self._buffer_pending_binds(to_buffer)
         ph.switch("other")
+
+    @staticmethod
+    def _binds_in_cycle(prof) -> bool:
+        """A profile with nothing around its bind (no reserve, permit,
+        pre- or post-bind plugin, the default binder): its binds are sent
+        from the scheduling thread, in the order the placements were
+        committed, and not from the bind pool, whose workers would let a
+        later placement reach the store before an earlier one it was
+        feasible after."""
+        ps = prof.framework.plugin_set
+        return (
+            not ps.reserve
+            and not ps.permit
+            and not ps.pre_bind
+            and not ps.post_bind
+            and ps.bind == ["DefaultBinder"]
+        )
 
     def _assume_and_bind_after_assume(
         self, pi: QueuedPodInfo, node_name: str, t_start: float
@@ -3690,6 +3770,17 @@ class Scheduler:
             self._handle_failure(pi, self.queue.moves_snapshot(), message=st.message)
             return
         self._stamp_bind_submit(pi, t_a0)
+        if (
+            self._binds_in_cycle(prof)
+            and not self._pod_has_pvcs(pod)
+            and not any(
+                e.is_binder() and e.is_interested(pod) for e in self.extenders
+            )
+        ):
+            # the host path's binds leave in the order of its assumes, as
+            # the wave path's do (_assume_and_bind_bulk): from this thread
+            self._bind_async(pi, node_name, state, t_start)
+            return
         try:
             self._bind_pool.submit(
                 self._bind_async, pi, node_name, state, t_start

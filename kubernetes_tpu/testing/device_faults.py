@@ -179,14 +179,14 @@ class DeviceFaultInjector:
             )
         fetched = self._real_fetch_index(batches)
         out = []
-        for chosen, placed, deferred in fetched:
+        for chosen, placed, deferred, commit_wave in fetched:
             chosen = np.array(chosen)
             placed = np.array(placed)
             if wild and placed.any():
                 chosen = chosen.copy()
                 chosen[np.nonzero(placed)[0][0]] = 2**30
                 self.injected.append(("wild_row", n))
-            out.append((chosen, placed, deferred))
+            out.append((chosen, placed, deferred, commit_wave))
         return out
 
     def _fetch_bulk(self, entries):
