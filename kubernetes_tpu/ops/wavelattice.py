@@ -13,9 +13,15 @@ batch cycle so nothing scales with P serially:
       16, ... slots for the pairs the templates reference): the segment
       scatters and gathers below cost a TPU one serial update per [J, N]
       element, dead slot or not;
-    * per-template top-M candidate nodes; per-pod candidate order =
-      score-descending with per-pod random tie-noise (selectHost's uniform
-      tie-break, generic_scheduler.go:235).
+    * per-template top-M candidate nodes: the M best-scoring nodes feasible
+      at the launch's start, or, for a template with a hard
+      (DoNotSchedule) topology-spread pair in a program compiled with
+      `stratify`, the best nodes of EVERY domain of that pair, M / D a
+      domain, whether or not the skew lets the domain take a pod yet: a
+      zone over the skew at the start is the only feasible one two
+      iterations later, and Stage B can commit only at the columns;
+      per-pod candidate order = score-descending with per-pod random
+      tie-noise (selectHost's uniform tie-break, generic_scheduler.go:235).
 
   Stage B (W waves, all-vectorized):
     every wave, each unplaced pod takes its best still-feasible candidate;
@@ -107,17 +113,28 @@ def _group_prefix_sums(groups, sort_key, values):
     v = values[order]
     cum = jnp.cumsum(v, axis=0)
     excl_global = cum - v
-    # group start position via running max over indices where a new group starts
-    pos = jnp.arange(g.shape[0])
-    is_start = jnp.concatenate([jnp.array([True]), g[1:] != g[:-1]])
-    start_pos = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(is_start, pos, -1)
-    )
-    base = excl_global[start_pos]
+    base = excl_global[_run_starts(g)]
     return order, excl_global - base
 
 
+def _run_starts(g):
+    """For each position of `g` [X], where its run of equal values began:
+    a running max over the indices at which a new run starts."""
+    pos = jnp.arange(g.shape[0])
+    is_start = jnp.concatenate([jnp.array([True]), g[1:] != g[:-1]])
+    return jax.lax.associative_scan(
+        jnp.maximum, jnp.where(is_start, pos, -1)
+    )
+
+
 DEFAULT_RTC_SHAPE = ((0.0, 0.0), (100.0, 10.0))
+
+
+def _ordered_bits(x):
+    """int32 whose signed order is the float32 order of `x` (-0.0 below
+    0.0): a sort key the TPU compares as an integer."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b)
 
 
 def _bool_cols(plane, idx):
@@ -128,6 +145,56 @@ def _bool_cols(plane, idx):
     were right — so every candidate of the small bucket read as statically
     infeasible and its pods were deferred forever."""
     return jnp.take_along_axis(plane.astype(jnp.int32), idx, axis=1) != 0
+
+
+def stratified_columns(dom, elig, feas, score, m_c: int, v_cap: int):
+    """One template's m_c candidate columns taken round-robin over the
+    domains `dom` [N] (ids below v_cap) of a hard spread pair, from the
+    `elig` nodes: (the columns' scores descending, -inf where no eligible
+    node was left; their node rows). The order they are taken in is (rank
+    of the node within its domain, feasible at the launch's start before
+    not, score descending, row), the rank being by (`feas`, `score`, row)
+    within the domain: each present domain gives its m_c / D best nodes,
+    and a column that was feasible at the start is displaced only by
+    another domain's share. With D >= m_c (a hostname spread) every rank
+    is 0 and the list is top_k's over the feasible nodes.
+
+    Three sorts over [N] with unique int32 keys: a sort the TPU compiles
+    in seconds (a stable or several-key float32 sort of this size takes
+    tens) and runs in 0.1 ms for [4, 8192] (my chip run, PR 35)."""
+    n = dom.shape[0]
+    if (2 * m_c + 3) * n >= 2**31:
+        raise ValueError(f"{m_c} columns of {n} nodes overflow the int32 keys")
+    rows = jnp.arange(n, dtype=jnp.int32)
+    # 1: by (score descending, row); `at` is a node's place in that order
+    _, row1, k1 = jax.lax.sort(
+        (
+            ~_ordered_bits(score),
+            rows,
+            jnp.where(elig, dom, v_cap) * 2 + (~feas).astype(jnp.int32),
+        ),
+        num_keys=2, is_stable=False,
+    )
+    # 2: by (domain, feasible first, score, row), the ineligible behind:
+    # a node's rank within its domain
+    k2, at2, row2 = jax.lax.sort((k1, rows, row1), num_keys=2, is_stable=False)
+    d2 = k2 // 2
+    # a node of rank m_c or more has m_c nodes before it: never taken
+    rank = jnp.minimum(rows - _run_starts(d2), m_c)
+    # 3: by (rank, feasible first, score, row)
+    k3, row3 = jax.lax.sort(
+        (
+            jnp.where(d2 < v_cap, rank * 2 + k2 % 2, 2 * m_c + 2) * n + at2,
+            row2,
+        ),
+        num_keys=1, is_stable=False,
+    )
+    col = row3[:m_c]
+    v = jnp.where(k3[:m_c] < (2 * m_c + 2) * n, score[col], -jnp.inf)
+    # real scores, descending: the tie groups, the per-pod shuffle and
+    # the per-iteration re-score read top_v
+    neg_v, col = jax.lax.sort((-v, col), num_keys=1, is_stable=True)
+    return -neg_v, col
 
 
 @functools.lru_cache(maxsize=32)
@@ -141,9 +208,17 @@ def make_wave_kernel(
     rtc_shape: tuple = DEFAULT_RTC_SHAPE,
     has_pinned: bool = True,
     pallas_interpret: bool = False,
+    stratify: bool = False,
     mesh=None,
 ):
     """Build the wave kernel (unjitted) for the given static capacities.
+
+    stratify=True compiles IN the candidate columns' stratification over
+    a hard topology-spread pair's domains (Stage A, `stratified_columns`):
+    three sorts over [TPL, N] that a batch without such a template does
+    not pay. The host passes whether the batch's templates carry one
+    (scheduler._batch_waves), as it passes the wave count; a template
+    without a hard spread pair keeps its columns bit for bit either way.
 
     has_pinned=False compiles OUT the per-wave pinned-row plan (the
     [J, P] pair gathers + [TPL, J, P] verdict vmap below) — for the
@@ -434,6 +509,38 @@ def make_wave_kernel(
         # ---- top-M candidates per template ----
         masked = jnp.where(feasible0, total_score, -jnp.inf)
         top_v, top_i = jax.lax.top_k(masked, m_c)  # [TPL, M]
+        if stratify:
+            def stratified_one(spr_pair, spr_hard, elig, feas, score):
+                """(whether the template has a hard spread pair, its
+                stratified columns' scores and rows): stratified by the
+                hard pair with the fewest present domains; the others
+                are re-checked per iteration like every verdict."""
+                is_hard = (spr_pair >= 0) & spr_hard  # [C]
+                p = jnp.clip(spr_pair, 0, J - 1)
+                n_dom = jnp.sum(present_dom[p].astype(jnp.int32), axis=1)
+                by = p[jnp.argmin(jnp.where(is_hard, n_dom, v_cap + 1))]
+                # a node without a hard pair's key fails that pair
+                # whatever the counts are
+                elig = elig & jnp.all(
+                    ~is_hard[:, None] | (dom_j[p] >= 0), axis=0
+                )
+                return (jnp.any(is_hard),) + stratified_columns(
+                    dom_j[by], elig, feas, score, m_c, v_cap
+                )
+
+            # every launch-start verdict but the hard spreads' skew
+            # comparison: the one verdict that commits in OTHER domains
+            # turn from bad to good inside a launch. Stage B re-checks it
+            # with every other verdict at each column in each iteration
+            eligible = (
+                static_ok & fits0 & ~ports0 & aff_ok0 & ~anti_bad0
+                & ~eterm_bad0
+            )
+            has_strat, strat_v, strat_i = jax.vmap(stratified_one)(
+                pt.spr_pair, pt.spr_hard, eligible, feasible0, total_score
+            )
+            top_v = jnp.where(has_strat[:, None], strat_v, top_v)
+            top_i = jnp.where(has_strat[:, None], strat_i, top_i)
 
         # ---- per-pod candidate ordering ----
         t_of = jnp.clip(tb.pod_tpl, 0, TPL - 1)  # [P]
@@ -850,6 +957,7 @@ def make_wave_kernel_jit(
     rtc_shape: tuple = DEFAULT_RTC_SHAPE,
     has_pinned: bool = True,
     pallas_interpret: bool = False,
+    stratify: bool = False,
 ):
     return jax.jit(
         make_wave_kernel(
@@ -862,6 +970,7 @@ def make_wave_kernel_jit(
             rtc_shape,
             has_pinned,
             pallas_interpret,
+            stratify,
         ),
         donate_argnums=(0,),
     )

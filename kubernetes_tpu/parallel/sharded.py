@@ -153,6 +153,7 @@ def make_sharded_wave_kernel(
     rtc_shape: tuple = None,
     has_pinned: bool = True,
     pallas_interpret: bool = False,
+    stratify: bool = False,
 ):
     """The PRODUCTION wave kernel (ops/wavelattice.py) jitted with the
     snapshot sharded over the mesh's node axis.
@@ -186,6 +187,7 @@ def make_sharded_wave_kernel(
         rtc_shape or DEFAULT_RTC_SHAPE,
         has_pinned,
         pallas_interpret,
+        stratify,
         mesh,
     )
     rep = replicated(mesh)

@@ -129,11 +129,10 @@ class KubeSchedulerConfiguration:
     # (one compiled variant per batch shape; ops/wavelattice.py). Batches
     # whose PRESENT templates carry no hard pairs use min(2,
     # wave_n_waves) (scheduler._batch_waves). On the chip the 16
-    # iterations cost 7.3 ms a launch at the 256 bucket (3.3 ms for 2)
-    # and a hard zone spread commits in 2 of them (my chip runs, PR 34,
-    # perf5k-topologyspread.backlog; PERF.md section 6); a batch with a
-    # hard pair takes at most four pods an iteration from the queue
-    # (Scheduler._batch_limit).
+    # iterations cost 7.3-7.4 ms a launch at the 256 bucket (3.3 ms for
+    # 2; my chip runs, PR 34 and PR 35, perf5k-topologyspread.backlog;
+    # PERF.md section 6); a batch with a hard pair takes at most four
+    # pods an iteration from the queue (Scheduler._batch_limit).
     wave_n_waves: int = 16
     # degraded-store ride-through (scheduler/ridethrough.py): placements
     # whose bind 503s retryably park here (pods stay assumed, HBM snapshot

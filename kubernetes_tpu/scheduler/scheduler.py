@@ -127,9 +127,12 @@ COUNTER_WAVE_DEFERRED = "scheduler_wave_deferred_pods_total"
 GAUGE_WAVE_DEFERRED_MAX = "scheduler_wave_deferred_max_attempts"
 # the Stage B iterations in which a resolved launch committed a pod (the
 # last pod's commit_wave + 1; 0 for a launch that placed nothing), and the
-# launches whose batch carried a hard pair (the full-wave-count variant)
+# launches whose batch carried a hard pair (the full-wave-count variant),
+# and those of them whose candidate columns were stratified over a hard
+# spread pair's domains
 COUNTER_WAVE_COMMIT_ITERATIONS = "scheduler_wave_commit_iterations_total"
 COUNTER_WAVE_HARD_BATCHES = "scheduler_wave_hard_batches_total"
+COUNTER_WAVE_STRATIFIED_BATCHES = "scheduler_wave_stratified_batches_total"
 # a deferred pod re-enters a wave within seconds (readd, or 1-10 s of
 # backoff): an entry untouched this long belongs to a pod that is gone
 _DEFERRED_FORGET_S = 120.0
@@ -888,14 +891,15 @@ class Scheduler:
         with self.cache.lock:
             eb = self._tpl_cache.encode([warm_pod], pad_to=small)
             ptab = self._pair_table(eb)
-            n_waves, batch_has_hard = self._batch_waves(eb)
+            n_waves, batch_has_hard, stratify = self._batch_waves(eb)
             n_waves = min(n_waves, 2)  # the small no-hard bucket's count
             snap = self.cache.encoder.flush()
             enc_cfg = self.cache.encoder.cfg
         m_cand = min(self.cfg.wave_m_cand_small, self._m_cand)
         kern = self._wave_kernel(
             self._wave_variant(
-                enc_cfg, m_cand, n_waves, batch_has_hard, has_pinned=False
+                enc_cfg, m_cand, n_waves, batch_has_hard,
+                has_pinned=False, stratify=stratify,
             )
         )
         self._rng_key, sub = jax.random.split(self._rng_key)
@@ -1097,11 +1101,15 @@ class Scheduler:
         hostname pair sooner), and every pod it carries is encoded,
         read back and, if it was deferred, re-queued: at the 4,096 bucket
         such a launch takes 970 ms and a backlog that fills it binds 0-2
-        pods/s; at 256 pods it defers 183 to commit 3 (my chip runs, PR
-        34). So while the launches carry hard pairs a batch takes four
-        pods an iteration (the rest waits in the queue, where it costs
-        nothing), and _schedule_batch_wave_once returns the tail of a
-        hard batch that was popped before its kind was known."""
+        pods/s (my chip runs, PR 34). So while the launches carry hard
+        pairs a batch takes four pods an iteration (the rest waits in
+        the queue, where it costs nothing), and
+        _schedule_batch_wave_once returns the tail of a hard batch that
+        was popped before its kind was known. The 64 were sized when a
+        zone spread committed 3 pods a launch; with its candidate
+        columns stratified over the zones a full batch commits 45-47
+        (my chip run, PR 35, the kernel alone): PERF.md section 7 weighs
+        the value against the sweep past the knee."""
         if self._hard_backlog:
             return min(self._small_bucket, 4 * self.cfg.wave_n_waves)
         return self._batch_size
@@ -1848,39 +1856,40 @@ class Scheduler:
         return table
 
     def _batch_waves(self, eb) -> tuple:
-        """(wave count, has_hard) for THIS batch, from the templates
-        actually present in it (NOT the whole accumulated template cache —
-        one historical hard-pair template must not pin every later
-        soft-only burst to the full wave count). No-hard batches:
-        prefix-fit packing commits many pods per node per wave, so
-        conflicts drain in 1-2 waves even at 4096-pod bursts; losers
+        """(wave count, has_hard, stratify) for THIS batch, from the
+        templates actually present in it (NOT the whole accumulated
+        template cache — one historical hard-pair template must not pin
+        every later soft-only burst to the full wave count). No-hard
+        batches: prefix-fit packing commits many pods per node per wave,
+        so conflicts drain in 1-2 waves even at 4096-pod bursts; losers
         defer and retry next batch (`deferred_pods_per_wave` 0.0 in the
         five monotone cells: ledger, PR 33). Hard-pair batches keep the
         configured count and get the per-wave score refresh regardless of
-        backend (_wave_variant). What that program does on the chip (my
-        chip runs, PR 34, `perf5k-topologyspread.backlog`: 5,000 nodes, 3
-        zones, hard `maxSkew` 1): 7.3 ms a launch at the 256 bucket
-        against 3.3 ms for the 2-iteration program; it commits in 2 of
-        its 16 iterations (`commit_iterations_per_wave` 2.00), 3 pods a
-        launch, because Stage A picks the candidate columns from the
-        verdicts at the launch's start and a zone that is over the skew
-        then has none when it becomes the minimum (ROADMAP A11)."""
+        backend (_wave_variant): 7.3 ms a launch at the 256 bucket against
+        3.3 ms for the 2-iteration program (my chip runs, PR 34). A batch
+        with a hard (`DoNotSchedule`) topology-spread template also gets
+        its candidate columns stratified over that pair's domains
+        (ops/wavelattice.py Stage A): columns taken only from the nodes
+        feasible when the launch begins leave a zone that is over the
+        skew then without one when, two iterations later, it is the only
+        zone that may take a pod, and such a launch committed 3 pods of
+        64 in 2 of its 16 iterations (ledger, PR 34,
+        `perf5k-topologyspread.backlog`)."""
         enc = self.cache.encoder
         b = eb.tpl_np
         present = np.unique(eb.pod_tpl_np[eb.pod_tpl_np >= 0])
         if present.size == 0:
-            return min(2, self.cfg.wave_n_waves), False
+            return min(2, self.cfg.wave_n_waves), False, False
         anti_kinds = [
             tid
             for tid in range(len(enc.eterm_vocab))
             if enc.eterm_vocab.items[tid].kind == _ETERM_ANTI_REQ
         ]
+        hard_spread = bool(
+            np.any((b.spread_key[present] >= 0) & b.spread_hard[present])
+        )
         has_hard = (
-            bool(
-                np.any(
-                    (b.spread_key[present] >= 0) & b.spread_hard[present]
-                )
-            )
+            hard_spread
             or bool(np.any(b.panti_sid[present] >= 0))
             or any(
                 bool(np.any(b.match_eterm[present, tid]))
@@ -1888,12 +1897,12 @@ class Scheduler:
             )
         )
         if has_hard:
-            return self.cfg.wave_n_waves, True
-        return min(2, self.cfg.wave_n_waves), False
+            return self.cfg.wave_n_waves, True, hard_spread
+        return min(2, self.cfg.wave_n_waves), False, False
 
     def _wave_variant(
         self, enc_cfg, m_cand: int, n_waves: int, batch_has_hard: bool,
-        has_pinned: bool,
+        has_pinned: bool, stratify: bool,
     ) -> tuple:
         """The static arguments of one wave-kernel variant, in
         make_wave_kernel_jit's order."""
@@ -1912,6 +1921,7 @@ class Scheduler:
             self._rtc_shape or DEFAULT_RTC_SHAPE,
             has_pinned,
             self._pallas_interpret,
+            stratify,
         )
 
     def _wave_kernel(self, variant: tuple):
@@ -2293,7 +2303,7 @@ class Scheduler:
                 t_f0 = None
                 eb = self._tpl_cache.encode([pi.pod for pi in pis], pad_to=pad)
                 ptab = self._pair_table(eb)
-                n_waves, batch_has_hard = self._batch_waves(eb)
+                n_waves, batch_has_hard, stratify = self._batch_waves(eb)
                 self._hard_backlog = batch_has_hard
                 limit = self._batch_limit()
                 if batch_has_hard and len(pis) > limit:
@@ -2348,7 +2358,7 @@ class Scheduler:
         # variants max per config; pod_name_row is host-resident numpy)
         has_pinned = bool((eb.batch.pod_name_row >= 0).any())
         variant = self._wave_variant(
-            enc_cfg, m_cand, n_waves, batch_has_hard, has_pinned
+            enc_cfg, m_cand, n_waves, batch_has_hard, has_pinned, stratify
         )
         kern = self._wave_kernel(variant)
         self._rng_key, sub = jax.random.split(self._rng_key)
@@ -2370,7 +2380,7 @@ class Scheduler:
         # slow wave explains its N slow pods in one lookup
         wave_tid = tracer.start(
             "wave", f"wave/{len(pis)}pods", t0=t_start, pods=len(pis),
-            hard=batch_has_hard,
+            hard=batch_has_hard, stratified=stratify,
         )
         tracer.add_span(wave_tid, "encode", t_start, t_launch0)
         tracer.add_span(wave_tid, "launch", t_launch0, t_launched)
@@ -2392,6 +2402,8 @@ class Scheduler:
         )
         if batch_has_hard:
             metrics.inc(COUNTER_WAVE_HARD_BATCHES)
+        if stratify:
+            metrics.inc(COUNTER_WAVE_STRATIFIED_BATCHES)
         if len(pis) > self._wave_batch_pods_peak:
             self._wave_batch_pods_peak = len(pis)
             metrics.set_gauge(GAUGE_WAVE_BATCH_PODS_MAX, float(len(pis)))

@@ -5,11 +5,15 @@ pair-axis sizes.
 
     python scripts/profile_kernel.py [--workload SchedulingBasic]
         [--nodes 5000] [--pods 256] [--m 32] [--waves 0,2] [--pairs 32,4,1]
-        [--platform tpu] [--trace-dir DIR]
+        [--zones 3] [--unlevel 0,1] [--platform tpu] [--trace-dir DIR]
 
 The kernel is the variant the scheduler serves for that batch on this
-backend (`Scheduler._wave_variant`, has_pinned=False: on a TPU the Pallas
-fit mask and the per-wave score refresh, on the CPU neither). The n_waves
+backend (`Scheduler._batch_waves` and `_wave_variant`, has_pinned=False: on
+a TPU the Pallas fit mask and the per-wave score refresh, on the CPU
+neither; for a batch with a hard spread template the stratified candidate
+columns), at each wave count asked for. --unlevel K starts from a snapshot
+in which K pods of the measured kind already sit on the first node (zone
+0): a hard zone spread is then over its skew there. The n_waves
 sweep isolates Stage A (n_waves=0 compiles the kernel with an empty
 fori_loop) from the per-wave cost; the P sweep shows how much of the cycle
 is batch-size-invariant (the [TPL, N] planes) vs per-pod; the --pairs sweep
@@ -45,12 +49,21 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 
-def build_inputs(workload: str, n_nodes: int, n_pods: int, m_cand: int):
+def build_inputs(workload: str, n_nodes: int, n_pods: int, m_cand: int,
+                 zones: int = 0, unlevel: int = 0):
+    import dataclasses
+
     from kubernetes_tpu.client.apiserver import APIServer
     from kubernetes_tpu.perf.workloads import WORKLOADS, build_workload
     from kubernetes_tpu.scheduler import KubeSchedulerConfiguration, Scheduler
 
-    cfg = WORKLOADS[f"{workload}/{n_nodes}"]
+    cfg = WORKLOADS.get(f"{workload}/{n_nodes}")
+    if cfg is None:  # a rehearsal size: the family's 5000-node row, cut
+        cfg = dataclasses.replace(
+            WORKLOADS[f"{workload}/5000"], num_nodes=n_nodes
+        )
+    if zones:
+        cfg = dataclasses.replace(cfg, zones=zones)
     server = APIServer()
     sched = Scheduler(server, KubeSchedulerConfiguration())
     sched.cache.encoder.presize_for_cluster(cfg.num_nodes)
@@ -64,17 +77,23 @@ def build_inputs(workload: str, n_nodes: int, n_pods: int, m_cand: int):
             if time.monotonic() > deadline:
                 raise TimeoutError("informer sync")
             time.sleep(0.05)
+        for i in range(unlevel):
+            p = factory(n_pods + i)
+            p.spec.node_name = nodes[0].metadata.name
+            sched.cache.add_pod(p)
         pods = [factory(i) for i in range(n_pods)]
         with sched.cache.lock:
             eb = sched._tpl_cache.encode(pods, pad_to=n_pods)
             ptab = sched._pair_table(eb)
+            _n_waves, has_hard, stratify = sched._batch_waves(eb)
             snap = sched.cache.encoder.flush()
             enc_cfg = sched.cache.encoder.cfg
         weights = np.asarray(sched._weights)
 
         def variant(n_waves: int) -> tuple:
             return sched._wave_variant(
-                enc_cfg, m_cand, n_waves, False, has_pinned=False
+                enc_cfg, m_cand, n_waves, has_hard,
+                has_pinned=False, stratify=stratify,
             )
 
         return snap, eb, ptab, variant, weights
@@ -169,29 +188,37 @@ def main() -> int:
                     help="J sizes to cut or pad the built table to, e.g. "
                     "32,4,1 (default: the table as built)")
     ap.add_argument("--m", type=int, default=32)
+    ap.add_argument("--zones", type=int, default=0,
+                    help="zones of the cluster (default: the workload's)")
+    ap.add_argument("--unlevel", default="0",
+                    help="residents of the measured kind on the first "
+                    "node, one snapshot each, e.g. 0,1")
     ap.add_argument("--platform", default="cpu")
     ap.add_argument("--trace-dir", default="")
     args = ap.parse_args()
 
     dev = jax.devices()[0]
     print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}")
-    for P in [int(x) for x in args.pods.split(",")]:
+    for P, unlevel in [(int(x), int(u)) for x in args.pods.split(",")
+                       for u in args.unlevel.split(",")]:
         snap, eb, built, variant, weights = build_inputs(
-            args.workload, args.nodes, P, args.m
+            args.workload, args.nodes, P, args.m, args.zones, unlevel
         )
         TPL = int(eb.batch.tpl.valid.shape[0])
         real = int((np.asarray(built.col) >= 0).sum())
         sizes = [int(x) for x in args.pairs.split(",") if x] or [
             int(built.col.shape[0])
         ]
-        print(f"{args.workload} P={P} nodes={args.nodes} TPL={TPL} real_pairs={real} "
+        print(f"{args.workload} P={P} nodes={args.nodes} unlevel={unlevel} "
+              f"TPL={TPL} real_pairs={real} "
               f"J_built={int(built.col.shape[0])} variant={variant(2)}")
         for J in sizes:
             ptab = resize_pairs(built, J)
             for w in [int(x) for x in args.waves.split(",")]:
                 best, med, cs, placed, device = time_kernel(
                     snap, eb, ptab, variant(w), weights,
-                    trace_dir=(os.path.join(args.trace_dir, f"P{P}-J{J}-w{w}")
+                    trace_dir=(os.path.join(args.trace_dir,
+                                            f"P{P}-u{unlevel}-J{J}-w{w}")
                                if args.trace_dir else None),
                 )
                 cut = " (TRUNCATED: a timing, not a schedule)" if J < real else ""

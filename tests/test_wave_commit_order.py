@@ -118,11 +118,12 @@ def _launch(kind: str, seed: int, where: str):
     w = np.asarray(DEFAULT_WEIGHTS)
     key = jax.random.PRNGKey(seed)
     if where == "single":
-        kern = make_wave_kernel_jit(enc.cfg.v_cap, 32, 16)
+        kern = make_wave_kernel_jit(enc.cfg.v_cap, 32, 16, stratify=True)
     else:
         mesh = make_mesh(jax.devices()[:4])
         enc.set_sharding(snapshot_shardings(mesh), replicated(mesh))
-        kern = make_sharded_wave_kernel(enc.cfg.v_cap, 32, 16, 1.0, mesh)
+        kern = make_sharded_wave_kernel(
+            enc.cfg.v_cap, 32, 16, 1.0, mesh, stratify=True)
     _snap, res = kern(enc.flush(), eb.batch, ptab, w, key)
     chosen, placed, commit_wave, deferred = jax.device_get(
         (res.chosen, res.placed, res.commit_wave, res.deferred)
@@ -166,9 +167,10 @@ def _commit_order(commit_wave, placed):
 @pytest.mark.parametrize("kind", list(KINDS))
 def test_every_placement_is_feasible_in_commit_order(kind, seed, where):
     pods, names, commit_wave, placed, infos, deferred = _launch(kind, seed, where)
-    # an unlevel start places as few as two: the candidate columns are
-    # chosen from the verdicts at the launch's start (PERF.md, PR 34)
-    assert placed.sum() >= 1
+    # one commit an iteration is the floor the algorithm guarantees; the
+    # spread's columns are stratified over the zones, so an unlevel start
+    # does not run out of zones that have one (35-47 of 64: PERF.md, PR 35)
+    assert placed.sum() >= (16 if kind == "spread" else 1)
     # commit_wave names an iteration exactly where a pod was placed
     assert ((commit_wave >= 0) == placed).all()
     assert commit_wave.max() < 16 and commit_wave.min() >= -1
@@ -253,6 +255,7 @@ def test_served_path_binds_in_an_order_that_keeps_the_hard_spread():
     c0 = {name: metrics.counter(name) for name in (
         "scheduler_wave_commit_iterations_total",
         "scheduler_wave_hard_batches_total", "scheduler_wave_batches_total",
+        "scheduler_wave_stratified_batches_total",
         "scheduler_wave_deferred_pods_total", "kernel_guard_trips_total")}
     # the whole backlog waits when the scheduler starts: its first batch is
     # popped at the full size, before its kind is known
@@ -276,8 +279,10 @@ def test_served_path_binds_in_an_order_that_keeps_the_hard_spread():
     assert sched._hard_backlog and sched._batch_limit() == 64 < n_pods
     assert sched._wave_batch_pods_peak == 64
     assert d["kernel_guard_trips_total"] == 0
-    # every launch was the hard-pair program, and pods were deferred on the way
+    # every launch was the hard-pair program with its columns stratified
+    # over the zones, and pods were deferred on the way
     assert d["scheduler_wave_hard_batches_total"] == d["scheduler_wave_batches_total"] > 0
+    assert d["scheduler_wave_stratified_batches_total"] == d["scheduler_wave_batches_total"]
     assert d["scheduler_wave_deferred_pods_total"] > 0
     assert 0 < d["scheduler_wave_commit_iterations_total"] <= (
         16 * d["scheduler_wave_batches_total"])
