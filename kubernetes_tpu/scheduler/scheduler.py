@@ -129,10 +129,11 @@ GAUGE_WAVE_DEFERRED_MAX = "scheduler_wave_deferred_max_attempts"
 # last pod's commit_wave + 1; 0 for a launch that placed nothing), and the
 # launches whose batch carried a hard pair (the full-wave-count variant),
 # and those of them whose candidate columns were stratified over a hard
-# spread pair's domains
+# spread pair's domains, and those a required anti-affinity term made hard
 COUNTER_WAVE_COMMIT_ITERATIONS = "scheduler_wave_commit_iterations_total"
 COUNTER_WAVE_HARD_BATCHES = "scheduler_wave_hard_batches_total"
 COUNTER_WAVE_STRATIFIED_BATCHES = "scheduler_wave_stratified_batches_total"
+COUNTER_WAVE_ANTI_BATCHES = "scheduler_wave_anti_affinity_batches_total"
 # a deferred pod re-enters a wave within seconds (readd, or 1-10 s of
 # backoff): an entry untouched this long belongs to a pod that is gone
 _DEFERRED_FORGET_S = 120.0
@@ -891,7 +892,7 @@ class Scheduler:
         with self.cache.lock:
             eb = self._tpl_cache.encode([warm_pod], pad_to=small)
             ptab = self._pair_table(eb)
-            n_waves, batch_has_hard, stratify = self._batch_waves(eb)
+            n_waves, batch_has_hard, stratify, _ = self._batch_waves(eb)
             n_waves = min(n_waves, 2)  # the small no-hard bucket's count
             snap = self.cache.encoder.flush()
             enc_cfg = self.cache.encoder.cfg
@@ -1097,13 +1098,16 @@ class Scheduler:
         """The most pods the next batch takes from the queue. A launch
         with a hard pair commits at most one pod per (pair, domain) an
         iteration however many pods it carries (48 over three zones in
-        its 16 iterations; a template's 32 candidate columns bound a
-        hostname pair sooner), and every pod it carries is encoded,
-        read back and, if it was deferred, re-queued: at the 4,096 bucket
-        such a launch takes 970 ms and a backlog that fills it binds 0-2
-        pods/s (my chip runs, PR 34). So while the launches carry hard
-        pairs a batch takes four pods an iteration (the rest waits in
-        the queue, where it costs nothing), and
+        its 16 iterations; a hostname pair's domain is the node, so a
+        template's 32 candidate columns are 32 domains and such a launch
+        commits 32 of 64, with 4,000, 2,500 or 300 of 5,000 nodes free
+        alike: my chip run, PR 36, the kernel alone; in 2 of its
+        iterations: tests/test_wave_commit_order.py), and every pod it
+        carries is encoded, read back and, if it was deferred,
+        re-queued: at the 4,096 bucket such a launch takes 970 ms and a
+        backlog that fills it binds 0-2 pods/s (my chip runs, PR 34). So
+        while the launches carry hard pairs a batch takes four pods an
+        iteration (the rest waits in the queue, where it costs nothing), and
         _schedule_batch_wave_once returns the tail of a hard batch that
         was popped before its kind was known. The 64 were sized when a
         zone spread committed 3 pods a launch; with its candidate
@@ -1856,7 +1860,7 @@ class Scheduler:
         return table
 
     def _batch_waves(self, eb) -> tuple:
-        """(wave count, has_hard, stratify) for THIS batch, from the
+        """(wave count, has_hard, stratify, anti) for THIS batch, from the
         templates actually present in it (NOT the whole accumulated
         template cache — one historical hard-pair template must not pin
         every later soft-only burst to the full wave count). No-hard
@@ -1874,12 +1878,20 @@ class Scheduler:
         skew then without one when, two iterations later, it is the only
         zone that may take a pod, and such a launch committed 3 pods of
         64 in 2 of its 16 iterations (ledger, PR 34,
-        `perf5k-topologyspread.backlog`)."""
+        `perf5k-topologyspread.backlog`). A hostname pair (required
+        anti-affinity on `kubernetes.io/hostname`) is not stratified: its
+        domains are the nodes, `top_k`'s 32 columns are 32 of them, and a
+        launch of 64 commits those 32 (my chip run, PR 36:
+        `scripts/profile_kernel.py --occupied 1000,2500,4700`, 32 placed
+        of 64 at each fill, 4.935 ms a launch at the 64 bucket). `anti`
+        says that such a term, the batch's own or a resident's that a
+        present template matches, is what made the batch hard
+        (`scheduler_wave_anti_affinity_batches_total`)."""
         enc = self.cache.encoder
         b = eb.tpl_np
         present = np.unique(eb.pod_tpl_np[eb.pod_tpl_np >= 0])
         if present.size == 0:
-            return min(2, self.cfg.wave_n_waves), False, False
+            return min(2, self.cfg.wave_n_waves), False, False, False
         anti_kinds = [
             tid
             for tid in range(len(enc.eterm_vocab))
@@ -1888,17 +1900,14 @@ class Scheduler:
         hard_spread = bool(
             np.any((b.spread_key[present] >= 0) & b.spread_hard[present])
         )
-        has_hard = (
-            hard_spread
-            or bool(np.any(b.panti_sid[present] >= 0))
-            or any(
-                bool(np.any(b.match_eterm[present, tid]))
-                for tid in anti_kinds
-            )
+        # a present template's own required anti-affinity term, or one
+        # of a resident's that a present template matches
+        anti = bool(np.any(b.panti_sid[present] >= 0)) or any(
+            bool(np.any(b.match_eterm[present, tid])) for tid in anti_kinds
         )
-        if has_hard:
-            return self.cfg.wave_n_waves, True, hard_spread
-        return min(2, self.cfg.wave_n_waves), False, False
+        if hard_spread or anti:
+            return self.cfg.wave_n_waves, True, hard_spread, anti
+        return min(2, self.cfg.wave_n_waves), False, False, False
 
     def _wave_variant(
         self, enc_cfg, m_cand: int, n_waves: int, batch_has_hard: bool,
@@ -2303,7 +2312,9 @@ class Scheduler:
                 t_f0 = None
                 eb = self._tpl_cache.encode([pi.pod for pi in pis], pad_to=pad)
                 ptab = self._pair_table(eb)
-                n_waves, batch_has_hard, stratify = self._batch_waves(eb)
+                n_waves, batch_has_hard, stratify, anti = self._batch_waves(
+                    eb
+                )
                 self._hard_backlog = batch_has_hard
                 limit = self._batch_limit()
                 if batch_has_hard and len(pis) > limit:
@@ -2380,7 +2391,7 @@ class Scheduler:
         # slow wave explains its N slow pods in one lookup
         wave_tid = tracer.start(
             "wave", f"wave/{len(pis)}pods", t0=t_start, pods=len(pis),
-            hard=batch_has_hard, stratified=stratify,
+            hard=batch_has_hard, stratified=stratify, anti=anti,
         )
         tracer.add_span(wave_tid, "encode", t_start, t_launch0)
         tracer.add_span(wave_tid, "launch", t_launch0, t_launched)
@@ -2404,6 +2415,8 @@ class Scheduler:
             metrics.inc(COUNTER_WAVE_HARD_BATCHES)
         if stratify:
             metrics.inc(COUNTER_WAVE_STRATIFIED_BATCHES)
+        if anti:
+            metrics.inc(COUNTER_WAVE_ANTI_BATCHES)
         if len(pis) > self._wave_batch_pods_peak:
             self._wave_batch_pods_peak = len(pis)
             metrics.set_gauge(GAUGE_WAVE_BATCH_PODS_MAX, float(len(pis)))

@@ -5,7 +5,8 @@ pair-axis sizes.
 
     python scripts/profile_kernel.py [--workload SchedulingBasic]
         [--nodes 5000] [--pods 256] [--m 32] [--waves 0,2] [--pairs 32,4,1]
-        [--zones 3] [--unlevel 0,1] [--platform tpu] [--trace-dir DIR]
+        [--zones 3] [--unlevel 0,1] [--occupied 1000,2500,4700]
+        [--platform tpu] [--trace-dir DIR]
 
 The kernel is the variant the scheduler serves for that batch on this
 backend (`Scheduler._batch_waves` and `_wave_variant`, has_pinned=False: on
@@ -13,7 +14,11 @@ a TPU the Pallas fit mask and the per-wave score refresh, on the CPU
 neither; for a batch with a hard spread template the stratified candidate
 columns), at each wave count asked for. --unlevel K starts from a snapshot
 in which K pods of the measured kind already sit on the first node (zone
-0): a hard zone spread is then over its skew there. The n_waves
+0): a hard zone spread is then over its skew there. --occupied K starts
+from one in which K nodes (a seeded choice) hold one pod of the measured
+kind each: how full a one-pod-a-node deployment (required hostname
+anti-affinity, `--workload SchedulingPodAntiAffinity`) is when the launch
+begins, so that 5,000 - K nodes are feasible. The n_waves
 sweep isolates Stage A (n_waves=0 compiles the kernel with an empty
 fori_loop) from the per-wave cost; the P sweep shows how much of the cycle
 is batch-size-invariant (the [TPL, N] planes) vs per-pod; the --pairs sweep
@@ -50,7 +55,7 @@ import numpy as np  # noqa: E402
 
 
 def build_inputs(workload: str, n_nodes: int, n_pods: int, m_cand: int,
-                 zones: int = 0, unlevel: int = 0):
+                 zones: int = 0, unlevel: int = 0, occupied: int = 0):
     import dataclasses
 
     from kubernetes_tpu.client.apiserver import APIServer
@@ -81,11 +86,16 @@ def build_inputs(workload: str, n_nodes: int, n_pods: int, m_cand: int,
             p = factory(n_pods + i)
             p.spec.node_name = nodes[0].metadata.name
             sched.cache.add_pod(p)
+        rows = np.random.default_rng(0).permutation(cfg.num_nodes)[:occupied]
+        for i, row in enumerate(rows):
+            p = factory(n_pods + unlevel + i)
+            p.spec.node_name = nodes[int(row)].metadata.name
+            sched.cache.add_pod(p)
         pods = [factory(i) for i in range(n_pods)]
         with sched.cache.lock:
             eb = sched._tpl_cache.encode(pods, pad_to=n_pods)
             ptab = sched._pair_table(eb)
-            _n_waves, has_hard, stratify = sched._batch_waves(eb)
+            _n_waves, has_hard, stratify, _anti = sched._batch_waves(eb)
             snap = sched.cache.encoder.flush()
             enc_cfg = sched.cache.encoder.cfg
         weights = np.asarray(sched._weights)
@@ -193,16 +203,23 @@ def main() -> int:
     ap.add_argument("--unlevel", default="0",
                     help="residents of the measured kind on the first "
                     "node, one snapshot each, e.g. 0,1")
+    ap.add_argument("--occupied", default="0",
+                    help="nodes (a seeded choice) that already hold one pod "
+                    "of the measured kind each, one snapshot each, e.g. "
+                    "1000,2500,4700: the fill of a one-pod-a-node "
+                    "deployment (SchedulingPodAntiAffinity) at the launch")
     ap.add_argument("--platform", default="cpu")
     ap.add_argument("--trace-dir", default="")
     args = ap.parse_args()
 
     dev = jax.devices()[0]
     print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}")
-    for P, unlevel in [(int(x), int(u)) for x in args.pods.split(",")
-                       for u in args.unlevel.split(",")]:
+    for P, unlevel, occupied in [
+            (int(x), int(u), int(o)) for x in args.pods.split(",")
+            for u in args.unlevel.split(",") for o in args.occupied.split(",")]:
         snap, eb, built, variant, weights = build_inputs(
-            args.workload, args.nodes, P, args.m, args.zones, unlevel
+            args.workload, args.nodes, P, args.m, args.zones, unlevel,
+            occupied,
         )
         TPL = int(eb.batch.tpl.valid.shape[0])
         real = int((np.asarray(built.col) >= 0).sum())
@@ -210,7 +227,7 @@ def main() -> int:
             int(built.col.shape[0])
         ]
         print(f"{args.workload} P={P} nodes={args.nodes} unlevel={unlevel} "
-              f"TPL={TPL} real_pairs={real} "
+              f"occupied={occupied} TPL={TPL} real_pairs={real} "
               f"J_built={int(built.col.shape[0])} variant={variant(2)}")
         for J in sizes:
             ptab = resize_pairs(built, J)
@@ -218,7 +235,7 @@ def main() -> int:
                 best, med, cs, placed, device = time_kernel(
                     snap, eb, ptab, variant(w), weights,
                     trace_dir=(os.path.join(args.trace_dir,
-                                            f"P{P}-u{unlevel}-J{J}-w{w}")
+                                            f"P{P}-u{unlevel}-o{occupied}-J{J}-w{w}")
                                if args.trace_dir else None),
                 )
                 cut = " (TRUNCATED: a timing, not a schedule)" if J < real else ""
