@@ -40,6 +40,7 @@ GAUGE_DISK_STATE = "store_disk_state"
 GAUGE_DISK_CORRUPT = "store_disk_corrupt"
 COUNTER_PRESSURE_ENTRIES = "store_disk_pressure_entries_total"
 COUNTER_COMPACT_FAILURES = "wal_compaction_failures_total"
+COUNTER_COMPACTIONS = "wal_compactions_total"
 
 
 class NotFound(KeyError):
@@ -342,33 +343,42 @@ class APIServer:
             and not self._compacting.is_set()
             and time.monotonic() >= self._compact_backoff_until
         ):
-            # compaction runs OFF the mutation path: serializing + fsyncing
-            # the whole store under the server lock would stall every API
-            # call for seconds at kubemark scale (the reference compacts in
-            # a background goroutine for the same reason)
+            # compaction runs OFF the mutation path, and off this
+            # interpreter: see _compact_async
             self._compacting.set()
             threading.Thread(
                 target=self._compact_async, daemon=True, name="wal-compact"
             ).start()
 
     def _compact_async(self) -> None:
+        """One compaction, from the WAL's own files: note where the log
+        stands (`cut`), have a child process fold the snapshot on disk +
+        the log up to there into the next snapshot (`fold`), publish it
+        and keep the log's bytes past the cut (`publish`). The `store`
+        lock is never taken and no object is copied, encoded or parsed
+        here: this process is one core under the GIL, and a compaction
+        from memory cost it ~5 s at 30,000 objects, ~2 s of them a full
+        stop (the copy under the `store` lock that this comment used to
+        call "cheap", 1.5 s, and the log's re-parse under the wal lock).
+        What writers still wait for is the publish's hold of the wal
+        lock: `store_background_pass_seconds{task="wal_compact_publish"}`.
+        Only where the fold finds the files damaged is memory the truth:
+        then, once, the old way (`_compact_from_memory`), which rewrites
+        both files whole."""
+        from ..runtime.wal import LogDamaged
+
         try:
-            t0 = time.monotonic()
-            with self._lock:  # cheap structural copies only under the lock
-                rv = self._rv
-                objects = {
-                    kind: [copy.deepcopy(o) for o in store.values()]
-                    for kind, store in self._objects.items()
-                }
-            # the locked part stalls every API call for its length:
-            # observed after release, and kept for /debug/traces?stalls=1
-            dt = time.monotonic() - t0
-            metrics.observe(
-                "store_background_pass_seconds", dt,
-                {"task": "wal_compact_copy"},
-            )
-            note_pass("wal_compact_copy", t0, dt)
-            self._wal.write_snapshot(rv, objects)
+            try:
+                done, how = self._compact_from_files(), "files"
+            except LogDamaged as e:
+                logger.error(
+                    "WAL files damaged before the compaction's cut (%s): "
+                    "compacting from memory", e,
+                )
+                self._compact_from_memory()
+                done, how = True, "memory"
+            if done:
+                metrics.inc(COUNTER_COMPACTIONS, {"how": how})
             self._compact_failures = 0
         except OSError:
             # failed compaction must never wedge the append path (the WAL
@@ -386,6 +396,48 @@ class APIServer:
             )
         finally:
             self._compacting.clear()
+
+    def _compact_from_files(self) -> bool:
+        """False where nothing was published: the sink closed or poisoned,
+        or the log was rewritten under the fold (its owner's business)."""
+        t0 = time.monotonic()
+        cut = self._wal.cut()
+        if cut is None:
+            return False
+        self._wal.fold(cut)
+        t1 = time.monotonic()
+        published = self._wal.publish(cut)
+        t2 = time.monotonic()
+        # the publish holds the wal lock, which every write takes under
+        # the `store` lock: a stall of its length (the wait for the lock
+        # included), observed after release and kept for
+        # /debug/traces?stalls=1
+        metrics.observe(
+            "store_background_pass_seconds", t2 - t1,
+            {"task": "wal_compact_publish"},
+        )
+        note_pass("wal_compact_publish", t1, t2 - t1)
+        metrics.observe("wal_compaction_seconds", t1 - t0, {"phase": "fold"})
+        metrics.observe("wal_compaction_seconds", t2 - t1, {"phase": "publish"})
+        return published
+
+    def _compact_from_memory(self) -> None:
+        """The fallback: snapshot the live objects. The copy holds the
+        `store` lock for its length (1.46-1.66 s at 30,000 objects)."""
+        t0 = time.monotonic()
+        with self._lock:
+            rv = self._rv
+            objects = {
+                kind: [copy.deepcopy(o) for o in store.values()]
+                for kind, store in self._objects.items()
+            }
+        dt = time.monotonic() - t0
+        metrics.observe(
+            "store_background_pass_seconds", dt,
+            {"task": "wal_compact_copy"},
+        )
+        note_pass("wal_compact_copy", t0, dt)
+        self._wal.write_snapshot(rv, objects)
 
     def backup_state(self) -> dict:
         """One-lock-consistent online backup image: the full object state

@@ -8,6 +8,33 @@ acknowledged only after its record is on disk; recovery = load latest
 snapshot + replay the tail. Compaction writes a full snapshot and truncates
 the log (etcd's snapshot/compact cycle).
 
+The live store compacts from these files, not from memory (cut → fold →
+publish; runtime/walfold.py): the snapshot on disk + the log up to a cut
+ARE the acknowledged state at the cut, so a child process folds them and
+this process only renames. Every point a crash can land on recovers every
+acknowledged record:
+
+  crash after         on disk                              recovery reads
+  cut (noted only)    old snapshot, whole log              as if nothing began
+  fold, mid-way       the same + a partial `.snapshot.json.tmp`
+                                                           the same; the .tmp is
+                                                           never read, swept at
+                                                           the next open
+  fold, done          the same + a whole .tmp              the same
+  snapshot replace    NEW snapshot, whole log              the snapshot, then the
+                                                           log's records past its
+                                                           rv (those it covers
+                                                           are skipped by rv)
+  tail `.wal.tmp`     the same + a partial or whole .tmp   the same; swept
+  tail replace        new snapshot, the log past the cut   the snapshot + the tail
+  (sink reopened)
+
+A child whose parent died publishes nothing (only the parent renames). A
+cut whose log was rewritten by someone else meanwhile (write_snapshot: a
+backup restore, a follower's snapshot install) is given up at publish: the
+log's generation changed. write_snapshot, the compaction from memory, keeps
+the same order (snapshot, then log) and the same table from its third row.
+
 Record format v2: one CRC32-framed JSON line per mutation
   K2 <crc32-hex8> {"rv": N, "verb": "create|update|delete", "kind": ..., "obj": {...}}
 The CRC covers the JSON payload bytes (etcd frames WAL records the same
@@ -44,22 +71,30 @@ import errno
 import json
 import logging
 import os
+import subprocess
+import sys
 import threading
 import time
 import weakref
-import zlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..api import serialization
 from ..utils.metrics import metrics
+from . import walfold
+from .walfold import (  # noqa: F401  (the framing's home; re-exported)
+    FRAME_PREFIX,
+    LOG_SUFFIX,
+    SNAPSHOT_SUFFIX,
+    frame_record,
+    parse_wal_line,
+)
 
 logger = logging.getLogger("kubernetes_tpu.wal")
 
-SNAPSHOT_SUFFIX = ".snapshot.json"
-LOG_SUFFIX = ".wal"
-
-# v2 frame: "K2 " + 8 hex chars of crc32(payload) + " " + payload
-FRAME_PREFIX = "K2 "
+# the directory `python -m kubernetes_tpu.runtime.walfold` is started in
+_PACKAGE_PARENT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
 
 # recovery classification + repair (the four disk failure modes)
 COUNTER_TORN_TAIL = "wal_torn_tail_truncations_total"
@@ -109,42 +144,29 @@ class DiskFull(OSError):
     Retryable once disk space frees."""
 
 
-def frame_record(payload: str) -> str:
-    """CRC32-frame one JSON payload into a v2 WAL line."""
-    crc = zlib.crc32(payload.encode("utf-8")) & 0xFFFFFFFF
-    return f"{FRAME_PREFIX}{crc:08x} {payload}\n"
+class LogDamaged(Exception):
+    """The fold found the snapshot or a record before the cut damaged, or
+    the log's prefix does not end at the cut's rv. Memory is the truth
+    then: the owner compacts from it (write_snapshot), which rewrites both
+    files without the damage."""
 
 
-def parse_wal_line(line: str) -> Optional[dict]:
-    """Parse one WAL line (either framing version) or None if damaged.
+class FoldFailed(OSError):
+    """The fold's child process failed for another reason (I/O error, no
+    interpreter, killed, timed out): the compaction is retried later."""
 
-    v2 (`K2 <crc8> <json>`): the CRC must match the payload bytes — a
-    bit-flip inside a string value still parses as JSON, only the CRC
-    catches it. v1 (starts with `{`): plain JSON, best-effort. Anything
-    else is damage."""
-    if line.startswith(FRAME_PREFIX):
-        body = line[len(FRAME_PREFIX):]
-        if len(body) < 10 or body[8] != " ":
-            return None
-        try:
-            want = int(body[:8], 16)
-        except ValueError:
-            return None
-        payload = body[9:]
-        if zlib.crc32(payload.encode("utf-8")) & 0xFFFFFFFF != want:
-            return None
-        try:
-            rec = json.loads(payload)
-        except json.JSONDecodeError:
-            return None
-        return rec if isinstance(rec, dict) else None
-    if line.startswith("{"):
-        try:
-            rec = json.loads(line)
-        except json.JSONDecodeError:
-            return None
-        return rec if isinstance(rec, dict) else None
-    return None
+
+@dataclasses.dataclass(frozen=True)
+class LogCut:
+    """Where a compaction from the files divides the log: the fold covers
+    `offset` bytes, an acknowledged-record boundary, and publish keeps the
+    rest. `rv` is that of the last record this process appended before it
+    (None: none yet, the fold is not held to it). `generation` names the
+    log the offset belongs to: every rewrite of the log bumps it."""
+
+    offset: int
+    rv: Optional[int]
+    generation: int
 
 
 @dataclasses.dataclass
@@ -221,6 +243,9 @@ class WriteAheadLog:
     # an fsync (or native group-commit wait) slower than this trips the
     # stall watchdog: a dying disk stretches fsyncs long before erroring
     FSYNC_STALL_S = 1.0
+    # a fold reads and writes ~25 MB in ~1 s of another core; one that
+    # takes this long hangs on a dying disk
+    FOLD_TIMEOUT_S = 300.0
 
     def __init__(
         self,
@@ -249,6 +274,10 @@ class WriteAheadLog:
         self.allow_native = native
         self._lock = threading.Lock()
         self._since_compact = 0
+        # rewrites of the log so far, and the rv of the last record acked
+        # (what a LogCut is checked against)
+        self._generation = 0
+        self._last_rv: Optional[int] = None
         os.makedirs(os.path.dirname(os.path.abspath(self.log_path)), exist_ok=True)
         self._f = None
         self._native = None  # (lib, handle) when the C++ sink is in use
@@ -458,7 +487,10 @@ class WriteAheadLog:
         appenders') shares one fsync. Returns the seconds of that call
         spent waiting for the fsync (the store's `fsync` commit stage;
         the rest — serialising, enqueue or write — is its `wal_append`)."""
-        return self._append_lines([self._record(*r) for r in records])
+        return self._append_lines(
+            [self._record(*r) for r in records],
+            records[-1][0] if records else None,
+        )
 
     def append_commit(self, rv: int, commit: int, term: int, event: str) -> None:
         """Durably log a commit-index epoch transition (consensus mode:
@@ -484,9 +516,11 @@ class WriteAheadLog:
         """Python-sink fsync seam (patched by testing/diskfaults.py)."""
         os.fsync(self._f.fileno())
 
-    def _append_lines(self, lines: List[str]) -> float:
+    def _append_lines(self, lines: List[str], rv: Optional[int] = None) -> float:
         """Returns the seconds spent in the fsync wait (0.0 with fsync
-        off). Every series is observed after the wal lock is released."""
+        off). Every series is observed after the wal lock is released.
+        `rv`: that of the last data record among `lines` (a commit record
+        shares its rv with one and passes None)."""
         if not lines:
             return 0.0
         with self._lock:
@@ -530,6 +564,8 @@ class WriteAheadLog:
                 self._good_offset = self._f.tell()
             self._records_total += len(lines)
             self._since_compact += len(lines)
+            if rv is not None:
+                self._last_rv = rv
             if _DEBUG:
                 rvs = [(parse_wal_line(line.rstrip("\n")) or {}).get("rv") for line in lines]
                 _trace(self.path, f"append acked rvs={rvs} native={self._native is not None}")
@@ -599,13 +635,22 @@ class WriteAheadLog:
             return self._since_compact >= self.compact_every
 
     def write_snapshot(self, rv: int, objects: Dict[str, List[Any]]) -> None:
-        """Publish a snapshot at `rv` and drop log records it covers.
-        Serialization happens OUTSIDE the wal lock (and the caller runs this
-        off the store's mutation path — see APIServer._compact_async);
-        appends racing the compaction are preserved by rewriting, not
-        truncating, the log tail. I/O errors propagate to the caller (which
-        counts and backs off) — with the sink reopened first, so a failed
-        compaction never wedges the append path."""
+        """Publish a snapshot of `objects` at `rv`, taken from MEMORY, and
+        drop the log records it covers. For owners with no log to fold
+        (backup restore, a follower's snapshot install and compaction) and
+        for the live store's fallback when its files are damaged
+        (APIServer._compact_async: the store compacts from its files,
+        cut → fold → publish below). Encoding and dumping ~30,000 objects
+        here costs the caller's interpreter ~2 s (`json.dump` to a file
+        takes the pure-Python encoder), and the log is re-read and
+        re-parsed line by line UNDER the wal lock (~0.5 s at 45,000
+        records, every append waiting): the file path does neither.
+        Appends racing the compaction are preserved by rewriting, not
+        truncating, the log tail; damaged lines are dropped with the
+        records the snapshot covers, which is what heals a log. I/O
+        errors propagate to the caller (which counts and backs off) — with
+        the sink reopened first, so a failed compaction never wedges the
+        append path."""
         snap = {
             "rv": rv,
             "objects": {
@@ -622,11 +667,21 @@ class WriteAheadLog:
                 f.flush()
                 os.fsync(f.fileno())
         except OSError:
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
+            self._unlink_quietly(tmp)
             raise
+
+        def newer_than_snapshot() -> bytes:
+            keep: List[str] = []
+            with open(self.log_path, encoding="utf-8") as f:
+                for line in f:
+                    line = line.rstrip("\n")
+                    if not line:
+                        continue
+                    rec = parse_wal_line(line)
+                    if rec is not None and rec.get("rv", 0) > rv:
+                        keep.append(line + "\n")
+            return "".join(keep).encode("utf-8")
+
         with self._lock:
             if self._closed:
                 return  # shut down mid-compaction: don't resurrect the sink
@@ -634,48 +689,122 @@ class WriteAheadLog:
                 return  # poisoned sink: no log rewrite, no reopen
             os.replace(tmp, self.snap_path)  # atomic publish
             _trace(self.path, f"snapshot published rv={rv}")
-            # rewrite the log keeping only records newer than the snapshot
-            # (the sink is closed around the rewrite and reopened after —
-            # appends are excluded by the wal lock for the duration)
-            self._close_sink()
-            log_tmp = self.log_path + ".tmp"
+            self._rewrite_log_locked(newer_than_snapshot)
+
+    # -- compaction from the files (the live store's) --------------------------
+    #
+    # cut (here, microseconds) → fold (a child process, runtime/walfold.py)
+    # → publish (here: two renames and the tail's bytes). Nothing of it
+    # reads the store or parses a record in this process.
+
+    def cut(self) -> Optional[LogCut]:
+        """Where the log stands now; None for a closed or poisoned sink.
+        Under the wal lock the file is quiescent (_append_lines returns
+        only after the fsync wait), so its length is an acknowledged-record
+        boundary."""
+        with self._lock:
+            if self._closed or self._failed is not None:
+                return None
+            return LogCut(
+                os.path.getsize(self.log_path), self._last_rv, self._generation
+            )
+
+    def fold(self, cut: LogCut) -> None:
+        """Have a child process write `<path>.snapshot.json.tmp` from the
+        snapshot on disk + the log's first `cut.offset` bytes. Never
+        `os.fork()` without exec: the server has 64+ threads and a native
+        committer. Raises LogDamaged (compact from memory instead) or
+        OSError (retry later)."""
+        argv = [
+            sys.executable, "-m", walfold.__name__,
+            os.path.abspath(self.path), str(cut.offset),
+        ]
+        if cut.rv is not None:
+            argv.append(str(cut.rv))
+        _trace(self.path, f"fold start offset={cut.offset} rv={cut.rv}")
+        try:
+            proc = subprocess.run(
+                argv, cwd=_PACKAGE_PARENT, stdin=subprocess.DEVNULL,
+                stdout=subprocess.DEVNULL, stderr=subprocess.PIPE,
+                timeout=self.FOLD_TIMEOUT_S,
+            )
+            rc = proc.returncode
+            said = proc.stderr.decode("utf-8", errors="replace").strip()
+        except subprocess.TimeoutExpired:
+            rc, said = None, f"killed after {self.FOLD_TIMEOUT_S:.0f} s"
+        if rc == 0:
+            return
+        if rc == walfold.EXIT_DAMAGED:
+            raise LogDamaged(said)  # found before anything was written
+        self._unlink_quietly(self.snap_path + ".tmp")
+        raise FoldFailed(f"WAL fold exited {rc}: {said[-2000:]}")
+
+    def publish(self, cut: LogCut) -> bool:
+        """Publish the fold's snapshot, then replace the log by its bytes
+        past the cut, copied as bytes: no line is parsed, so the wal lock
+        is held for the tail (~1 MB of a 1-2 s fold), not for the log.
+        Snapshot BEFORE log, as write_snapshot: every crash point
+        recovers. False, and nothing published, where the sink closed or
+        poisoned meanwhile or the log was rewritten since the cut (a
+        backup restore, a follower's snapshot install): the offset then
+        belongs to a log that is gone."""
+        tmp = self.snap_path + ".tmp"
+
+        def past_the_cut() -> bytes:
+            with open(self.log_path, "rb") as f:
+                f.seek(cut.offset)
+                return f.read()
+
+        with self._lock:
+            if (
+                self._closed
+                or self._failed is not None
+                or cut.generation != self._generation
+            ):
+                self._unlink_quietly(tmp)
+                return False
+            os.replace(tmp, self.snap_path)  # atomic publish
+            _trace(self.path, f"folded snapshot published cut={cut}")
+            self._rewrite_log_locked(past_the_cut)
+        return True
+
+    def _rewrite_log_locked(self, keep: Callable[[], bytes]) -> None:
+        """Replace the log by `keep()`, whole lines read from it with the
+        sink closed (appends are excluded by the wal lock for the
+        duration). ATOMIC rotation (tmp + replace): a concurrent recover()
+        must never observe a truncated in-place rewrite — it sees either
+        the old full log or the rewritten tail, both consistent with the
+        published snapshot."""
+        self._close_sink()
+        log_tmp = self.log_path + ".tmp"
+        try:
+            data = keep()
+            with open(log_tmp, "wb") as f:
+                f.write(data)
+                f.flush()
+                os.fsync(f.fileno())
+            os.replace(log_tmp, self.log_path)
+            self._since_compact = data.count(b"\n")
+            self._generation += 1
+        except OSError:
+            self._unlink_quietly(log_tmp)
+            raise
+        finally:
+            # ALWAYS reopen (or poison trying): an exception above used
+            # to leave the sink closed forever — every later append
+            # died and compaction was wedged for the process lifetime
             try:
-                keep: List[str] = []
-                with open(self.log_path, encoding="utf-8") as f:
-                    for line in f:
-                        line = line.rstrip("\n")
-                        if not line:
-                            continue
-                        rec = parse_wal_line(line)
-                        if rec is not None and rec.get("rv", 0) > rv:
-                            keep.append(line)
-                # ATOMIC rotation (tmp + replace): a concurrent recover()
-                # must never observe a truncated in-place rewrite — it sees
-                # either the old full log or the rewritten tail, both
-                # consistent with the published snapshot
-                with open(log_tmp, "w", encoding="utf-8") as f:
-                    for line in keep:
-                        f.write(line + "\n")
-                    f.flush()
-                    os.fsync(f.fileno())
-                os.replace(log_tmp, self.log_path)
-                self._since_compact = len(keep)
-            except OSError:
-                try:
-                    os.unlink(log_tmp)
-                except OSError:
-                    pass
-                raise
-            finally:
-                # ALWAYS reopen (or poison trying): an exception above used
-                # to leave the sink closed forever — every later append
-                # died and compaction was wedged for the process lifetime
-                try:
-                    self._open_sink()
-                except OSError as e:
-                    self._poison_locked(f"sink reopen after compaction failed: {e}")
-            if _DEBUG:
-                _trace(self.path, f"log rewritten keep={len(keep)}")
+                self._open_sink()
+            except OSError as e:
+                self._poison_locked(f"sink reopen after compaction failed: {e}")
+        _trace(self.path, f"log rewritten keep={self._since_compact}")
+
+    @staticmethod
+    def _unlink_quietly(path: str) -> None:
+        try:
+            os.unlink(path)
+        except OSError:
+            pass
 
     def close(self) -> None:
         with self._lock:
@@ -714,9 +843,11 @@ class WriteAheadLog:
         and `corrupt` is set so the caller resyncs from a healthy peer
         instead of silently serving a log with acked records missing.
 
-        Crash-point consistency: the compactor publishes the snapshot
-        (atomic replace) BEFORE rewriting the log, so every on-disk state a
-        crash can leave behind recovers fully. A LIVE writer compacting
+        Crash-point consistency: a compaction, from the files (publish)
+        or from memory (write_snapshot), publishes the snapshot (atomic
+        replace) BEFORE rewriting the log, so every on-disk state a crash
+        can leave behind recovers fully (the module docstring's table; the
+        fold's `.tmp` is never read here). A LIVE writer compacting
         concurrently (tests; split-brain probes) can still interleave our
         two reads — stale snapshot paired with an already-rewritten log
         tail, silently losing the records in between. Detected by

@@ -12,7 +12,7 @@ with one request in flight, as the scheduler has done since PR 28. Two
 scrapes of /metrics and two reads of /proc/<pid>/stat bracket the window.
 
   python scripts/apiserver_saturation.py [--senders 64] [--seconds 20]
-      [--config perf5k-podaffinity]
+      [--config perf5k-podaffinity] [--across-compaction [--after 10]]
 
 Prints one JSON line: pods created and seen bound a second, the child's
 user + sys cores, the store's lock wait per op and its stage means, WAL
@@ -21,6 +21,14 @@ records a fsync, and the binding request as the binder saw it. A child at
 wait is its queue; a child well under a core with a long lock wait is the
 lock. CPU only: a reading aid for PERF.md, not part of the benchmark, and
 no number of it is a device metric.
+
+`--across-compaction` drives the same load until the WAL's 50,000th record
+has made the server compact (`cluster.snapshot.json` appears in its data
+directory) and `--after` seconds more, `--seconds` at most: the line then
+also holds the writes acknowledged in each second of the window (a create,
+or each binding of a binding request), the second in which the snapshot
+appeared, and the longest gap between two acknowledgements with its second.
+A compaction that stops the writers is a hole in that series.
 """
 
 from __future__ import annotations
@@ -56,6 +64,21 @@ def _cpu_seconds(pid: int) -> tuple:
     return int(fields[11]) / tick, int(fields[12]) / tick
 
 
+def _ack_series(acks: list, t0: float, t1: float) -> dict:
+    """Writes acknowledged in each whole second of [t0, t1), and the
+    longest gap between two consecutive acknowledgements in it."""
+    times = sorted(t for t, _ in acks if t0 <= t < t1)
+    per_s = [0] * int(t1 - t0)
+    for t, n in acks:
+        if t0 <= t < t0 + len(per_s):
+            per_s[int(t - t0)] += n
+    gap, at = max(((b - a, a) for a, b in zip(times, times[1:])),
+                  default=(0.0, t0))
+    return {"acked_writes_per_s": per_s,
+            "longest_ack_gap_ms": round(gap * 1e3, 1),
+            "longest_ack_gap_at_s": round(at - t0, 2)}
+
+
 def _rounded(x, digits: int):
     return None if x is None else round(x, digits)
 
@@ -63,9 +86,14 @@ def _rounded(x, digits: int):
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--senders", type=int, default=64)
-    ap.add_argument("--seconds", type=float, default=20.0)
+    ap.add_argument("--seconds", type=float, default=None,
+                    help="20; with --across-compaction at most 180 "
+                    "(50,000 records are ~60 s of this load)")
     ap.add_argument("--config", default="perf5k-podaffinity")
+    ap.add_argument("--across-compaction", action="store_true")
+    ap.add_argument("--after", type=float, default=10.0)
     args = ap.parse_args()
+    seconds = args.seconds or (180.0 if args.across_compaction else 20.0)
 
     with open(os.path.join(ROOT, "benchmark", "configs",
                            args.config + ".json")) as f:
@@ -95,6 +123,7 @@ def main() -> int:
         created: queue.Queue = queue.Queue()
         stop = threading.Event()
         bind_ms, bind_sizes, bind_failed = [], [], [0]
+        acks = []  # (instant, writes acknowledged by that reply)
 
         def sender(i: int) -> None:
             n = 0
@@ -102,6 +131,7 @@ def main() -> int:
                 name = f"s{i}-{n}"
                 if rest.create(pods_path, template.replace(
                         "$NAME", name).encode()):
+                    acks.append((time.monotonic(), 1))
                     created.put(name)
                 n += 1
 
@@ -131,7 +161,9 @@ def main() -> int:
                 ok = status == 200 and all(
                     it.get("status") == "Success"
                     for it in json.loads(reply)["items"])
-                if not ok:
+                if ok:
+                    acks.append((time.monotonic(), len(items)))
+                else:
                     bind_failed[0] += 1
 
         threads = [threading.Thread(target=sender, args=(i,), daemon=True)
@@ -146,7 +178,17 @@ def main() -> int:
         cpu0, t0, bound0 = (_cpu_seconds(api.proc.pid), time.monotonic(),
                             len(watches[0].bound))
         n_bind0 = len(bind_ms)
-        time.sleep(args.seconds)
+        snapshot = os.path.join(work, "wal", "cluster.snapshot.json")
+        snapshot_at = None
+        if args.across_compaction:
+            while (now := time.monotonic() - t0) < seconds:
+                if snapshot_at is None and os.path.exists(snapshot):
+                    snapshot_at = now
+                if snapshot_at is not None and now >= snapshot_at + args.after:
+                    break
+                time.sleep(0.02)
+        else:
+            time.sleep(seconds)
         cpu1, t1, bound1 = (_cpu_seconds(api.proc.pid), time.monotonic(),
                             len(watches[0].bound))
         n_bind1 = len(bind_ms)
@@ -195,6 +237,14 @@ def main() -> int:
             "refused": len(rest.refused) + bind_failed[0],
             "watch_errors": [w.errors for w in watches if w.errors],
         }
+        if args.across_compaction:
+            result.update(_ack_series(acks, t0, t1), snapshot_at_s=_rounded(
+                snapshot_at, 2), wal_records=pair[1].total(
+                    "wal_records_appended_total"),
+                compactions=pair[1].by_label("wal_compactions_total", "how"),
+                background_pass_s={
+                    task: round(v, 3) for task, v in pair[1].by_label(
+                        "store_background_pass_seconds_sum", "task").items()})
         print(json.dumps(result))
         return 0 if not result["refused"] else 1
     finally:

@@ -569,39 +569,6 @@ def test_fsync_stall_watchdog_flags_slow_disk(tmp_path):
 # compaction resilience + recovery-signal satellites
 # ---------------------------------------------------------------------------
 
-def test_compaction_failure_backs_off_then_recovers(tmp_path, monkeypatch):
-    prefix = str(tmp_path / "compact")
-    wal = make_wal(tmp_path, "compact", compact_every=3)
-    srv = APIServer(wal=wal)
-    real_snapshot = wal.write_snapshot
-    fails0 = metrics.counter("wal_compaction_failures_total")
-
-    def exploding_snapshot(rv, objects):
-        raise OSError("simulated snapshot I/O error")
-
-    monkeypatch.setattr(wal, "write_snapshot", exploding_snapshot)
-    for i in range(4):
-        srv.create("pods", make_pod(f"p{i}"))
-    # the failed compaction must clear the in-flight flag (no wedge)...
-    assert wait_until(lambda: not srv._compacting.is_set(), 10)
-    assert wait_until(
-        lambda: metrics.counter("wal_compaction_failures_total") > fails0, 10
-    )
-    assert srv._compact_backoff_until > time.monotonic(), (
-        "failure must arm backoff, not retry hot"
-    )
-    # ...and the append path kept working throughout
-    srv.create("pods", make_pod("during-backoff"))
-    # past the backoff with a healthy disk, the next write compacts
-    monkeypatch.setattr(wal, "write_snapshot", real_snapshot)
-    srv._compact_backoff_until = 0.0
-    srv.create("pods", make_pod("trigger"))
-    assert wait_until(
-        lambda: os.path.exists(prefix + ".snapshot.json"), 10
-    ), "compaction never recovered after the backoff"
-    wal.close()
-
-
 def test_orphaned_compaction_tmp_files_swept_at_open(tmp_path):
     prefix = str(tmp_path / "orphans")
     for suffix in (".snapshot.json.tmp", ".wal.tmp"):
