@@ -908,17 +908,22 @@ class RESTClient:
         ctype = resp.headers.get("Content-Type") or ""
         try:
             if watchcodec.WATCH_CONTENT_TYPE in ctype:
+                committed = 0.0  # a 'T' frame's instant, for the next event
                 while not w.stopped:
                     frame = watchcodec.read_frame(resp)
                     if frame is None:
                         return last_rv, "eof"
                     ev_type, rv, obj = frame
+                    if ev_type == watchcodec.COMMITTED:
+                        committed = obj
+                        continue
                     if ev_type == BOOKMARK:
                         w.push(Event(BOOKMARK, bookmark_object(kind, rv), rv))
                     else:
                         if isinstance(obj, dict):
                             obj = codec.decode(kind, obj)  # 'J' fallback frame
-                        w.push(Event(ev_type, obj, rv))
+                        w.push(Event(ev_type, obj, rv, committed=committed))
+                    committed = 0.0
                     last_rv = max(last_rv, rv)
                 return last_rv, "stopped"
             for line in resp:
@@ -944,7 +949,8 @@ class RESTClient:
                     continue
                 obj = codec.decode(kind, msg["object"])
                 rv = obj.metadata.resource_version
-                w.push(Event(msg["type"], obj, rv))
+                w.push(Event(msg["type"], obj, rv,
+                             committed=msg.get("committed", 0.0)))
                 last_rv = max(last_rv, rv)
             return last_rv, "eof"
         except ValueError:

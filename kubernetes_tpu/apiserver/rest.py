@@ -1020,15 +1020,19 @@ class _Handler(BaseHTTPRequestHandler):
             last_write = _time.monotonic()
 
         def write_event(ev, live: bool) -> None:
+            # a create's commit instant rides beside its event (the
+            # scheduler's commit -> queue admit leg): a 'T' frame in the
+            # same chunk, or a "committed" key on the JSON line
             if binary:
-                write_chunk(watchcodec.event_frame(ev))
+                frame = watchcodec.event_frame(ev)
+                if ev.committed:
+                    frame = watchcodec.committed_frame(ev.committed) + frame
+                write_chunk(frame)
             else:
-                write_chunk(
-                    json.dumps(
-                        {"type": ev.type, "object": codec.encode(ev.object)}
-                    ).encode()
-                    + b"\n"
-                )
+                msg = {"type": ev.type, "object": codec.encode(ev.object)}
+                if ev.committed:
+                    msg["committed"] = ev.committed
+                write_chunk(json.dumps(msg).encode() + b"\n")
             if live and ev.ts:
                 # Event.ts (the watch cache's fan-out enqueue) -> this
                 # event's bytes handed to the socket: queue wait, encode

@@ -23,6 +23,11 @@ Frame layout (all integers big-endian):
     frame    := type(1) length(4) payload(length)
     type 'A' | 'M' | 'D'  object event; payload = protocodec envelope
     type 'B'              bookmark; payload = rv as 8-byte unsigned
+    type 'T'              the commit instant of the NEXT frame's event
+                          (``Event.committed``: a create's, wall clock);
+                          payload = seconds as an 8-byte IEEE double.
+                          Written per stream in the same chunk, never
+                          memoized, so the shared frame stays the event's
     type 'J'              JSON fallback event (custom resources — the
                           protocodec cannot encode Unstructured, same
                           restriction as the reference); payload is the
@@ -44,9 +49,12 @@ from ..runtime.watch import ADDED, BOOKMARK, DELETED, MODIFIED
 
 # offered by clients in Accept, answered by speakers in Content-Type
 WATCH_CONTENT_TYPE = "application/vnd.kubernetes-tpu.watchstream"
+# read_frame's type for a 'T' frame (not an event: it annotates the next)
+COMMITTED = "COMMITTED"
 
 _HEADER = struct.Struct(">cI")
 _RV = struct.Struct(">Q")
+_WALL = struct.Struct(">d")
 
 _TYPE_TO_CODE = {ADDED: b"A", MODIFIED: b"M", DELETED: b"D"}
 _CODE_TO_TYPE = {b"A": ADDED, b"M": MODIFIED, b"D": DELETED}
@@ -67,6 +75,12 @@ def bookmark_frame(rv: int) -> bytes:
     """Bookmarks are per-stream (the idle heartbeat advertises each
     stream's own last-written rv) — never memoized, always cheap."""
     return _frame(b"B", _RV.pack(rv))
+
+
+def committed_frame(wall: float) -> bytes:
+    """The 'T' frame a stream writes before an event whose
+    ``Event.committed`` is set."""
+    return _frame(b"T", _WALL.pack(wall))
 
 
 def event_frame(ev: Any) -> bytes:
@@ -100,7 +114,8 @@ def read_frame(fp) -> Optional[Tuple[str, int, Any]]:
     Returns (event_type, rv, object) — object is None for bookmarks (rv
     carries the payload), a DECODED typed object for binary frames, and
     a JSON-ready dict for 'J' fallback frames (the caller resolves the
-    kind, exactly like the legacy JSON line pump). Returns None on a
+    kind, exactly like the legacy JSON line pump); (COMMITTED, 0, wall
+    seconds) for a 'T' frame, which belongs to the next. Returns None on a
     clean EOF at a frame boundary; a truncated frame raises ValueError
     (the stream died mid-frame — a resume, not an EOF).
     """
@@ -115,6 +130,8 @@ def read_frame(fp) -> Optional[Tuple[str, int, Any]]:
         raise ValueError("truncated watch frame payload")
     if code == b"B":
         return BOOKMARK, _RV.unpack(payload)[0], None
+    if code == b"T":
+        return COMMITTED, 0, _WALL.unpack(payload)[0]
     if code == b"J":
         msg = json.loads(payload)
         obj = msg.get("object") or {}

@@ -571,7 +571,8 @@ class APIServer:
             t_g = time.monotonic()
             self._notify(
                 kind,
-                Event(ADDED, copy.deepcopy(stored), stored.metadata.resource_version),
+                Event(ADDED, copy.deepcopy(stored),
+                      stored.metadata.resource_version, committed=time.time()),
             )
             t_n = time.monotonic()
             out = copy.deepcopy(stored)

@@ -102,6 +102,11 @@ class SharedInformer:
         # evicted events past this position — forces the relist.
         self.last_resource_version = 0
         self._resume = False  # True: skip the list, watch from last rv
+        # the store's commit instant (wall) of the ADDED event whose
+        # handlers run right now (Event.committed): read by an add
+        # handler on this informer's thread; 0.0 during a list's replay,
+        # an update's or a delete's handlers — no create is being delivered
+        self.event_committed = 0.0
 
     def add_handler(
         self,
@@ -259,8 +264,12 @@ class SharedInformer:
                 key = ev.object.metadata.key
                 if ev.type == ADDED:
                     self.indexer.add(ev.object)
-                    for h in self._handlers:
-                        h.on_add(ev.object)
+                    self.event_committed = ev.committed
+                    try:
+                        for h in self._handlers:
+                            h.on_add(ev.object)
+                    finally:
+                        self.event_committed = 0.0
                 elif ev.type == MODIFIED:
                     old = self.indexer.get(key)
                     self.indexer.update(ev.object)

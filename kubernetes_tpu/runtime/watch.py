@@ -31,6 +31,12 @@ class Event:
     # cache's dispatch loop; lets consumers measure delivery latency
     # without a side channel. 0.0 for events from the raw store.
     ts: float = 0.0
+    # wall clock of the store's commit of a CREATE (APIServer.create,
+    # under the store lock, after the WAL): the start of a new pod's
+    # commit -> queue admit leg, carried over both watch wires. 0.0 for
+    # every other event (updates, deletes, a list's or a cache's replay
+    # of state): the object's creation_timestamp stays the client's
+    committed: float = 0.0
 
 
 COUNTER_OVERFLOW = "watch_queue_overflow_total"

@@ -39,7 +39,7 @@ def add_all_event_handlers(sched: "Scheduler") -> None:
         return not _is_scheduled(pod) and sched.profiles.for_pod(pod) is not None
 
     pods.add_handler(
-        on_add=lambda p: _on_pending_add(sched, p),
+        on_add=lambda p: _on_pending_add(sched, p, pods.event_committed),
         on_update=lambda old, new: _on_pending_update(sched, old, new),
         on_delete=lambda p: sched.queue.delete(p),
         filter_fn=responsible,
@@ -83,13 +83,15 @@ def _on_scheduled_delete(sched, pod):
     sched.queue.move_all_to_active_or_backoff(qevents.ASSIGNED_POD_DELETE)
 
 
-def _on_pending_add(sched, pod):
+def _on_pending_add(sched, pod, committed=0.0):
     # skip pods this scheduler has already assumed (skipPodUpdate,
     # eventhandlers.go: the optimistic cache owns them now)
     if sched.cache.is_assumed(pod.metadata.key):
         return
     if pod.metadata.deletion_timestamp is None:
-        sched.queue.add(pod)
+        # committed: the store's commit of this pod's create, when the
+        # watch delivered it (a first admission); 0.0 from a relist
+        sched.queue.add(pod, committed=committed)
 
 
 def _on_pending_update(sched, old, new):

@@ -10,6 +10,10 @@ plus the nominated-pods map for preemption.
 TPU addition: `pop_batch(max_n, window)` pops up to a device batch of pods in
 one call (the batch former of SURVEY.md §7 stage 4) — the reference pops one
 pod per cycle; the device path amortizes one kernel launch over the batch.
+
+The waiting population — activeQ plus the batch `pop_batch` is forming —
+is integrated over time where it changes (`waiting()`): the scheduler's
+PhaseTracker credits the pod-seconds to the loop phase that held them.
 """
 
 from __future__ import annotations
@@ -55,6 +59,7 @@ class PriorityQueue:
         pod_initial_backoff: float = 1.0,
         pod_max_backoff: float = 10.0,
         unschedulable_timeout: float = 60.0,
+        clock: Callable[[], float] = time.monotonic,
     ):
         # named for the lock-order watchdog + lockset sanitizer
         # (testing/lockgraph.py); _cond shares the SAME lock, so both
@@ -78,6 +83,14 @@ class PriorityQueue:
         self._stop = threading.Event()
         self._threads: List[threading.Thread] = []
         self.moves = 0  # MoveAllToActiveOrBackoffQueue invocations (metrics)
+        # the waiting population (activeQ + pods pop_batch has taken but
+        # not yet returned), integrated: (pod-seconds up to `at`, pods
+        # since `at`, at, pods pop_batch has handed out). Rewritten whole
+        # under the queue lock where the population changes, read whole
+        # without it (waiting())
+        self._clock = clock
+        self._forming = 0
+        self._wait = (0.0, 0, clock(), 0)
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -112,26 +125,47 @@ class PriorityQueue:
         with self._cond:
             self._cond.notify_all()
 
+    # -- the waiting population ---------------------------------------------
+
+    def _note_wait_locked(self, handed: int = 0) -> None:
+        """Caller holds the lock and has just changed activeQ or the
+        forming batch: close the integral at now under the old count."""
+        area, n, at, out = self._wait
+        now = self._clock()
+        self._wait = (
+            area + n * (now - at),
+            len(self._active) + self._forming,
+            now,
+            out + handed,
+        )
+
+    def waiting(self) -> tuple:
+        """(pod-seconds waited up to `at`, pods waiting since `at`, at,
+        pods handed out by pop_batch): one read, no lock. The pod-seconds
+        at a later instant t are ``area + n * (t - at)``."""
+        return self._wait  # graftlint: unguarded(one tuple, rewritten whole under the lock: a read sees one consistent state)
+
     # -- adds ---------------------------------------------------------------
 
-    def add(self, pod: v1.Pod) -> None:
+    def add(self, pod: v1.Pod, committed: float = 0.0) -> None:
+        """`committed`: the store's commit instant (wall) of the pod's
+        create when the watch delivered it — a first admission; the
+        commit -> admit lag is then the trace's ``admit_lag_s`` and one
+        observation of ``scheduler_pod_admit_lag_seconds``. 0.0 (a
+        relist, a direct add) observes nothing."""
         # mint the trace OUTSIDE the queue lock (tracing.ring is a leaf,
-        # but the admit itself needs nothing the lock guards).
-        # admit_lag_s: object creation (wall) -> queue admit — the
-        # store->watch->cacher->informer delivery leg, recorded as an
-        # ATTRIBUTE (wall-clock delta), never mixed into monotonic spans
-        tid = tracer.start(
-            "pod",
-            pod.metadata.key,
-            admit_lag_s=round(
-                max(time.time() - pod.metadata.creation_timestamp, 0.0), 6
-            ),
-        )
+        # but the admit itself needs nothing the lock guards); the lag is
+        # a wall-clock delta, an attribute, never mixed into the spans
+        lag = None
+        if committed and tracer.enabled:
+            lag = round(max(time.time() - committed, 0.0), 6)
+        tid = tracer.start("pod", pod.metadata.key, admit_lag_s=lag)
         with self._cond:
             pi = QueuedPodInfo(pod, trace_id=tid)
             self._active.add(pi)
             self._backoff.delete_by_key(pi.key)
             self._unschedulable.pop(pi.key, None)
+            self._note_wait_locked()
             self._cond.notify()
 
     def readd(self, pi: QueuedPodInfo) -> None:
@@ -143,6 +177,7 @@ class PriorityQueue:
             pi.attempts = max(pi.attempts - 1, 0)
             pi.trace_queued_at = time.monotonic()
             self._active.add(pi)
+            self._note_wait_locked()
             self._cond.notify()
 
     def add_unschedulable_if_not_present(
@@ -207,20 +242,26 @@ class PriorityQueue:
         can ever see "queue empty and scheduler not busy" between a pop
         and the popped batch entering the in-flight pipeline."""
         with self._cond:
-            deadline = None if timeout is None else time.monotonic() + timeout
-            while len(self._active) == 0 and not self._stop.is_set():
-                rem = None if deadline is None else deadline - time.monotonic()
-                if rem is not None and rem <= 0:
-                    return None
-                self._cond.wait(rem if rem is None or rem < 0.1 else 0.1)
-            if self._stop.is_set():
-                return None
-            if on_pop is not None:
-                on_pop()
-            pi = self._active.pop()
+            pi = self._pop_locked(timeout, on_pop)
             if pi is not None:
-                pi.attempts += 1
+                self._note_wait_locked()  # it left the waiting population
             return pi
+
+    def _pop_locked(self, timeout, on_pop) -> Optional[QueuedPodInfo]:
+        deadline = None if timeout is None else time.monotonic() + timeout
+        while len(self._active) == 0 and not self._stop.is_set():
+            rem = None if deadline is None else deadline - time.monotonic()
+            if rem is not None and rem <= 0:
+                return None
+            self._cond.wait(rem if rem is None or rem < 0.1 else 0.1)
+        if self._stop.is_set():
+            return None
+        if on_pop is not None:
+            on_pop()
+        pi = self._active.pop()
+        if pi is not None:
+            pi.attempts += 1
+        return pi
 
     def pop_batch(
         self,
@@ -237,28 +278,35 @@ class PriorityQueue:
         producer is actively producing. Once no new pod has arrived for
         `idle_gap` the batch ships immediately — a lone low-load pod pays
         ~3 ms of former latency instead of the full window, while a burst
-        mid-arrival keeps accumulating up to `window`."""
+        mid-arrival keeps accumulating up to `window`.
+
+        A popped pod stays in the waiting population (it moved from
+        activeQ into the forming batch) until the batch is returned."""
         idle_gap = min(0.003, window) if window > 0 else 0.0
-        first = self.pop(timeout, on_pop=on_first)
-        if first is None:
-            return []
+        with self._cond:
+            first = self._pop_locked(timeout, on_first)
+            if first is None:
+                return []
+            self._forming += 1
         out = [first]
         deadline = time.monotonic() + window
         last_arrival = time.monotonic()
-        while len(out) < max_n:
+        while True:
             with self._cond:
-                pi = self._active.pop()
+                pi = self._active.pop() if len(out) < max_n else None
                 if pi is not None:
                     pi.attempts += 1
+                    self._forming += 1
                     out.append(pi)
                     last_arrival = time.monotonic()
                     continue
-            now = time.monotonic()
-            if window > 0 and now < deadline and now - last_arrival < idle_gap:
-                time.sleep(0.0005)
-                continue
-            break
-        return out
+                now = time.monotonic()
+                if not (len(out) < max_n and window > 0 and now < deadline
+                        and now - last_arrival < idle_gap):
+                    self._forming -= len(out)
+                    self._note_wait_locked(handed=len(out))
+                    return out
+            time.sleep(0.0005)
 
     # -- event-driven movement ----------------------------------------------
 
@@ -276,6 +324,7 @@ class PriorityQueue:
                 else:
                     self._active.add(pi)
                 del self._unschedulable[key]
+            self._note_wait_locked()
             self._cond.notify_all()
 
     def flush_backoff_completed(self) -> None:
@@ -287,6 +336,7 @@ class PriorityQueue:
                     break
                 self._backoff.pop()
                 self._active.add(pi)
+                self._note_wait_locked()
                 self._cond.notify()
 
     def _flush_unschedulable_leftover(self) -> None:
@@ -303,6 +353,7 @@ class PriorityQueue:
                         self._active.add(pi)
                         moved = True
             if moved:
+                self._note_wait_locked()
                 self._cond.notify_all()
 
     # -- update/delete (informer-driven) ------------------------------------
@@ -324,6 +375,7 @@ class PriorityQueue:
                 if _significant_update(old, new):
                     del self._unschedulable[key]
                     self._active.add(pi)
+                    self._note_wait_locked()
                     self._cond.notify()
 
     def delete(self, pod: v1.Pod) -> None:
@@ -340,6 +392,7 @@ class PriorityQueue:
             self._active.delete_by_key(key)
             self._backoff.delete_by_key(key)
             self._unschedulable.pop(key, None)
+            self._note_wait_locked()
             self.delete_nominated_if_exists(pod)
         # pod deleted while queued: no lifecycle left to attribute
         tracer.discard(tid)
@@ -359,6 +412,7 @@ class PriorityQueue:
                     if pi.pod.metadata.uid != uid:
                         return False
                     q.delete_by_key(key)
+                    self._note_wait_locked()
                     self.delete_nominated_if_exists(pod)
                     return True
             pi = self._unschedulable.get(key)
@@ -451,6 +505,7 @@ track_attrs(
     "_nominated",
     "_nominated_by_node",
     "moves",
+    "_forming",
 )
 
 

@@ -441,8 +441,12 @@ class Scheduler:
         # the scheduling loop's wall, phase by phase (utils/tracing.py):
         # switched by the loop thread at every stage boundary below, read
         # by the /metrics scrape; each phase is also a ktpu.loop.<phase>
-        # annotation on the profiler's host plane while a session runs
-        self._phase = PhaseTracker(annotate=jax.profiler.TraceAnnotation)
+        # annotation on the profiler's host plane while a session runs.
+        # The same switches split the queue's waiting pod-seconds by the
+        # phase that held them (`pop` is the batch former's linger)
+        self._phase = PhaseTracker(
+            annotate=jax.profiler.TraceAnnotation, waiting=self.queue.waiting
+        )
         # pod key -> [deferrals, last deferred at]: scheduling-loop
         # thread only (scheduler_wave_deferred_max_attempts)
         self._deferred_counts: Dict[str, list] = {}

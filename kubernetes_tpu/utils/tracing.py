@@ -33,8 +33,11 @@ opened through the ``span()`` context manager, which MUST be used as a
 
 Clock discipline: every timestamp in a span is `time.monotonic()` —
 never wall clock (deflake guard: NTP steps and clock skew must not
-produce negative or inflated stages). Wall time appears only as trace
-attributes (`since_created_s`) for cross-referencing API objects.
+produce negative or inflated stages). Wall time appears only as a trace
+attribute: a pod trace's `admit_lag_s`, the store's commit of the pod's
+create -> its queue admission (a wall-clock delta computed by the queue,
+``Event.committed`` -> now; only on a first admission, delivered by the
+watch), also folded into ``scheduler_pod_admit_lag_seconds``.
 
 Window-long aggregates (the ring holds 1,024 traces; a benchmark window
 binds ten times that): at ``finish()`` a pod trace's per-stage durations
@@ -45,7 +48,10 @@ own wall is accounted by a :class:`PhaseTracker` (exactly one phase at
 any instant, one clock read per switch; published as
 ``scheduler_loop_phase_seconds_total{phase,inflight}`` and, while a
 profiler session runs, as ``ktpu.loop.<phase>`` annotations on the
-device trace's clock). Stalls — GC pauses and periodic background passes
+device trace's clock); the same switches split the pod-seconds that the
+scheduling queue's pods waited by the phase the loop was in
+(``scheduler_queue_wait_seconds_total{phase}``, with
+``scheduler_queue_waits_total`` the visits they were waited over). Stalls — GC pauses and periodic background passes
 — land in a small bounded log (``stall_events``: ``/debug/traces?stalls=1``
 and the SIGUSR2 dump), so a stall seen from outside at t can be matched
 to what ran at t.
@@ -92,6 +98,12 @@ HIST_POD_STAGE = "scheduling_pod_stage_duration_seconds"
 # the scheduling loop's wall by phase; inflight="1" while a launched
 # batch's index payload has not been read back (the chip has work)
 COUNTER_LOOP_PHASE = "scheduler_loop_phase_seconds_total"
+# the queue's waiting pods, integrated over time, by the loop phase that
+# held them (pod-seconds), and the pods pop_batch handed to the loop
+COUNTER_QUEUE_WAIT = "scheduler_queue_wait_seconds_total"
+COUNTER_QUEUE_WAITS = "scheduler_queue_waits_total"
+# a pod's store commit -> queue admit (first admissions), batch-published
+HIST_ADMIT_LAG = "scheduler_pod_admit_lag_seconds"
 # stall causes, in every process that installs the probes
 HIST_GC_PAUSE = "process_gc_pause_seconds"
 GAUGE_PROCESS_CLOCK = "process_clock_seconds"
@@ -261,6 +273,8 @@ class Tracer:
         # stage -> [n, total_s, bucket counts]: finished pod traces'
         # stages, folded under the same lock and flushed with _counts
         self._stage_agg: Dict[str, list] = {}
+        # admitted pods' commit -> admit lags, folded at start()
+        self._admit_agg: list = _new_agg()
         self._last_pub = 0.0  # graftlint: unguarded(single-float publish throttle; a torn read double-publishes at worst)
         self._pub_interval_s = 1.0
 
@@ -279,18 +293,29 @@ class Tracer:
     # -- trace lifecycle ------------------------------------------------------
 
     def start(
-        self, kind: str, key: str, t0: Optional[float] = None, **attrs
+        self,
+        kind: str,
+        key: str,
+        t0: Optional[float] = None,
+        admit_lag_s: Optional[float] = None,
+        **attrs,
     ) -> str:
         """Mint a trace; returns "" when disabled (every other entry
         point treats "" as a no-op id, so call sites stay unconditional).
         t0 (monotonic) backdates the trace start for records minted
-        after their first span's interval began."""
+        after their first span's interval began. admit_lag_s (a pod's
+        commit -> admit, seconds) is kept as the attribute of that name
+        and folded into ``scheduler_pod_admit_lag_seconds``: one value."""
         if not self._enabled:
             return ""
         seq = next(self._id_counter)
         trace_id = f"{self._id_prefix}{seq:08x}"
+        if admit_lag_s is not None:
+            attrs["admit_lag_s"] = admit_lag_s
         rec = _TraceRecord(trace_id, kind, key, attrs, t0)
         with self._lock:
+            if admit_lag_s is not None:
+                _fold(self._admit_agg, admit_lag_s)
             if len(self._active) >= self._max_active:
                 # evict the oldest active trace (dict preserves insertion
                 # order) — bounded memory beats a complete tail under a
@@ -596,11 +621,16 @@ class Tracer:
             depth, active = len(self._ring), len(self._active)
             deltas, self._counts = self._counts, {}
             stages, self._stage_agg = self._stage_agg, {}
+            admit, self._admit_agg = self._admit_agg, _new_agg()
         from .metrics import metrics
 
         for name, (n, total, counts) in sorted(stages.items()):
             metrics.merge_histogram(
                 HIST_POD_STAGE, {"stage": name}, counts, total, n
+            )
+        if admit[0]:
+            metrics.merge_histogram(
+                HIST_ADMIT_LAG, None, admit[2], admit[1], admit[0]
             )
         for (what, label), n in sorted(deltas.items()):
             by = float(n)
@@ -625,6 +655,7 @@ class Tracer:
             self._store_ledger.clear()
             self._counts.clear()
             self._stage_agg.clear()
+            self._admit_agg = _new_agg()
 
 
 # lockset sanitizer (testing/lockgraph.py Eraser mode): the active
@@ -633,7 +664,7 @@ class Tracer:
 # guarded by the one `tracing.ring` leaf lock, machine-checked in chaos
 track_attrs(
     Tracer, "_active", "_by_key", "_ring", "_store_ledger", "_counts",
-    "_stage_agg",
+    "_stage_agg", "_admit_agg",
 )
 
 
@@ -654,19 +685,30 @@ class PhaseTracker:
     delta over two scrapes sums to their distance on this process's
     clock, whatever the loop was in the middle of.
 
+    ``waiting`` (the scheduling queue's ``PriorityQueue.waiting``: its
+    waiting population integrated over time, one tuple read) splits the
+    queue's wait the same way: each switch credits the pod-seconds waited
+    since the last one to the phase it leaves, and ``publish()`` incs
+    ``scheduler_queue_wait_seconds_total{phase}`` (no ``inflight``) and
+    ``scheduler_queue_waits_total`` (the pods ``pop_batch`` handed out) up
+    to the scrape. Δ(all phases) / Δwaits is the mean wait of a visit to
+    the queue (Little's law); ``pop`` is the batch former's linger.
+
     ``annotate`` (``jax.profiler.TraceAnnotation``, handed in by the
     scheduler: this module stays importable without JAX) opens each phase
     as a ``ktpu.loop.<phase>`` host event, so a profiler session started
     from outside records the phases on the device trace's own clock; with
     no session active an annotation is a flag test. Always on:
     ``KTPU_TRACING=0`` does not reach here (operators read the series
-    from ``/metrics``)."""
+    from ``/metrics``). ``clock`` is for tests."""
 
     def __init__(
         self,
         annotate: Optional[Callable[[str], object]] = None,
         prefix: str = "ktpu.loop.",
         start: str = "other",
+        waiting: Optional[Callable[[], tuple]] = None,
+        clock: Callable[[], float] = time.monotonic,
     ):
         # a leaf: taken by the owner thread per switch (uncontended) and
         # by the scrape thread in publish()
@@ -675,7 +717,14 @@ class PhaseTracker:
         self._published: Dict[Tuple[str, str], float] = {}
         self._phase = start
         self._inflight = "0"
-        self._t = time.monotonic()
+        self._clock = clock
+        self._waiting = waiting
+        # phase -> pod-seconds of queue wait; `_area` is the queue's
+        # integral at the last switch, `_handed_pub` the visits published
+        self._wacc: Dict[str, float] = {}
+        self._wpublished: Dict[str, float] = {}
+        self._handed_pub = 0
+        self._t, self._area, _ = self._now()
         self._annotate = annotate
         self._prefix = prefix
         # the open annotation: touched by the owner thread only
@@ -686,15 +735,28 @@ class PhaseTracker:
         with self._lock:
             return self._phase
 
+    def _now(self) -> Tuple[float, float, int]:
+        """(now, the queue's pod-seconds waited up to now, pods it has
+        handed out). The queue's tuple is read BEFORE the clock, so `now`
+        is never earlier than the tuple's own instant."""
+        if self._waiting is None:
+            return self._clock(), 0.0, 0
+        area, n, at, handed = self._waiting()
+        now = self._clock()
+        return now, area + n * (now - at), handed
+
     def switch(self, phase: str, inflight: Optional[bool] = None) -> float:
         """Enter `phase` now; returns the instant. `inflight` (when given)
         re-labels the time from here on: True while the chip holds a
         launched batch whose index payload has not been read back."""
-        now = time.monotonic()
         with self._lock:
+            now, area, _ = self._now()
             k = (self._phase, self._inflight)
             self._acc[k] = self._acc.get(k, 0.0) + (now - self._t)
-            self._t = now
+            self._wacc[self._phase] = (
+                self._wacc.get(self._phase, 0.0) + (area - self._area)
+            )
+            self._t, self._area = now, area
             changed = phase != self._phase
             self._phase = phase
             if inflight is not None:
@@ -722,26 +784,45 @@ class PhaseTracker:
         if self._annotate is not None:
             self._reannotate(None)
 
-    def _totals_locked(self) -> Dict[Tuple[str, str], float]:
+    def _totals_locked(self, now: float) -> Dict[Tuple[str, str], float]:
         out = dict(self._acc)
         k = (self._phase, self._inflight)
-        out[k] = out.get(k, 0.0) + (time.monotonic() - self._t)
+        out[k] = out.get(k, 0.0) + (now - self._t)
+        return out
+
+    def _waits_locked(self, area: float) -> Dict[str, float]:
+        out = dict(self._wacc)
+        out[self._phase] = out.get(self._phase, 0.0) + (area - self._area)
         return out
 
     def totals(self) -> Dict[Tuple[str, str], float]:
         """(phase, inflight) -> seconds, the open phase counted to now."""
         with self._lock:
-            return self._totals_locked()
+            return self._totals_locked(self._now()[0])
+
+    def queue_waits(self) -> Tuple[Dict[str, float], int]:
+        """(phase -> pod-seconds the queue waited, the open phase counted
+        to now; pods handed out)."""
+        with self._lock:
+            _now, area, handed = self._now()
+            return self._waits_locked(area), handed
 
     def publish(self) -> None:
         from .metrics import metrics
 
         with self._lock:
-            totals = self._totals_locked()
+            now, area, handed = self._now()
+            totals = self._totals_locked(now)
             deltas = {
                 k: v - self._published.get(k, 0.0) for k, v in totals.items()
             }
             self._published = totals
+            waits = self._waits_locked(area)
+            wdeltas = {
+                p: v - self._wpublished.get(p, 0.0) for p, v in waits.items()
+            }
+            self._wpublished = waits
+            visits, self._handed_pub = handed - self._handed_pub, handed
         for (phase, inflight), by in sorted(deltas.items()):
             if by > 0.0:
                 metrics.inc(
@@ -749,9 +830,17 @@ class PhaseTracker:
                     {"phase": phase, "inflight": inflight},
                     by=by,
                 )
+        for phase, by in sorted(wdeltas.items()):
+            if by > 0.0:
+                metrics.inc(COUNTER_QUEUE_WAIT, {"phase": phase}, by=by)
+        if visits > 0:
+            metrics.inc(COUNTER_QUEUE_WAITS, by=float(visits))
 
 
-track_attrs(PhaseTracker, "_acc", "_published", "_phase", "_inflight", "_t")
+track_attrs(
+    PhaseTracker, "_acc", "_published", "_phase", "_inflight", "_t",
+    "_wacc", "_wpublished", "_area", "_handed_pub",
+)
 
 
 # -- stalls: GC pauses and background passes -----------------------------------

@@ -236,7 +236,7 @@ def run_codec_ab(n_streams: int = 64, n_events: int = 400) -> list:
                         code, length = _HDR.unpack(head)
                         resp.read(length)
                         wire_bytes[idx] += _HDR.size + length
-                        if code != b"B":
+                        if code not in (b"B", b"T"):  # T: a commit instant
                             counts[idx] += 1
                 else:
                     for line in resp:

@@ -470,6 +470,11 @@ def _hist_n(name, labels=None):
     return h.n if h is not None else 0
 
 
+def _hist_total(name, labels=None):
+    h = metrics.histogram(name, labels)
+    return h.total if h is not None else 0.0
+
+
 def _device_sched(server):
     # the device path at any batch size (the small-batch host lane is for
     # clusters of <= 256 nodes: switched off, as at 5,000 nodes)
@@ -501,6 +506,10 @@ def driven_loop():
         tracer.publish_gauges()
         before = {
             "phases": sched._phase.totals(),
+            "waits": sched._phase.queue_waits(),
+            "queue_s": _hist_total(tracing_mod.HIST_POD_STAGE,
+                                   {"stage": "queue"}),
+            "admits": _hist_n(tracing_mod.HIST_ADMIT_LAG),
             "t": time.monotonic(),
             "pod_stage": {s: _hist_n(tracing_mod.HIST_POD_STAGE, {"stage": s})
                           for s in POD_STAGES},
@@ -519,7 +528,9 @@ def driven_loop():
                 server.create("pods", make_pod(f"win-{i}"))
             assert wait_until(lambda: _bound(server) >= N_WINDOW + 8, 300)
             assert sched.wait_for_idle(60)
-            after = {"phases": sched._phase.totals(), "t": time.monotonic()}
+            after = {"phases": sched._phase.totals(),
+                     "waits": sched._phase.queue_waits(),
+                     "t": time.monotonic()}
         finally:
             gc.enable()
         sched._phase.publish()
@@ -529,6 +540,9 @@ def driven_loop():
             for s in POD_STAGES}
         after["completed"] = metrics.counter(
             "tracing_traces_completed_total", {"kind": "pod"})
+        after["queue_s"] = _hist_total(tracing_mod.HIST_POD_STAGE,
+                                       {"stage": "queue"})
+        after["admits"] = _hist_n(tracing_mod.HIST_ADMIT_LAG)
         after["page"] = metrics.render_prometheus()
         yield before, after
     finally:
@@ -594,6 +608,25 @@ def test_cache_lock_wait_is_observed_per_acquisition(driven_loop):
         "scheduling_stage_duration_seconds", {"stage": "flush"}) > 0
 
 
+def test_queue_wait_split_closes_on_the_queue_spans(driven_loop):
+    """The pod-seconds the phases were credited over the window are the
+    `queue` spans' sum (every visit of every pod: no backoff here; the
+    gap is the microseconds from pop_batch's return to `prepare`), the
+    visits are at least the window's pods, and each window pod was
+    admitted once with its commit -> admit lag."""
+    before, after = driven_loop
+    (w0, n0), (w1, n1) = before["waits"], after["waits"]
+    counted = sum(w1.values()) - sum(w0.values())
+    spans = after["queue_s"] - before["queue_s"]
+    assert counted == pytest.approx(spans, rel=0.05), (counted, spans)
+    assert counted <= spans
+    assert n1 - n0 >= N_WINDOW
+    assert w1["pop"] - w0.get("pop", 0.0) > 0.0
+    assert after["admits"] - before["admits"] == N_WINDOW
+    assert "scheduler_queue_waits_total" in after["page"]
+    assert 'scheduler_queue_wait_seconds_total{phase="pop"}' in after["page"]
+
+
 def test_tracing_off_keeps_loop_and_store_series():
     """KTPU_TRACING=0: every tracer entry point is one attribute test, so
     the pod-stage series stays empty; the loop's phases and the store's
@@ -614,7 +647,10 @@ def test_tracing_off_keeps_loop_and_store_series():
         tracer.publish_gauges()
         page = metrics.render_prometheus()
         assert "scheduling_pod_stage_duration_seconds" not in page
+        assert "scheduler_pod_admit_lag_seconds" not in page
         assert "scheduler_loop_phase_seconds_total" in page
+        assert "scheduler_queue_wait_seconds_total" in page
+        assert "scheduler_queue_waits_total" in page
         assert _hist_n("store_commit_stage_seconds",
                        {"op": "bind", "kind": "pods", "stage": "apply"}) >= 1
         assert _hist_n("store_lock_wait_seconds",
@@ -954,3 +990,266 @@ def test_a_write_is_counted_once_per_stage(apiserver_process):
     with urllib.request.urlopen(
             f"http://127.0.0.1:{port}/debug/traces?stalls=1", timeout=5) as r:
         assert set(json.loads(r.read())) == {"now", "gc", "passes"}
+
+
+# -- ISSUE 38: the queue's wait by the loop phase that held it, and the -------
+# -- store's commit -> queue admit leg -----------------------------------------
+
+from types import SimpleNamespace  # noqa: E402
+
+from kubernetes_tpu.client.informers import SharedInformerFactory  # noqa: E402
+from kubernetes_tpu.scheduler.eventhandlers import (  # noqa: E402
+    add_all_event_handlers,
+)
+from kubernetes_tpu.scheduler.queue.scheduling_queue import (  # noqa: E402
+    PriorityQueue,
+)
+from kubernetes_tpu.utils.tracing import PhaseTracker  # noqa: E402
+
+
+class _Clock:
+    def __init__(self, t=100.0):
+        self.t = t
+
+    def __call__(self):
+        return self.t
+
+
+def _split(t=100.0):
+    """A queue and a phase tracker on one injected clock, the loop in
+    `other` from t."""
+    metrics.reset()
+    clock = _Clock(t)
+    q = PriorityQueue(clock=clock)
+    return clock, q, PhaseTracker(waiting=q.waiting, clock=clock)
+
+
+def _published():
+    return ({p: metrics.counter(tracing_mod.COUNTER_QUEUE_WAIT, {"phase": p})
+             for p in ("other", "bind", "readback", "pop", "launch")},
+            metrics.counter(tracing_mod.COUNTER_QUEUE_WAITS))
+
+
+def _at(clock, t, fn, *args, **kw):
+    clock.t = t
+    return fn(*args, **kw)
+
+
+def test_queue_wait_pod_seconds_are_exact_by_phase():
+    """Pods enter at 101, 103 and 104.5 and leave at pop_batch's returns
+    (105.5, 107); the loop is in other, bind, readback, pop, other, pop,
+    other: each phase holds exactly the pods x seconds that fell in it,
+    and the phases sum to the pods' (entry -> pop_batch return)."""
+    clock, q, ph = _split()
+    _at(clock, 101.0, q.add, make_pod("qa"))
+    _at(clock, 102.0, ph.switch, "bind")
+    _at(clock, 103.0, q.add, make_pod("qb"))
+    _at(clock, 104.0, ph.switch, "readback")
+    _at(clock, 104.5, q.add, make_pod("qc"))
+    _at(clock, 105.0, ph.switch, "pop")
+    got = _at(clock, 105.5, q.pop_batch, 2)
+    assert [pi.key for pi in got] == ["default/qa", "default/qb"]
+    _at(clock, 106.0, ph.switch, "other")
+    _at(clock, 106.5, ph.switch, "pop")
+    assert len(_at(clock, 107.0, q.pop_batch, 8)) == 1
+    _at(clock, 107.25, ph.switch, "other")
+    _at(clock, 110.0, ph.publish)
+    by_phase, visits = _published()
+    # other: qa 101-102, then qc 106-106.5; bind: qa 102-104 + qb 103-104;
+    # pop: three pods 105-105.5, then qc 105.5-106 and 106.5-107
+    assert by_phase == pytest.approx(
+        {"other": 1.0 + 0.5, "bind": 3.0, "readback": 2 * 0.5 + 3 * 0.5,
+         "pop": 3 * 0.5 + 0.5 + 0.5, "launch": 0.0}, abs=1e-12)
+    entry_to_return = (105.5 - 101.0) + (105.5 - 103.0) + (107.0 - 104.5)
+    assert sum(by_phase.values()) == pytest.approx(entry_to_return, abs=1e-12)
+    assert visits == 3
+    waits, handed = ph.queue_waits()
+    assert sum(waits.values()) == pytest.approx(entry_to_return, abs=1e-12)
+    assert handed == 3
+
+
+def test_a_scrape_mid_phase_publishes_up_to_its_instant():
+    clock, q, ph = _split()
+    _at(clock, 100.0, q.add, make_pod("sa"))
+    _at(clock, 100.0, q.add, make_pod("sb"))
+    _at(clock, 101.0, ph.switch, "bind")
+    _at(clock, 101.5, ph.publish)        # two pods, half a second of bind
+    assert _published()[0]["bind"] == pytest.approx(1.0, abs=1e-12)
+    assert _published()[0]["other"] == pytest.approx(2.0, abs=1e-12)
+    _at(clock, 102.0, ph.switch, "pop")
+    _at(clock, 102.5, q.pop_batch, 8)
+    _at(clock, 103.0, ph.switch, "other")
+    _at(clock, 104.0, ph.publish)        # only the rest is new
+    by_phase, visits = _published()
+    assert by_phase["bind"] == pytest.approx(2.0, abs=1e-12)
+    assert by_phase["pop"] == pytest.approx(1.0, abs=1e-12)
+    assert by_phase["other"] == pytest.approx(2.0, abs=1e-12)
+    assert visits == 2
+    _at(clock, 105.0, ph.publish)        # nothing waits: nothing new
+    assert _published() == (by_phase, visits)
+
+
+def test_a_pod_readded_after_deferral_counts_a_second_wait():
+    clock, q, ph = _split()
+    _at(clock, 100.0, q.add, make_pod("ra"))
+    _at(clock, 101.0, ph.switch, "pop")
+    (pi,) = _at(clock, 101.0, q.pop_batch, 8)
+    _at(clock, 101.0, ph.switch, "launch")
+    _at(clock, 102.0, q.readd, pi)       # deferred by the wave
+    _at(clock, 103.0, ph.switch, "pop")
+    assert _at(clock, 103.5, q.pop_batch, 8) == [pi]
+    _at(clock, 104.0, ph.switch, "other")
+    _at(clock, 104.0, ph.publish)
+    by_phase, visits = _published()
+    assert visits == 2
+    assert sum(by_phase.values()) == pytest.approx(1.0 + 1.5, abs=1e-12)
+    assert metrics.counter(tracing_mod.COUNTER_QUEUE_WAIT,
+                           {"phase": "launch"}) == pytest.approx(1.0)
+
+
+def test_queue_wait_integral_survives_racing_writers():
+    """Eight adders, one batch former and a scrape thread that switches
+    phases, with the interpreter switching threads every microsecond: a
+    lost update of the population or of the forming count would leave
+    pods counted after the last pop, or visits unequal to the adds."""
+    import sys
+    import threading
+
+    metrics.reset()
+    q = PriorityQueue()
+    ph = PhaseTracker(waiting=q.waiting)
+    n_adders, per = 8, 150
+    done = threading.Event()
+
+    def adder(a):
+        for i in range(per):
+            q.add(make_pod(f"race-{a}-{i}"))
+
+    def former():
+        got = 0
+        while got < n_adders * per:
+            got += len(q.pop_batch(16, timeout=0.05))
+        done.set()
+
+    def scraper():
+        phases = ("bind", "readback", "pop", "launch")
+        i = 0
+        while not done.is_set():
+            ph.switch(phases[i % 4])
+            ph.publish()
+            i += 1
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=adder, args=(a,))
+                   for a in range(n_adders)]
+        threads += [threading.Thread(target=former),
+                    threading.Thread(target=scraper)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    area, n, _at, handed = q.waiting()
+    assert (n, handed) == (0, n_adders * per)
+    ph.switch("other")
+    ph.publish()
+    published = sum(metrics.counter(tracing_mod.COUNTER_QUEUE_WAIT,
+                                    {"phase": p})
+                    for p in ("bind", "readback", "pop", "launch", "other"))
+    assert published == pytest.approx(area, rel=1e-9)
+    assert metrics.counter(tracing_mod.COUNTER_QUEUE_WAITS) == n_adders * per
+
+
+class _NotAssumed:
+    def is_assumed(self, key):
+        return False
+
+
+def _wired(server):
+    """The scheduler's own informer handlers (eventhandlers.py) over an
+    informer factory and a queue, with nothing else of a scheduler."""
+    factory = SharedInformerFactory(server)
+    sched = SimpleNamespace(
+        informer_factory=factory, cache=_NotAssumed(), queue=PriorityQueue(),
+        profiles=SimpleNamespace(for_pod=lambda pod: object()))
+    add_all_event_handlers(sched)
+    factory.start()
+    assert factory.wait_for_cache_sync(10)
+    return sched, factory
+
+
+def test_admit_lag_is_observed_once_per_first_admission():
+    """Five creates reach the queue through the watch: five observations,
+    each the trace's own `admit_lag_s`. A readd, an update and a second
+    informer's list of the same pods (a relist) observe nothing."""
+    metrics.reset()
+    server = APIServer()
+    sched, factory = _wired(server)
+    other = None
+    try:
+        for i in range(5):
+            server.create("pods", make_pod(f"al-{i}"))
+        assert wait_until(lambda: sched.queue.active_len() == 5, 10)
+        tracer.publish_gauges()
+        h = metrics.histogram(tracing_mod.HIST_ADMIT_LAG)
+        assert h.n == 5 and 0.0 <= h.total < 5.0
+        attrs = [tracer.get(pi.trace_id)["attrs"]
+                 for pi in sched.queue.pending_pod_infos()]
+        assert sum(a["admit_lag_s"] for a in attrs) == pytest.approx(h.total)
+        for pi in sched.queue.pop_batch(8):
+            sched.queue.readd(pi)
+        pod = server.get("pods", "default", "al-0")
+        pod.metadata.labels["touched"] = "yes"
+        server.update("pods", pod)
+        other, other_factory = _wired(server)
+        assert wait_until(lambda: other.queue.active_len() == 5, 10)
+        tracer.publish_gauges()
+        assert metrics.histogram(tracing_mod.HIST_ADMIT_LAG).n == 5
+    finally:
+        factory.stop()
+        if other is not None:
+            other_factory.stop()
+
+
+def test_the_commit_instant_is_the_stores_after_the_decode(rest_stack):
+    """A create's watch event carries the store's commit instant, on the
+    in-process watch and on both REST wires: later than the instant the
+    apiserver decoded the body (the dataclass default of a body without
+    creationTimestamp), no later than the reply."""
+    srv, port, store, client = rest_stack
+    local = store.watch("pods")
+    binary = client.watch("pods")
+    url = client._url("pods", "default") + "?watch=1&resourceVersion=0"
+    lines = urllib.request.urlopen(url, timeout=10)   # no Accept: JSON lines
+    body = {"metadata": {"name": "ci-0", "namespace": "default"},
+            "spec": {"containers": [{"name": "c",
+                                     "resources": {"requests": {"cpu": "1m"}}}]}}
+    sent = time.time()
+    client._request("POST", client._url("pods", "default"), body)
+    replied = time.time()
+    decoded = store.get("pods", "default", "ci-0").metadata.creation_timestamp
+    assert sent <= decoded
+
+    def added(w):
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline:
+            ev = w.get(timeout=0.5)
+            if ev is not None and ev.type == "ADDED":
+                return ev
+        raise AssertionError("no ADDED event")
+
+    committed = [added(local).committed, added(binary).committed]
+    for line in lines:
+        msg = json.loads(line)
+        if msg["type"] == "ADDED":
+            committed.append(msg["committed"])
+            break
+    lines.close()
+    binary.stop()
+    local.stop()
+    assert len(committed) == 3 and len(set(committed)) == 1
+    assert decoded < committed[0] <= replied
