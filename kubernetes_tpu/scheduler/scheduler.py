@@ -73,6 +73,7 @@ from ..parallel.sharded import (
 )
 from ..utils.metrics import metrics
 from ..utils.tracing import PhaseTracker, tracer
+from .bindlane import BindLane, LaneEntry
 from .cache.cache import SchedulerCache
 from .config import KubeSchedulerConfiguration
 from .core import FitError, GenericScheduler
@@ -198,12 +199,13 @@ class _InFlightBatch:
     __slots__ = (
         "pis", "eb", "row_names", "res", "moves0", "t_start",
         "snapshot", "launch_gen", "wave_tid", "t_launched", "weights",
-        "rng_key", "trailing",
+        "rng_key", "trailing", "donated",
     )
 
     def __init__(
         self, pis, eb, row_names, res, moves0, t_start, snapshot=None,
         launch_gen=0, wave_tid="", t_launched=0.0, weights=None, rng_key=None,
+        donated=None,
     ):
         self.pis = pis
         self.eb = eb
@@ -236,6 +238,11 @@ class _InFlightBatch:
         # the _TrailingReadback registered at fast commit (None when
         # nothing was placed) — whoever consumes it finishes the wave trace
         self.trailing = None
+        # the snapshot the kernel was launched on (donated to it): let go
+        # with the batch, after its readback. On the CPU backend dropping
+        # the last reference while the kernel runs waits for the kernel,
+        # which would put a device wait into the loop's `other` phase
+        self.donated = donated
 
 
 class _TrailingReadback:
@@ -350,7 +357,7 @@ class Scheduler:
         # header — else "local" (the in-process store's bind lock). Labels
         # scheduler_ha_fenced_binds_total so a deployment can see WHERE
         # its zombies are being stopped.
-        from ..apiserver.client import RESTClient
+        from ..apiserver.client import BIND_CHUNK, RESTClient
 
         self._bind_transport = (
             "rest"
@@ -424,6 +431,9 @@ class Scheduler:
         self._bind_pool = ThreadPoolExecutor(
             max_workers=self.cfg.bind_workers, thread_name_prefix="binder"
         )
+        # the one sender of in-cycle binds (bindlane.py): commit order,
+        # one request in flight, off the scheduling loop
+        self._bind_lane = BindLane(self._send_lane_request, chunk=BIND_CHUNK)
         self._stop = threading.Event()
         self._sched_thread: Optional[threading.Thread] = None
         self._rng_counter = itertools.count()
@@ -1044,6 +1054,7 @@ class Scheduler:
         # drain in-flight binds BEFORE flushing recorders: a bind finishing
         # after the flush would drop its Scheduled event into a buffer
         # nobody serves
+        self._bind_lane.close()
         self._bind_pool.shutdown(wait=True)
         for p in self.profiles.values():
             rec = getattr(p, "recorder", None)
@@ -1066,6 +1077,7 @@ class Scheduler:
                 and not self._pending
                 and not self._trailing
                 and not self._busy
+                and not self._bind_lane.busy()
                 and not self._ridethrough.open
                 and self._ridethrough.depth == 0
                 and not self.cache.encoder.has_pending_updates
@@ -1083,6 +1095,7 @@ class Scheduler:
             len(self.queue) == 0
             and not self._pending
             and not self._busy
+            and not self._bind_lane.busy()
             and not self._ridethrough.open
             and self._ridethrough.depth == 0
         )
@@ -1220,8 +1233,9 @@ class Scheduler:
     def _ride_through_degraded(self) -> None:
         """Breaker-open tick: flush in-flight wave batches (their binds
         buffer too — the kernels already committed on-device), wait one
-        jittered probe interval, then try to drain the pending-bind
-        buffer. The breaker closes only when the buffer fully drains."""
+        jittered probe interval and for the bind lane to empty, then try
+        to drain the pending-bind buffer. The breaker closes only when
+        the buffer fully drains."""
         if self._pending:
             self._busy = True
             try:
@@ -1232,6 +1246,10 @@ class Scheduler:
                 self._busy = False
             self._phase.switch("ridethrough")
         if self._stop.wait(self._ridethrough.next_probe_delay()):
+            return
+        if self._bind_lane.busy():
+            # the lane parks what it still holds behind the buffer: the
+            # replay waits until nothing is left to join it
             return
         # cheap introspection first: an in-process store exposes its write
         # gate — while it still reports degraded, skip the write probe
@@ -1393,8 +1411,8 @@ class Scheduler:
         this process's grant has been superseded. Callers own the
         DegradedWrites / LeaderFenced handling."""
         if self._bind_fence is not None:
-            return self.server.bind_pods(bindings, fence=self._bind_fence)  # graftlint: degraded-ok(fence-attaching seam; both callers catch DegradedWrites/LeaderFenced at their call sites)
-        return self.server.bind_pods(bindings)  # graftlint: degraded-ok(fence-attaching seam; both callers catch DegradedWrites/LeaderFenced at their call sites)
+            return self.server.bind_pods(bindings, fence=self._bind_fence)  # graftlint: degraded-ok(fence-attaching seam; its callers catch DegradedWrites/LeaderFenced at their call sites)
+        return self.server.bind_pods(bindings)  # graftlint: degraded-ok(fence-attaching seam; its callers catch DegradedWrites/LeaderFenced at their call sites)
 
     def _check_fence_live(self) -> None:
         """Best-effort fence pre-check for bind writes the store cannot
@@ -1495,7 +1513,7 @@ class Scheduler:
     def _record_bound(
         self, pi: QueuedPodInfo, node_name: str, prof, outcome: Optional[str] = None
     ) -> None:
-        """Post-bind bookkeeping shared by the in-cycle bulk path and the
+        """Post-bind bookkeeping shared by the bind lane and the
         ride-through reconciler."""
         self.cache.finish_binding(pi.pod)
         metrics.observe(
@@ -2409,6 +2427,7 @@ class Scheduler:
             _InFlightBatch(
                 pis, eb, row_names, res, moves0, t_start, verify_snap,
                 launch_gen, wave_tid, t_launched, w_launch, sub,
+                donated=snap,
             )
         )
         metrics.inc("scheduler_wave_batches_total")
@@ -3485,11 +3504,13 @@ class Scheduler:
     ) -> None:
         """Assume + bind a whole wave of placements ((pi, node, band,
         proto) tuples; proto may be None for host-path placements). When
-        the profile has no permit/prebind/postbind plugins and the binder
-        is the default, the binds collapse into one batch API call (the
-        in-cycle fast path — async per-pod binding remains for
-        plugin-bearing profiles, matching the reference's
-        goroutine-per-bind at scheduler.go:666)."""
+        the profile has nothing around its bind (_binds_in_cycle), the
+        assumed placements are handed to the bind lane in commit order
+        and leave as one batch API call with whatever the lane holds
+        (the in-cycle fast path: one ordered sender, so the loop goes on
+        to the next launch while the request is in flight). Async
+        per-pod binding remains for plugin-bearing profiles, matching
+        the reference's goroutine-per-bind at scheduler.go:666."""
         if not to_bind:
             return
         ph = self._phase
@@ -3573,80 +3594,105 @@ class Scheduler:
                 self._assume_and_bind_after_assume(pi, node_name, t_start)
         if not simple:
             return
+        # the wave's bindings go to the bind lane in commit order; the
+        # loop's `bind` phase is the hand-off (back-pressure included)
+        t_h = ph.switch("bind")
+        self._bind_lane.put(
+            [LaneEntry(pi, node_name, prof, wave_tid, t_start, t_h)
+             for pi, node_name, prof in simple]
+        )
+        ph.switch("other")
+
+    def _send_lane_request(self, entries: List[LaneEntry]) -> None:
+        """One binding request of the bind lane (its thread, one in
+        flight), and what its outcome means for each entry: bound ->
+        the bookkeeping, its `bind` spans and the binding / e2e
+        observations timed from the hand-off; DegradedWrites -> parked
+        (the breaker opens; while it is open nothing is sent and every
+        request parks, so the reconciler replays the buffer in commit
+        order); LeaderFenced -> dropped, with every entry queued behind
+        it; any other error -> forgotten and failed."""
+        if self._ridethrough.open:
+            # bindings parked ahead of these: they wait behind them
+            self._park_lane_entries(entries)
+            return
         bindings = [
             Binding(
-                pod_name=pi.pod.metadata.name,
-                pod_namespace=pi.pod.metadata.namespace,
-                pod_uid=pi.pod.metadata.uid,
-                target_node=node_name,
+                pod_name=e.pi.pod.metadata.name,
+                pod_namespace=e.pi.pod.metadata.namespace,
+                pod_uid=e.pi.pod.metadata.uid,
+                target_node=e.node_name,
             )
-            for pi, node_name, _ in simple
+            for e in entries
         ]
-        b0 = ph.switch("bind")
         try:
             errors = self._bind_pods_fenced(bindings)
-        except DegradedWrites as e:
-            # in-process store: the gate refused before applying anything
-            # (Degraded — safe to replay) or the whole batch applied but
-            # missed its quorum ack (QuorumLost — outcome unknown). Either
-            # way the wave is NOT failed: park every placement.
-            errors = [e] * len(bindings)
+        except DegradedWrites as exc:
+            # the gate refused before applying anything (Degraded — safe
+            # to replay) or the request applied but missed its quorum ack
+            # (QuorumLost — outcome unknown): park every placement
+            errors = [exc] * len(entries)
         except LeaderFenced:
             # zombie ex-leader: the store holds a newer leadership grant.
             # Nothing applied — drop every placement and stand down.
-            ph.switch("other")
-            self._on_fenced_binds([pi for pi, _n, _p in simple])
+            self._on_fenced_binds(
+                [e.pi for e in entries + self._bind_lane.take_queued()]
+            )
             return
-        # one read: the bind call's end, span and phase; what follows is
-        # the per-pod bookkeeping of the bound pods (`record`)
-        t_b1 = ph.switch("record")
-        bind_dur = t_b1 - b0
-        e2e = t_b1 - t_start
-        tracer.add_span(wave_tid, "bind", b0, t_b1)
-        tracer.add_span_many(
-            [pi.trace_id
-             for (pi, _n, _p), err in zip(simple, errors)
-             if err is None],
-            "bind", b0, t_b1,
+        except Exception as exc:
+            logger.exception("binding request of %d failed", len(entries))
+            errors = [exc] * len(entries)
+        t_b1 = time.monotonic()
+        bound = [e for e, err in zip(entries, errors) if err is None]
+        spans = {
+            (e.wave_tid, "bind", e.t_handoff, t_b1) for e in bound if e.wave_tid
+        }
+        tracer.add_spans(
+            list(spans)
+            + [(e.pi.trace_id, "bind", e.t_handoff, t_b1) for e in bound]
         )
-        to_buffer: List[PendingBind] = []
-        for (pi, node_name, prof), err in zip(simple, errors):
+        parked: List[LaneEntry] = []
+        for e, err in zip(entries, errors):
             if err is None:
-                metrics.observe("binding_duration_seconds", bind_dur)
+                metrics.observe("binding_duration_seconds", t_b1 - e.t_handoff)
                 # exemplar: the tail samples carry the trace id, so the
                 # histogram's p99 resolves to this pod's full waterfall
                 metrics.observe(
-                    "e2e_scheduling_duration_seconds", e2e,
-                    exemplar=pi.trace_id or None,
+                    "e2e_scheduling_duration_seconds", t_b1 - e.t_start,
+                    exemplar=e.pi.trace_id or None,
                 )
-                # queue-entry → bound, incl. queue wait (reference
-                # pod_scheduling_duration_seconds, metrics.go:51-231) — the
-                # honest per-pod number the latency bench reports
-                self._record_bound(pi, node_name, prof)
+                self._record_bound(e.pi, e.node_name, e.prof)
             elif isinstance(err, DegradedWrites):
                 # retryable store refusal (incl. QuorumLost, where THIS
                 # bind applied but wasn't acked — the reconciler's
                 # read-back discriminates): the pod stays assumed — its
                 # assume TTL is unarmed, so the reservation holds for
                 # the whole outage
-                to_buffer.append(PendingBind(pi, node_name, prof))
+                parked.append(e)
             else:
-                self.cache.forget_pod(pi.pod)
+                self.cache.forget_pod(e.pi.pod)
                 self._handle_failure(
-                    pi, self.queue.moves_snapshot(), message=str(err), error=True
+                    e.pi, self.queue.moves_snapshot(), message=str(err),
+                    error=True,
                 )
-        if to_buffer:
-            self._buffer_pending_binds(to_buffer)
-        ph.switch("other")
+        if parked:
+            # the breaker is open now: what is queued behind parks next
+            self._park_lane_entries(parked)
+
+    def _park_lane_entries(self, entries: List[LaneEntry]) -> None:
+        self._buffer_pending_binds(
+            [PendingBind(e.pi, e.node_name, e.prof) for e in entries]
+        )
 
     @staticmethod
     def _binds_in_cycle(prof) -> bool:
         """A profile with nothing around its bind (no reserve, permit,
-        pre- or post-bind plugin, the default binder): its binds are sent
-        from the scheduling thread, in the order the placements were
-        committed, and not from the bind pool, whose workers would let a
-        later placement reach the store before an earlier one it was
-        feasible after."""
+        pre- or post-bind plugin, the default binder): its binds leave
+        from the one bind lane (bindlane.py), in the order the placements
+        were committed, and not from the bind pool, whose workers would
+        let a later placement reach the store before an earlier one it
+        was feasible after. One thread with one request in flight sends
+        a FIFO: nothing handed over later can overtake it."""
         ps = prof.framework.plugin_set
         return (
             not ps.reserve
@@ -3806,9 +3852,17 @@ class Scheduler:
                 e.is_binder() and e.is_interested(pod) for e in self.extenders
             )
         ):
-            # the host path's binds leave in the order of its assumes, as
-            # the wave path's do (_assume_and_bind_bulk): from this thread
-            self._bind_async(pi, node_name, state, t_start)
+            # the host path's binds leave in the order of its assumes, from
+            # the same bind lane as the wave path's (_assume_and_bind_bulk):
+            # behind every wave's binding handed over before it
+            ph = self._phase
+            resume = ph.phase
+            ph.switch("bind")
+            self._bind_lane.put(
+                [LaneEntry(pi, node_name, prof, "", t_start,
+                           pi._bind_submitted_at)]
+            )
+            ph.switch(resume)
             return
         try:
             self._bind_pool.submit(

@@ -575,6 +575,8 @@ def test_loop_phase_other_stays_small(driven_loop):
     "phase", ["pop", "lock_wait", "encode", "launch", "readback", "guard",
               "assume", "bind"])
 def test_loop_phase_is_entered(driven_loop, phase):
+    """`bind` is the hand-off of a wave's bindings to the bind lane: the
+    loop enters it once a wave, however short the stay."""
     before, after = driven_loop
     assert _phase_deltas(before, after).get(phase, 0.0) > 0.0
     assert (f'scheduler_loop_phase_seconds_total{{inflight="0",'
