@@ -1,12 +1,14 @@
 """The per-layer metrics of ISSUE 38: the scheduler's queue wait split by
 the loop phase that held each pod (`scheduler_queue_wait_seconds_total
 {phase}` over `scheduler_queue_waits_total`) and the store's commit ->
-queue admit leg (`scheduler_pod_admit_lag_seconds`). Each entry of
-BENCHMARK.json has its file and lists the cells that report what it
-moves, each file reads what it says from two scrapes, nothing from two
-empty ones nor from a program without the series (the parent commit),
-and a number in a CPU rehearsal of the first one-chip cell of its
-suffix."""
+queue admit leg (`scheduler_pod_admit_lag_seconds`). The eight entries
+stand in BENCHMARK.json in their order, one after another, wherever
+later entries put them; each has its file and lists the cells that
+report what it moves, each file reads what it says from two scrapes,
+nothing from two empty ones nor from a program without the series (the
+parent commit), and a number in a CPU rehearsal of the first one-chip
+cell of its suffix. That rehearsal also reads the bind lane's `.steady`
+entries (test_bind_lane_metrics.py)."""
 
 import json
 import os
@@ -96,9 +98,11 @@ def _ctx(s0, s1):
 def test_the_benchmark_and_its_files_agree():
     assert set(NEW) <= set(ENTRIES)
     assert CAT.problems() == []
-    # appended after every entry that was there, none in between
+    # in their order and none in between; entries appended after them, by
+    # a later configuration or metric, leave them where they are
     names = [m["name"] for m in BENCH["per_layer"]]
-    assert names[-len(NEW):] == list(NEW)
+    first = names.index(next(iter(NEW)))
+    assert names[first:first + len(NEW)] == list(NEW)
 
 
 @pytest.mark.parametrize("name", sorted(NEW))
@@ -160,6 +164,12 @@ def test_the_rehearsal_reads_a_number_for_every_new_metric(suffix, tmp_path):
             assert m[name]["value"] >= 0.0
     assert m[f"admit_lag_ms.{suffix}"]["value"] > 0.0
     if suffix == "steady":
+        # the bind lane's entries, read in the same run (a rehearsal costs
+        # the tier-1 run its time)
+        for name in ("waves_per_bind_request.steady", "bind_lane_wait_ms.steady"):
+            assert name in m, f"{name} read nothing"
+            assert isinstance(m[name]["value"], float)
+            assert m[name]["value"] >= 0.0
         parts = sum(m[f"queue_wait_in_{p}_ms.steady"]["value"]
                     for p in ("pop", "bind", "readback", "launch"))
         assert parts <= m["queue_wait_counted_ms.steady"]["value"] * (1 + 1e-9)
