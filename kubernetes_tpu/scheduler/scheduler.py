@@ -42,6 +42,8 @@ from ..runtime.consensus import DegradedWrites
 from ..controller.volume_scheduling import VolumeBinder
 from ..api.objects import Binding
 from ..ops.batch import encode_pod_batch
+from ..ops.encoding import ETERM_AFF_PREF as _ETERM_AFF_PREF
+from ..ops.encoding import ETERM_ANTI_PREF as _ETERM_ANTI_PREF
 from ..ops.encoding import ETERM_ANTI_REQ as _ETERM_ANTI_REQ
 from ..ops.preemptlattice import validate_preempt_outputs
 from ..ops.templates import TemplateCache, build_pair_table
@@ -130,11 +132,13 @@ GAUGE_WAVE_DEFERRED_MAX = "scheduler_wave_deferred_max_attempts"
 # last pod's commit_wave + 1; 0 for a launch that placed nothing), and the
 # launches whose batch carried a hard pair (the full-wave-count variant),
 # and those of them whose candidate columns were stratified over a hard
-# spread pair's domains, and those a required anti-affinity term made hard
+# spread pair's domains, and those a required anti-affinity term made hard,
+# and the launches whose score a preferred pod (anti-)affinity term moved
 COUNTER_WAVE_COMMIT_ITERATIONS = "scheduler_wave_commit_iterations_total"
 COUNTER_WAVE_HARD_BATCHES = "scheduler_wave_hard_batches_total"
 COUNTER_WAVE_STRATIFIED_BATCHES = "scheduler_wave_stratified_batches_total"
 COUNTER_WAVE_ANTI_BATCHES = "scheduler_wave_anti_affinity_batches_total"
+COUNTER_WAVE_PREF_BATCHES = "scheduler_wave_preferred_affinity_batches_total"
 # a deferred pod re-enters a wave within seconds (readd, or 1-10 s of
 # backoff): an entry untouched this long belongs to a pod that is gone
 _DEFERRED_FORGET_S = 120.0
@@ -1931,6 +1935,24 @@ class Scheduler:
             return self.cfg.wave_n_waves, True, hard_spread, anti
         return min(2, self.cfg.wave_n_waves), False, False, False
 
+    def _batch_preferred(self, eb) -> bool:
+        """Whether a template of THIS batch carries a preferred (score-only)
+        pod affinity or anti-affinity term, or matches one that a resident
+        carries: a launch whose InterPodAffinity plane is not flat
+        (`scheduler_wave_preferred_affinity_batches_total`)."""
+        present = np.unique(eb.pod_tpl_np[eb.pod_tpl_np >= 0])
+        if present.size == 0:
+            return False
+        b = eb.tpl_np
+        if np.any(b.ppref_sid[present] >= 0):
+            return True
+        items = self.cache.encoder.eterm_vocab.items
+        return any(
+            bool(np.any(b.match_eterm[present, tid]))
+            for tid in range(len(items))
+            if items[tid].kind in (_ETERM_AFF_PREF, _ETERM_ANTI_PREF)
+        )
+
     def _wave_variant(
         self, enc_cfg, m_cand: int, n_waves: int, batch_has_hard: bool,
         has_pinned: bool, stratify: bool,
@@ -2337,6 +2359,7 @@ class Scheduler:
                 n_waves, batch_has_hard, stratify, anti = self._batch_waves(
                     eb
                 )
+                pref = self._batch_preferred(eb)
                 self._hard_backlog = batch_has_hard
                 limit = self._batch_limit()
                 if batch_has_hard and len(pis) > limit:
@@ -2440,6 +2463,8 @@ class Scheduler:
             metrics.inc(COUNTER_WAVE_STRATIFIED_BATCHES)
         if anti:
             metrics.inc(COUNTER_WAVE_ANTI_BATCHES)
+        if pref:
+            metrics.inc(COUNTER_WAVE_PREF_BATCHES)
         if len(pis) > self._wave_batch_pods_peak:
             self._wave_batch_pods_peak = len(pis)
             metrics.set_gauge(GAUGE_WAVE_BATCH_PODS_MAX, float(len(pis)))
